@@ -9,9 +9,9 @@ import os as _os
 
 import jax as _jax
 
-# Platform selection must happen before ANY backend initializes (some TPU
-# plugins ignore JAX_PLATFORMS).  MXTPU_PLATFORM=cpu pins a process to
-# host XLA — used by multi-process launches on a single-accelerator box;
+# Platform selection must happen before ANY backend initializes.
+# MXTPU_PLATFORM=cpu pins a process to host XLA — used by multi-process
+# launches on a single-accelerator box (a chip belongs to one process);
 # server-role processes (parameter server) are host-only and never touch
 # the accelerator (parity: reference servers are CPU processes).
 _platform = _os.environ.get("MXTPU_PLATFORM")

@@ -13,6 +13,7 @@ the pure :func:`~mxnet_tpu.autotune.cache.schedule_for`.
 """
 from __future__ import annotations
 
+import logging
 import os
 import time
 
@@ -34,6 +35,13 @@ _TM_CACHE = _tm.counter(
     "search mode triggers a bounded search; in readonly mode the "
     "consumer keeps its default schedule)",
     labels=("result",))
+_TM_REJECTED = _tm.counter(
+    "autotune_rejected_total",
+    "candidate schedules whose build or compile raised during a search "
+    "on a TPU (the compiler's message is logged once per kernel and "
+    "shape) — a lowering the chip refuses is a fault to read, not a "
+    "candidate to skip in silence",
+    labels=("kernel",))
 _TM_BEST = _tm.gauge(
     "autotune_best_us",
     "best-of-k microseconds of the winning schedule at its last "
@@ -82,8 +90,11 @@ def ensure(kernel: str, keysig: str, default, candidates, bench_fn,
       ``candidates`` through ``bench_fn(candidate) -> fn`` (the returned
       thunk is timed with warmup + best-of-k), record + persist the
       winner, return it.  A candidate whose build raises is skipped (a
-      lowering's shape gate may reject it); if every candidate fails,
-      ``default`` wins.
+      lowering's shape gate may reject it) — on a TPU it is also
+      counted (``autotune_rejected_total``) and the first one per
+      search logged with the compiler's message, so a kernel the chip
+      refuses is not quietly replaced by the reference; if every
+      candidate fails, ``default`` wins.
 
     ``default`` should normally appear in ``candidates`` so a search
     can never do worse than not searching.
@@ -100,7 +111,7 @@ def ensure(kernel: str, keysig: str, default, candidates, bench_fn,
     _TM_CACHE.inc(result="miss")
     if mode == "readonly":
         return default
-    best_sched, best_us, trials = None, float("inf"), 0
+    best_sched, best_us, trials, rejected = None, float("inf"), 0, 0
     budget = trials_budget()
     for cand in candidates:
         if trials >= budget:
@@ -108,7 +119,14 @@ def ensure(kernel: str, keysig: str, default, candidates, bench_fn,
         try:
             fn = bench_fn(cand)
             us = measure(fn, warmup=warmup, best_of=best_of)
-        except Exception:  # noqa: BLE001 — candidate rejected by its gate
+        except Exception as e:  # noqa: BLE001 — candidate rejected by its gate
+            if _on_tpu():
+                rejected += 1
+                _TM_REJECTED.inc(kernel=kernel)
+                if rejected == 1:
+                    logging.getLogger("mxnet_tpu.autotune").warning(
+                        "autotune: %s %s candidate %r refused on the "
+                        "TPU: %s", kernel, keysig, cand, e)
             continue
         trials += 1
         if us < best_us:
@@ -124,6 +142,12 @@ def ensure(kernel: str, keysig: str, default, candidates, bench_fn,
     return best_sched
 
 
+def _on_tpu() -> bool:
+    import jax
+
+    return jax.default_backend() == "tpu"
+
+
 def _log_winner_roofline(kernel: str, best_us: float, trials: int):
     """Achieved-vs-roofline context for a search winner (perf plane,
     docs/perf_attr.md): when a cost row exists for a program whose
@@ -131,8 +155,6 @@ def _log_winner_roofline(kernel: str, best_us: float, trials: int):
     the analytical roofline floor — max(flops/peak_flops,
     bytes/peak_bw) — else just name the peaks the consumer's live MFU
     will be measured against.  Logging only; never raises."""
-    import logging
-
     try:
         kind = _tm.perf.device_kind()
         pf = _tm.perf.peak_flops(kind)

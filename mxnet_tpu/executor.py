@@ -358,12 +358,13 @@ def _cl_adapt(node, ins, lay, hwio_params=frozenset()):
 
 
 def _eval_node(node, topo_index, env, key, is_train, lay=None, platform=None,
-               hwio_params=frozenset(), layout_report=None):
+               hwio_params=frozenset(), layout_report=None, mesh=None):
     """Evaluate one op node into env; returns {aux_name: new_val} updates.
 
     ``lay`` (entry -> is_nhwc) enables the channels-last pass; None keeps
     plain NCHW evaluation (the placed/segment path).  ``platform`` is the
-    execution platform threaded into OpCtx (see registry.OpCtx).
+    execution platform threaded into OpCtx (see registry.OpCtx), ``mesh``
+    the device mesh the graph is partitioned over.
     ``layout_report`` (a dict with "conv_w"/"other" sets) collects which
     variables are consumed as NHWC conv weights vs by anything else —
     the discovery pass behind FusedTrainer's HWIO weight storage (a
@@ -388,6 +389,7 @@ def _eval_node(node, topo_index, env, key, is_train, lay=None, platform=None,
         is_train=is_train,
         key=jax.random.fold_in(key, topo_index) if od.needs_rng else None,
         platform=platform,
+        mesh=mesh,
     )
     res = od.fn(octx, *ins, **attrs)
     aux_updates = {}
@@ -407,7 +409,8 @@ def _eval_node(node, topo_index, env, key, is_train, lay=None, platform=None,
 
 def _build_graph_fn(symbol: Symbol, channels_last: Optional[bool] = None,
                     platform: Optional[str] = None,
-                    hwio_params=frozenset(), layout_report=None):
+                    hwio_params=frozenset(), layout_report=None,
+                    mesh=None):
     """Build f(arg_dict, aux_dict, key, is_train) -> (outputs, new_aux_dict).
 
     This is the tracing equivalent of GraphExecutor::InitCachedOps
@@ -417,7 +420,9 @@ def _build_graph_fn(symbol: Symbol, channels_last: Optional[bool] = None,
     chains execute NHWC; graph outputs are always converted back to the
     logical NCHW layout.  ``platform`` tells platform-sensitive ops
     (FlashAttention: Pallas vs lax) what they will lower for; None means
-    "the default backend".
+    "the default backend".  ``mesh`` is the device mesh the caller
+    partitions this graph over: ops that lower to a Mosaic kernel
+    shard_map it over that mesh (GSPMD cannot partition one).
     """
     if channels_last is None:
         channels_last = channels_last_default()
@@ -466,7 +471,8 @@ def _build_graph_fn(symbol: Symbol, channels_last: Optional[bool] = None,
                 new_aux["__rs_idx__:" + rsw] = idx.reshape(-1)
                 continue
             new_aux.update(_eval_node(node, i, env, key, is_train, lay,
-                                      platform, hwio_params, layout_report))
+                                      platform, hwio_params, layout_report,
+                                      mesh))
         outputs = [
             _to_nchw(env[id(n)][i]) if lay and lay.get((id(n), i))
             else env[id(n)][i]

@@ -361,7 +361,7 @@ class DevicePrefetchIter(DataIter):
     PrefetchingIter overlaps host batch PREP with compute; this overlaps
     the host->device copy too.  jax transfers are dispatched
     asynchronously, so a producer thread calling ``device_put`` ``depth``
-    batches ahead hides the PCIe/tunnel latency behind the training
+    batches ahead hides the host->device copy behind the training
     step — the TPU-shaped analogue of the reference's PrefetcherIter
     feeding pinned GPU memory (src/io/iter_prefetcher.h:50-155).  Stack
     as ImageRecordIter -> PrefetchingIter -> DevicePrefetchIter for the

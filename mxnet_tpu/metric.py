@@ -206,9 +206,23 @@ class EvalMetric:
             # mesh -> single-device transition (metric reused across
             # modules): fold the sharded window out rather than mixing
             self._drain()
+        # one device: the label and the accumulators follow the
+        # prediction's device.  A label batch out of a host iterator is
+        # committed to the CPU backend while a Module bound to mx.tpu(0)
+        # predicts on the chip, and jit refuses to mix the two (found by
+        # the first Module.fit on a chip, PR 21)
+        home = None
+        if rep is None and sh is not None:
+            (dev,) = sh.device_set
+            home = jax.sharding.SingleDeviceSharding(dev)
+            if label is not None and raw_l.devices() != {dev}:
+                raw_l = jax.device_put(raw_l, home)
         if self._dev_sum is None:
-            self._dev_sum = jnp.zeros((), jnp.float32)
-            self._dev_num = jnp.zeros((), jnp.float32)
+            self._dev_sum = jnp.zeros((), jnp.float32, device=home)
+            self._dev_num = jnp.zeros((), jnp.float32, device=home)
+        elif home is not None and self._dev_sum.devices() != {dev}:
+            self._dev_sum = jax.device_put(self._dev_sum, home)
+            self._dev_num = jax.device_put(self._dev_num, home)
         if rep is not None and len(
                 self._dev_sum.sharding.device_set) != len(sh.device_set):
             self._dev_sum = _replicate(self._dev_sum, rep)

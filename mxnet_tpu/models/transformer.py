@@ -99,8 +99,8 @@ def get_symbol(num_classes=1000, **kwargs):
 
 # the compute-bound headline config (~220M params): big enough matmuls to
 # feed the MXU, small enough that Adam state + activations fit one v5e
-# chosen by the on-silicon sweep (docs/measured/lmmfu_r05.txt): the
-# d2048 8-layer config more than doubles the d1024 12-layer's MFU
+# chosen by an on-silicon sweep taken before PR 1 (capture deleted in
+# PR 21; not measured this round): the d2048 8-layer config more than doubles the d1024 12-layer's MFU
 # (0.47-0.53 vs 0.24 at b8 on v5e) — wider matmuls feed the MXU better
 # than more layers at the same parameter budget
 MFU_HEADLINE_CONFIG = dict(num_layers=8, num_heads=16, d_model=2048,

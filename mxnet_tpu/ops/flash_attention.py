@@ -313,7 +313,7 @@ def _register():
         from ..parallel.ring_attention import attention
 
         return attention(query, key, value, causal=causal, scale=scale,
-                         impl=impl, platform=ctx.platform)
+                         impl=impl, platform=ctx.platform, mesh=ctx.mesh)
 
 
 _register()

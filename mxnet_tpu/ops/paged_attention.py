@@ -39,6 +39,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..base import mxu_precision
+
 __all__ = [
     "supports", "keysig", "default_schedule", "candidate_schedules",
     "paged_attention", "gather_tables", "make_bench_fn",
@@ -52,13 +54,20 @@ _PAGEWALK_CHUNKS = (1, 2, 4, 8)
 
 
 def supports(block: int, dh: int, dtype) -> bool:
-    """Can the Pallas kernel tile ``(block, dh)`` KV pages?  One page is
-    one VMEM tile, so both dims must fill whole 8-row sublanes; wider
-    lane padding is Mosaic's job.  Ragged shapes fall back to gather."""
-    if jnp.dtype(dtype) not in (jnp.dtype(jnp.float32),
-                                jnp.dtype(jnp.bfloat16)):
+    """Will Mosaic take the Pallas kernel at ``(block, dh)`` KV pages?
+    One page is one VMEM tile DMA'd out of the pool: ``dh`` must fill
+    whole 128-wide lanes (the v5e compiler refuses a narrower slice of
+    the pool: "must be aligned to tiling (128)") and ``block`` whole
+    sublane tiles — 8 rows of f32, 16 of packed bf16.  Everything else
+    takes gather, by this gate."""
+    dt = jnp.dtype(dtype)
+    if dt == jnp.dtype(jnp.float32):
+        rows = 8
+    elif dt == jnp.dtype(jnp.bfloat16):
+        rows = 16
+    else:
         return False
-    return block % 8 == 0 and dh % 8 == 0 and block > 0 and dh > 0
+    return block > 0 and dh > 0 and block % rows == 0 and dh % 128 == 0
 
 
 def keysig(B: int, H: int, M: int, block: int, dh: int, dtype) -> str:
@@ -208,12 +217,22 @@ def _pallas_attention(q, pool_k, pool_v, bt, cursor, layer, block,
             else:
                 _dma()
         qv = q_ref[0, 0]                                 # (1, dh)
-        scores = jnp.einsum("nd,sd->ns", qv, kbuf[...]) \
-            / jnp.sqrt(jnp.asarray(dh, qv.dtype))
+        # Mosaic only takes a matmul that accumulates in 32 bits, and a
+        # bf16 one only at single-pass precision (the package default is
+        # fp32 passes): both products and the softmax between them run
+        # in f32 whatever the cache dtype; the weights drop back to the
+        # cache dtype for the MXU
+        prec = mxu_precision(qv)
+        scores = jnp.einsum("nd,sd->ns", qv, kbuf[...], precision=prec,
+                            preferred_element_type=jnp.float32) \
+            / jnp.sqrt(jnp.asarray(dh, jnp.float32))
         s_idx = jax.lax.broadcasted_iota(jnp.int32, (1, S), 1)
         scores = jnp.where(s_idx <= cur, scores, NEG_INF)
         att = jax.nn.softmax(scores, axis=-1)
-        o_ref[0, 0] = jnp.einsum("ns,sd->nd", att, vbuf[...])
+        o_ref[0, 0] = jnp.einsum(
+            "ns,sd->nd", att.astype(vbuf.dtype), vbuf[...],
+            precision=prec,
+            preferred_element_type=jnp.float32).astype(o_ref.dtype)
 
     if flat:
         grid = (B * H,)
@@ -226,8 +245,8 @@ def _pallas_attention(q, pool_k, pool_v, bt, cursor, layer, block,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, 1, dh), qmap),
-            pl.BlockSpec(memory_space=pltpu.ANY),   # pool_k stays in HBM
-            pl.BlockSpec(memory_space=pltpu.ANY),   # pool_v stays in HBM
+            pl.BlockSpec(memory_space=pl.ANY),   # pool_k stays in HBM
+            pl.BlockSpec(memory_space=pl.ANY),   # pool_v stays in HBM
         ],
         out_specs=pl.BlockSpec((1, 1, 1, dh), qmap),
         scratch_shapes=[
@@ -259,10 +278,10 @@ def paged_attention(q, pool_k, pool_v, bt, cursor, layer, *, block,
     if impl == "pallas" and not supports(block, q.shape[-1], q.dtype):
         impl = "gather"
     if impl == "pallas":
-        # a TPU kernel forced onto a host without one runs interpreted
-        # (the parity tool) instead of failing to lower
-        interp = bool(interpret or sched.get("interpret", False)
-                      or jax.default_backend() != "tpu")
+        # interpreted only when asked for (the CPU parity tool) — never
+        # inferred from the backend: a forced kernel that cannot lower
+        # says so
+        interp = bool(interpret or sched.get("interpret", False))
         return _pallas_attention(
             q, pool_k, pool_v, bt, cursor, layer, block, sched, interp)
     if impl == "pagewalk":

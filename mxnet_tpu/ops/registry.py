@@ -40,9 +40,10 @@ class OpCtx:
     explicit key (pure & replayable inside jit).
     """
 
-    __slots__ = ("is_train", "_key", "_nsplit", "platform")
+    __slots__ = ("is_train", "_key", "_nsplit", "platform", "mesh")
 
-    def __init__(self, is_train: bool = False, key=None, platform=None):
+    def __init__(self, is_train: bool = False, key=None, platform=None,
+                 mesh=None):
         self.is_train = is_train
         self._key = key
         self._nsplit = 0
@@ -53,6 +54,10 @@ class OpCtx:
         # be the default backend while the computation is being lowered
         # for a CPU mesh (e.g. dryrun_multichip on a TPU-attached host).
         self.platform = platform
+        # the device mesh the graph is partitioned over (None: one
+        # device).  GSPMD cannot partition a Mosaic kernel, so an op
+        # that lowers to one must shard_map it over this mesh itself.
+        self.mesh = mesh
 
     def rng(self):
         if self._key is None:

@@ -235,23 +235,3 @@ def megatron_rules(model_axis: str = "model", shard_embed: bool = True):
     if shard_embed:
         rules.append(ShardingRule(r"tok_embed_weight$", (model_axis, None)))
     return tuple(rules)
-
-
-def shard_map(f=None, **kw):
-    """jax.shard_map across jax versions: the new top-level API spells
-    the replication check `check_vma`, the 0.4.x experimental API spells
-    it `check_rep` (and has no top-level export).  Shared by
-    pipeline/moe/ring_attention so version skew lives in ONE place."""
-    import functools
-
-    import jax
-
-    if f is None:
-        return functools.partial(shard_map, **kw)
-    check = kw.pop("check_rep", kw.pop("check_vma", True))
-    impl = getattr(jax, "shard_map", None)
-    if impl is not None:
-        return impl(f, check_vma=check, **kw)
-    from jax.experimental.shard_map import shard_map as impl
-
-    return impl(f, check_rep=check, **kw)

@@ -29,7 +29,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from .mesh import shard_map
 
 
 def init_moe_params(rng, d_model, d_hidden, n_experts, scale=0.02):
@@ -59,11 +58,11 @@ def moe_ffn(params, x, mesh: Mesh, axis_name: str = "expert",
     if not 1 <= top_k <= n_exp:
         raise ValueError(f"top_k must be in [1, {n_exp}], got {top_k}")
 
-    @partial(shard_map, mesh=mesh,
+    @partial(jax.shard_map, mesh=mesh,
              in_specs=(P(None, None), P(axis_name, None, None),
                        P(axis_name, None, None), P(axis_name, None)),
              out_specs=(P(axis_name, None), P()),
-             check_rep=False)
+             check_vma=False)
     def run(gate_w, w_in, w_out, xs):
         nt = xs.shape[0]  # local tokens
         cap = max(1, int(capacity_factor * top_k * nt / n_exp))

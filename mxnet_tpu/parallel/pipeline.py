@@ -50,7 +50,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from .mesh import shard_map
 
 
 def stack_stage_params(per_stage_params):
@@ -126,10 +125,10 @@ def pipeline_apply(stage_fn, stacked_params, micro_inputs, mesh: Mesh,
     param_specs = {n: P(axis_name, *([None] * (v.ndim - 1)))
                    for n, v in stacked_params.items()}
 
-    @partial(shard_map, mesh=mesh,
+    @partial(jax.shard_map, mesh=mesh,
              in_specs=(param_specs, P()),
              out_specs=P(),
-             check_rep=False)
+             check_vma=False)
     def run(params, xs):
         # params: {name: [1, ...]} my stage's slice; xs: [n_micro, mb, ...]
         my = {n: v[0] for n, v in params.items()}
@@ -374,8 +373,8 @@ def pipeline_apply_tree(stage_fns, stacked, meta, micro_inputs,
               for k, sig in meta.union.items()}
     xspec = (P(None, data_axis) if data_axis else P())
 
-    @partial(shard_map, mesh=mesh, in_specs=(pspecs, xspec),
-             out_specs=xspec, check_rep=False)
+    @partial(jax.shard_map, mesh=mesh, in_specs=(pspecs, xspec),
+             out_specs=xspec, check_vma=False)
     def run(params, xs):
         sl = {k: v[0] for k, v in params.items()}
         stage = jax.lax.axis_index(axis_name)
@@ -466,10 +465,10 @@ def make_pipeline_train_step(stage_fns, loss_fn, meta, mesh: Mesh,
                   for k, sig in meta.union.items()}
         dspec = (P(None, data_axis) if data_axis else P())
 
-        @partial(shard_map, mesh=mesh,
+        @partial(jax.shard_map, mesh=mesh,
                  in_specs=(pspecs, dspec, dspec),
                  out_specs=(P(), pspecs),
-                 check_rep=False)
+                 check_vma=False)
         def run(params, xs, labels):
             sl = {k: v[0] for k, v in params.items()}
             stage = jax.lax.axis_index(axis_name)

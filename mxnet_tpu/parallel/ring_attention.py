@@ -27,7 +27,6 @@ from ..base import mxu_precision
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from .mesh import shard_map
 
 
 def _stream_block(q, k, v, m, l, o, scale, mask=None):
@@ -94,7 +93,7 @@ def ring_attention(q, k, v, mesh: Mesh, axis_name: str = "seq",
         m, l, o, _, _ = jax.lax.fori_loop(0, n, body, (m0, l0, o0, k, v))
         return o / jnp.maximum(l, 1e-20)[..., None]
 
-    return shard_map(local_fn, mesh=mesh, in_specs=(spec, spec, spec),
+    return jax.shard_map(local_fn, mesh=mesh, in_specs=(spec, spec, spec),
                      out_specs=spec, check_vma=False)(q, k, v)
 
 
@@ -131,7 +130,7 @@ def ulysses_attention(q, k, v, mesh: Mesh, axis_name: str = "seq",
         return jax.lax.all_to_all(ol, axis_name, split_axis=2, concat_axis=1,
                                   tiled=True)
 
-    return shard_map(local_fn, mesh=mesh, in_specs=(spec, spec, spec),
+    return jax.shard_map(local_fn, mesh=mesh, in_specs=(spec, spec, spec),
                      out_specs=spec, check_vma=False)(q, k, v)
 
 
@@ -151,7 +150,27 @@ def full_attention(q, k, v, causal=False, scale=None):
     return jnp.einsum("bhqk,bhkd->bhqd", p, v, precision=mxu_precision(p, v))
 
 
-def attention(q, k, v, causal=False, scale=None, impl="auto", platform=None):
+def _kernel_spec(mesh, shape):
+    """How the flash kernel's ``(B, H, T, dh)`` operands split over
+    ``mesh``: batch over the data axis, heads over ``model`` (the
+    megatron layout of the q/k/v projections), each only where it
+    divides.  None when there is nothing to split over."""
+    if mesh is None or mesh.size == 1:
+        return None
+
+    def axis(names, dim):
+        for name in names:
+            n = mesh.shape.get(name, 1)
+            if n > 1 and dim % n == 0:
+                return name
+        return None
+
+    return P(axis(("data", "batch"), shape[0]), axis(("model",), shape[1]),
+             None, None)
+
+
+def attention(q, k, v, causal=False, scale=None, impl="auto", platform=None,
+              mesh=None):
     """Single-device attention dispatcher.
 
     impl='flash' (or 'auto' on TPU with block-compatible shapes) runs the
@@ -163,7 +182,13 @@ def attention(q, k, v, causal=False, scale=None, impl="auto", platform=None):
     OpCtx by the symbol-graph path); None falls back to the process
     default backend.  The distinction matters whenever a computation
     targets non-default devices — a CPU mesh on a TPU-attached host
-    would otherwise pick the Pallas kernel and fail to lower."""
+    would otherwise pick the Pallas kernel and fail to lower.
+
+    ``mesh`` is the device mesh the surrounding program is partitioned
+    over.  GSPMD refuses a Mosaic kernel ("cannot be automatically
+    partitioned"), so under a mesh the flash kernel runs inside a
+    ``shard_map``: every device attends over its own batch rows and
+    heads, no collective."""
     from ..ops import flash_attention as fa
 
     # kernel tile sizes are a measured quantity, not a constant:
@@ -177,10 +202,17 @@ def attention(q, k, v, causal=False, scale=None, impl="auto", platform=None):
     if impl == "auto":
         on_tpu = (platform or jax.default_backend()) == "tpu"
         impl = "flash" if on_tpu and fa.supports(q.shape, bq, bk) else "lax"
-    if impl == "flash":
-        return fa.flash_attention(q, k, v, causal, scale, bq, bk)
-    if impl == "flash_interpret":  # CPU test path for the kernels
-        return fa.flash_attention(q, k, v, causal, scale, bq, bk, True)
+    if impl in ("flash", "flash_interpret"):
+        # flash_interpret: the CPU test path for the kernels
+        def kernel(q, k, v):
+            return fa.flash_attention(q, k, v, causal, scale, bq, bk,
+                                      impl == "flash_interpret")
+
+        spec = _kernel_spec(mesh, q.shape)
+        if spec is not None:
+            kernel = jax.shard_map(kernel, mesh=mesh, in_specs=(spec,) * 3,
+                                   out_specs=spec, check_vma=False)
+        return kernel(q, k, v)
     return full_attention(q, k, v, causal=causal, scale=scale)
 
 

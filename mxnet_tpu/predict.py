@@ -175,8 +175,7 @@ class Predictor:
 
         Unlike Executor.forward (which runs eager NDArray writes, an
         eager RNG fold, and output re-wrapping per call — each one a
-        host↔device round trip that serializes on tunneled/remote
-        backends), this path is a single dispatch: the RNG fold happens
+        host↔device round trip), this path is a single dispatch: the RNG fold happens
         *inside* the program (the step counter is a traced scalar), the
         dtype casts fuse into their consumers, and outputs stay raw jax
         arrays until ``get_output`` copies them out (parity note: the
@@ -229,8 +228,8 @@ class Predictor:
         # upload inputs over the wire ALREADY in the compute dtype: the
         # in-graph cast would throw the upper half of every fp32 mantissa
         # away on arrival anyway, so casting on the host first halves the
-        # host->device bytes — on transport-bound deployments (remote/
-        # tunneled devices) input upload IS the predictor's bottleneck
+        # host->device bytes — where input upload bounds the predictor,
+        # that is half its time
         if cast is not None and cast != jnp.float32:
             self._wire_dtype = cast
 
@@ -325,9 +324,9 @@ class Predictor:
         ``get_async``.  Several tickets may be in flight at once — each
         call's input upload, compute, and device→host output fetch queue
         independently, so consecutive calls pipeline all three stages
-        against each other.  On transport-bound deployments (remote or
-        tunneled devices) this hides compute and output-fetch time under
-        the next call's input upload; a strict
+        against each other.  Where the transfers bound the predictor
+        this hides compute and output-fetch time under the next call's
+        input upload; a strict
         ``forward()``/``get_output()`` loop instead pays the full
         upload+compute+fetch round trip per call.
 

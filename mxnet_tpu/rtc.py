@@ -33,18 +33,14 @@ class Rtc:
 
     def __init__(self, name, inputs, outputs, kernel):
         from jax.experimental import pallas as pl
+        from jax.experimental.pallas import tpu as pltpu
 
         self.name = name
         self._in_templates = list(inputs)
         self._out_templates = list(outputs)
 
-        ns = {"pl": pl, "jnp": jnp, "jax": jax, "lax": jax.lax}
-        try:
-            from jax.experimental.pallas import tpu as pltpu
-
-            ns["pltpu"] = pltpu
-        except ImportError:  # CPU-only builds
-            pass
+        ns = {"pl": pl, "pltpu": pltpu, "jnp": jnp, "jax": jax,
+              "lax": jax.lax}
         try:
             exec(compile(kernel, f"<rtc:{name}>", "exec"), ns)
         except SyntaxError as e:

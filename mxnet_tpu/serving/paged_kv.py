@@ -118,7 +118,7 @@ class _PagedPrograms:
                  schedule=None):
         import jax
 
-        from ..models.decode import _count_compiles
+        from ..models.decode import _WeightProgram, _count_compiles
 
         self.dec = decoder
         self.block = int(block)
@@ -130,7 +130,7 @@ class _PagedPrograms:
         # always gathers (one admission-time cost, not the per-tick one)
         self.schedule = schedule if (
             schedule and schedule.get("impl") != "gather") else None
-        self._step_jit = jax.jit(_count_compiles(
+        self._step_jit = _WeightProgram(decoder, _count_compiles(
             self._forward_step, "decode_step_paged"))
         self._prefill_cache = {}
 
@@ -152,7 +152,7 @@ class _PagedPrograms:
                          self.max_blocks * self.block, d.dh)
 
     # ---------------------------------------------------------------- step
-    def _forward_step(self, pool_k, pool_v, bt, tokens, cursor):
+    def _forward_step(self, p, pool_k, pool_v, bt, tokens, cursor):
         """One decode position for every slot: row ``b`` writes its new
         K/V at absolute cache position ``cursor[b]`` (page
         ``bt[b, cursor//block]``, offset ``cursor%block``) and attends
@@ -165,7 +165,6 @@ class _PagedPrograms:
         from ..models.decode import NEG_INF, _fc, _ln
 
         d = self.dec
-        p = d.p
         B = tokens.shape[0]
         H, dh, D = d.H, d.dh, d.d_model
         S = self.max_blocks * self.block
@@ -190,7 +189,7 @@ class _PagedPrograms:
         for i in range(d.L):
             name = f"layer{i}"
             h2 = _ln(h, p[f"{name}_ln1_gamma"], p[f"{name}_ln1_beta"])
-            q, k, v = d._block_qkv(i, h2)
+            q, k, v = d._block_qkv(p, i, h2)
             sh = lambda a: a.reshape(B, 1, H, dh).transpose(0, 2, 1, 3)
             qh, kh, vh = sh(q), sh(k), sh(v)                 # (B, H, 1, dh)
             if sched is None:
@@ -228,7 +227,8 @@ class _PagedPrograms:
         return (pool_k, pool_v), logits[:, 0]                # (B, V)
 
     # ------------------------------------------------------------- prefill
-    def _forward_prefill(self, pool_k, pool_v, bt_row, tokens, hist, t):
+    def _forward_prefill(self, p, pool_k, pool_v, bt_row, tokens, hist,
+                         t):
         """Tail prefill behind a (possibly reused) history: ``tokens``
         (1, T) RIGHT-padded, the ``t`` real tokens sit at absolute
         positions ``hist .. hist+t-1``.  K/V of real tokens scatter
@@ -242,7 +242,6 @@ class _PagedPrograms:
         from ..models.decode import NEG_INF, _fc, _ln
 
         d = self.dec
-        p = d.p
         T = tokens.shape[1]
         H, dh, D = d.H, d.dh, d.d_model
         S = self.max_blocks * self.block
@@ -269,7 +268,7 @@ class _PagedPrograms:
         for i in range(d.L):
             name = f"layer{i}"
             h2 = _ln(h, p[f"{name}_ln1_gamma"], p[f"{name}_ln1_beta"])
-            q, k, v = d._block_qkv(i, h2)
+            q, k, v = d._block_qkv(p, i, h2)
             sh = lambda a: a.reshape(1, T, H, dh).transpose(0, 2, 1, 3)
             qh, kh, vh = sh(q), sh(k), sh(v)                 # (1, H, T, dh)
             k_t = kh[0].transpose(1, 0, 2)                   # (T, H, dh)
@@ -302,10 +301,11 @@ class _PagedPrograms:
         if bucket not in self._prefill_cache:
             import jax
 
-            from ..models.decode import _count_compiles
+            from ..models.decode import _WeightProgram, _count_compiles
 
-            self._prefill_cache[bucket] = jax.jit(_count_compiles(
-                self._forward_prefill, "decode_prefill_paged"))
+            self._prefill_cache[bucket] = _WeightProgram(
+                self.dec, _count_compiles(self._forward_prefill,
+                                          "decode_prefill_paged"))
         return self._prefill_cache[bucket]
 
 
@@ -616,6 +616,16 @@ class PagedSlots:
         if starved:
             self._set_gauges()
         return logits, starved
+
+    def lower_step(self):
+        """The paged step program lowered for this pool's shapes,
+        without running it: ``.compile().as_text()`` shows whether the
+        Pallas kernel (``tpu_custom_call``) is in it.  Inspection only."""
+        from ..models.decode import _snap
+
+        return self.programs._step_jit.lower(
+            self.pool[0], self.pool[1], _snap(self.bt),
+            _snap(np.zeros(self.num_slots, np.int64)), _snap(self.cursor))
 
     def exhausted(self, slot):
         return self.cursor[slot] >= self.decoder.max_len

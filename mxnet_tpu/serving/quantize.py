@@ -33,6 +33,7 @@ tests/test_serving_fleet.py).
 """
 from __future__ import annotations
 
+import jax
 import numpy as np
 
 __all__ = ["QuantizedTensor", "quantize_per_channel", "quantize_params",
@@ -73,6 +74,15 @@ class QuantizedTensor:
     def __repr__(self):
         return (f"QuantizedTensor(shape={self.shape}, axis={self.axis}, "
                 f"dtype={np.dtype(self.dtype).name})")
+
+
+# the decode programs take their weights as ARGUMENTS (models/decode.py:
+# _WeightProgram): the int8 payload and its scale are the traced
+# children, dtype and axis ride as static aux data
+jax.tree_util.register_pytree_node(
+    QuantizedTensor,
+    lambda t: ((t.q, t.scale), (t.dtype, t.axis)),
+    lambda aux, kids: QuantizedTensor(kids[0], kids[1], *aux))
 
 
 def quantize_per_channel(w, axis=0):

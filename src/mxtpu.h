@@ -117,8 +117,7 @@ int MXPredSetInput(void *handle, const char *key, const float *data,
 int MXPredForward(void *handle);
 /* Pipelined inference: ForwardAsync dispatches without joining and hands
  * back a ticket; GetOutputAsync joins that ticket.  Keeping 2+ tickets in
- * flight overlaps input upload, compute, and output fetch across calls —
- * the transport-hiding path for remote/tunneled devices. */
+ * flight overlaps input upload, compute, and output fetch across calls. */
 int MXPredForwardAsync(void *handle, int64_t *out_ticket);
 int MXPredGetOutputAsync(void *handle, int64_t ticket, uint32_t index,
                          float *data, uint32_t size);

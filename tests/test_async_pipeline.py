@@ -118,6 +118,28 @@ def test_fused_metric_local_global_split():
     np.testing.assert_allclose(g, exp, rtol=1e-6)
 
 
+def test_fused_metric_follows_the_predictions_device():
+    """A Module bound to an accelerator predicts there while its label
+    batch sits where the host iterator put it; the fused accumulate
+    moves the label (and its accumulators) to the prediction's device
+    instead of handing jit two devices.  Found by the first Module.fit
+    on a chip (PR 21); two CPU devices stand in for host and chip."""
+    label, pred = _classification_batches(1)[0]
+    m = mx.metric.Accuracy()
+    ref = mx.metric.Accuracy()
+    on_chip = nd.array(pred, ctx=mx.cpu(1))
+    for _ in range(2):
+        m.update([nd.array(label)], [on_chip])
+        ref.update([nd.array(label)], [nd.array(pred)])
+    assert m._dev_sum is not None, "took the eager path"
+    assert m._dev_sum.devices() == on_chip._read().devices()
+    assert m.get() == ref.get()
+    # the same metric handed back to a host-bound module follows again
+    m.update([nd.array(label)], [nd.array(pred)])
+    ref.update([nd.array(label)], [nd.array(pred)])
+    assert m.get() == ref.get()
+
+
 def test_custom_and_f1_metrics_stay_eager():
     label = nd.array(np.array([1.0, 0.0]))
     pred = nd.array(np.array([[0.2, 0.8], [0.3, 0.7]]))

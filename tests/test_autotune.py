@@ -43,6 +43,27 @@ def decoder(lm_params):
     return KVDecoder(lm_params, num_layers=L, num_heads=H, max_len=T)
 
 
+@pytest.fixture(scope="module")
+def wide_decoder():
+    """dh = 128: the narrowest head the Pallas gate admits (one page
+    row fills whole 128-wide lanes) — the module's default D=32 model
+    takes gather by the gate."""
+    d_model = 128 * H
+    net = models.transformer.transformer_lm(
+        num_layers=L, num_heads=H, d_model=d_model, seq_len=T,
+        vocab_size=V)
+    ex = net.simple_bind(ctx=mx.cpu(), grad_req="null",
+                         data=(1, T), softmax_label=(1, T))
+    rs = np.random.RandomState(0)
+    params = {}
+    for name, arr in ex.arg_dict.items():
+        if name in ("data", "softmax_label"):
+            continue
+        arr[:] = rs.normal(0, 0.04, arr.shape).astype(np.float32)
+        params[name] = arr
+    return KVDecoder(params, num_layers=L, num_heads=H, max_len=T)
+
+
 @pytest.fixture()
 def metrics():
     was = tm.enabled()
@@ -227,6 +248,40 @@ def test_trial_budget_and_telemetry(sched_cache, monkeypatch, metrics):
     assert got == {"impl": "d"} and calls == []
 
 
+def test_refused_candidate_is_counted_and_logged_on_tpu(
+        sched_cache, metrics, monkeypatch, caplog):
+    """A candidate whose build raises is skipped — on a TPU it is also
+    counted and the first of the search logged with the compiler's
+    message, so a kernel the chip refuses is not quietly replaced by
+    the reference (ISSUE 21).  Off a TPU the skip stays silent: there
+    the gate is doing its job."""
+    from mxnet_tpu.autotune import search
+
+    def bench(cand):
+        if cand["impl"] != "gather":
+            raise RuntimeError("Mosaic failed to compile TPU kernel: "
+                               "Expected matmul acc to be 32-bit")
+        return lambda: 0.0
+
+    cands = [{"impl": "gather"}, {"impl": "pallas", "grid": "bh"},
+             {"impl": "pallas", "grid": "flat"}]
+    rejected = metrics.get("autotune_rejected_total")
+    r0 = rejected.total()
+    with caplog.at_level("WARNING", logger="mxnet_tpu.autotune"):
+        won = at.ensure("paged_attention", "cpu_sig", {"impl": "gather"},
+                        cands, bench, warmup=0, best_of=1)
+    assert won == {"impl": "gather"}
+    assert rejected.total() == r0 and not caplog.records
+    monkeypatch.setattr(search, "_on_tpu", lambda: True)
+    with caplog.at_level("WARNING", logger="mxnet_tpu.autotune"):
+        won = at.ensure("paged_attention", "tpu_sig", {"impl": "gather"},
+                        cands, bench, warmup=0, best_of=1)
+    assert won == {"impl": "gather"}
+    assert rejected.total() == r0 + 2
+    assert len(caplog.records) == 1, "logged once per search"
+    assert "Expected matmul acc to be 32-bit" in caplog.text
+
+
 def test_fingerprint_epoch_invalidates_on_record(sched_cache):
     fp0 = at.fingerprint()
     at.record("k", "sig", {"impl": "a"}, 1.0, 1)
@@ -239,15 +294,17 @@ def test_fingerprint_epoch_invalidates_on_record(sched_cache):
 # ---------------------------------------------------------------------------
 # paged-attention op parity
 # ---------------------------------------------------------------------------
-def _op_case(B=3, Hh=2, M=4, block=8, dh=32, Ll=2, seed=3):
+def _op_case(B=3, Hh=2, M=4, block=8, dh=128, Ll=2, seed=3,
+             dtype="float32"):
     rs = np.random.RandomState(seed)
     P = B * M + 1
     import jax.numpy as jnp
     pool_k = jnp.asarray(rs.normal(size=(P, Ll, Hh, block, dh))
-                         .astype(np.float32))
+                         .astype(np.float32)).astype(dtype)
     pool_v = jnp.asarray(rs.normal(size=(P, Ll, Hh, block, dh))
-                         .astype(np.float32))
-    q = jnp.asarray(rs.normal(size=(B, Hh, 1, dh)).astype(np.float32))
+                         .astype(np.float32)).astype(dtype)
+    q = jnp.asarray(rs.normal(size=(B, Hh, 1, dh))
+                    .astype(np.float32)).astype(dtype)
     bt = jnp.asarray(rs.permutation(np.arange(1, P))[:B * M]
                      .reshape(B, M).astype(np.int32))
     # ragged cursors: a nearly-empty, a mid, a nearly-full slot
@@ -264,7 +321,7 @@ def _run_op(sched, args, layer, block):
 
     f = jax.jit(lambda *a: pa.paged_attention(
         *a, layer, block=block, schedule=sched))
-    return np.asarray(f(*args))
+    return np.asarray(f(*args).astype("float32"))
 
 
 @pytest.mark.parametrize("grid", ["bh", "flat"])
@@ -282,6 +339,30 @@ def test_pallas_interpret_bitwise_vs_gather(no_cache, grid, live_only):
         assert np.array_equal(ref, out), (grid, live_only, layer)
 
 
+@pytest.mark.parametrize("grid", ["bh", "flat"])
+def test_pallas_interpret_bf16_close_to_gather(no_cache, grid):
+    """bf16 pages: the kernel accumulates both products and runs the
+    softmax in f32 (Mosaic takes no bf16 accumulator), gather does all
+    of it in bf16 — so the two agree to bf16 rounding of the gather
+    side, not bitwise.  Judged against the f32 math on the same
+    (bf16-rounded) inputs: the kernel must be at least as close."""
+    args = _op_case(block=16, dtype="bfloat16")
+    assert pa.supports(16, 128, "bfloat16")
+    f32 = tuple(a.astype("float32") if a.dtype == "bfloat16" else a
+                for a in args)
+    sched = {"impl": "pallas", "grid": grid, "live_only": True,
+             "interpret": True}
+    for layer in range(L):
+        exact = _run_op(None, f32, layer, 16)
+        ref = _run_op(None, args, layer, 16)
+        out = _run_op(sched, args, layer, 16)
+        assert np.isfinite(out).all()
+        scale = max(1.0, float(np.abs(exact).max()))
+        assert np.abs(out - ref).max() < 4e-2 * scale, (grid, layer)
+        assert np.abs(out - exact).max() <= \
+            np.abs(ref - exact).max() + 1e-2 * scale, (grid, layer)
+
+
 def test_pagewalk_allclose_vs_gather(no_cache):
     """The lax pagewalk reassociates the reductions (loop-carried
     accumulation) — allclose, deliberately NOT bitwise, which is why
@@ -294,11 +375,18 @@ def test_pagewalk_allclose_vs_gather(no_cache):
 
 
 def test_shape_gate_falls_back_bit_identical(no_cache):
-    """A shape the kernel cannot tile (block % 8 != 0) silently takes
-    the gather path even when the pallas schedule is forced — same
-    array, bit for bit."""
+    """A shape the kernel cannot tile (block % 8 != 0, dh short of the
+    128 lanes) takes the gather path by the gate even when the pallas
+    schedule is forced — same array, bit for bit."""
     args = _op_case(block=4, dh=12)
     assert not pa.supports(4, 12, np.float32)
+    # what the v5e compiler refused in rehearsal: a head narrower than
+    # the 128-lane tiling, and half a packed bf16 sublane tile
+    assert not pa.supports(16, 32, np.float32)
+    assert not pa.supports(16, 64, "bfloat16")
+    assert not pa.supports(8, 128, "bfloat16")
+    assert pa.supports(8, 128, np.float32)
+    assert pa.supports(16, 128, "bfloat16")
     ref = _run_op(None, args, 0, 4)
     out = _run_op({"impl": "pallas", "grid": "bh", "interpret": True},
                   args, 0, 4)
@@ -311,12 +399,17 @@ def test_candidate_schedules_and_keysig(no_cache):
     assert all(c["impl"] != "pallas" for c in cands), \
         "compiled-pallas candidates are TPU-only"
     assert {"impl": "pagewalk", "chunk": 3} not in cands  # 3 !| M=4
-    tpu = pa.candidate_schedules("tpu", 8, 32, 4, np.float32)
+    tpu = pa.candidate_schedules("tpu", 8, 128, 4, np.float32)
     assert any(c["impl"] == "pallas" for c in tpu)
-    assert pa.default_schedule("cpu", 8, 32, np.float32) == \
+    narrow = pa.candidate_schedules("tpu", 8, 32, 4, np.float32)
+    assert all(c["impl"] != "pallas" for c in narrow), \
+        "a head narrower than 128 lanes is gated off the kernel"
+    assert pa.default_schedule("cpu", 8, 128, np.float32) == \
         {"impl": "gather"}
-    assert pa.default_schedule("tpu", 8, 32, np.float32)["impl"] == \
+    assert pa.default_schedule("tpu", 8, 128, np.float32)["impl"] == \
         "pallas"
+    assert pa.default_schedule("tpu", 8, 32, np.float32) == \
+        {"impl": "gather"}
     assert pa.keysig(2, 4, 8, 16, 64, np.float32) == \
         "b2h4m8k16d64_float32"
 
@@ -350,12 +443,13 @@ def _drive(pg, seed=5):
     return outs
 
 
-def test_paged_slots_interpret_kernel_bitwise_end_to_end(decoder,
+def test_paged_slots_interpret_kernel_bitwise_end_to_end(wide_decoder,
                                                          no_cache):
     """The interpret-mode kernel drives the REAL serving backend —
     prefill, ragged decode steps, a fork admitting mid-flight behind
     the shared prefix block — bitwise against the gather backend at
     every emission."""
+    decoder = wide_decoder
     buckets = (8, 16, 32)
     ref = _drive(PagedSlots(decoder, 3, block=8, prefill_buckets=buckets,
                             kernel="gather"))

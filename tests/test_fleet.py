@@ -439,11 +439,18 @@ def test_bench_trend_tolerance_env(tmp_path, monkeypatch):
     assert r.returncode == 0
 
 
-def test_bench_trend_on_real_repo_trajectory():
-    """The committed trajectory must parse; r03+ are known fallbacks,
-    so the sentinel's verdict on the real repo is currently 'loud'."""
-    r = _run_trend(REPO)
-    assert r.returncode in (0, 1)
+def test_bench_trend_on_recorded_trajectory(tmp_path):
+    """The shape of the trajectory the repo once recorded — a crash, ONE
+    live round, then three backend-init fallbacks — must parse, name
+    the live round, and read 'loud' (the committed records themselves
+    were deleted in PR 21)."""
+    _write_round(tmp_path, 1, error="backend init failed")
+    _write_round(tmp_path, 2, {"value": 2251.15, "mfu": 0.1402})
+    for n in (3, 4, 5):
+        _write_round(tmp_path, n,
+                     error="backend init timed out after 240s")
+    r = _run_trend(tmp_path)
+    assert r.returncode == 1
     assert "rounds: live" in r.stdout
     assert "r02" in r.stdout
 

@@ -68,17 +68,12 @@ def measure_collective(sizes, num_devices, repeat):
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
-
     devices = jax.devices()[:num_devices]
     mesh = Mesh(np.array(devices), ("data",))
 
     @jax.jit
     def allreduce(*xs):
-        f = shard_map(lambda *ys: tuple(jax.lax.psum(y, "data") for y in ys),
+        f = jax.shard_map(lambda *ys: tuple(jax.lax.psum(y, "data") for y in ys),
                       mesh=mesh, in_specs=P("data"), out_specs=P("data"))
         return f(*xs)
 

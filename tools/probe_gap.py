@@ -1,7 +1,7 @@
 """Probe: decompose the framework-vs-raw-JAX ResNet-50 gap on the chip.
 
 Round 4 measured framework b32 = 2361 img/s vs a raw-JAX NHWC probe at
-2610 (docs/measured/probe_nhwc_r04.txt) — ~10% overhead that is by
+2610 (capture deleted in PR 21; not measured this round) — ~10% overhead that is by
 construction not roofline.  This probe splits it:
 
   device  — framework step time with the device saturated (the bench

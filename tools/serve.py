@@ -19,7 +19,9 @@ for the runbook.
     # paged KV cache with prefix reuse (16-token pages)
     python tools/serve.py --demo --kv-block 16
 
-    # a routed 2-replica local fleet (router + 2 replica subprocesses)
+    # a routed 2-replica local fleet (router + 2 replica subprocesses);
+    # a chip belongs to one process, so N replicas need N chips — or
+    # MXTPU_PLATFORM=cpu in the environment to keep them all off it
     python tools/serve.py --router --fleet 2 --demo --port 9100
 
     # router over existing replicas / a coordinator registry
@@ -96,7 +98,10 @@ def _parse_args(argv=None):
     ap.add_argument("--fleet", type=int, default=0, metavar="N",
                     help="with --router: spawn N local replica "
                          "subprocesses (same model flags) and route "
-                         "over them")
+                         "over them.  Each replica process claims a "
+                         "chip of its own: N replicas need N chips, or "
+                         "MXTPU_PLATFORM=cpu (the router itself never "
+                         "initialises a backend)")
     ap.add_argument("--replicas", default=None,
                     help="with --router: static host:port list "
                          "(MXTPU_SERVE_REPLICAS)")
@@ -173,9 +178,10 @@ def _arm_sigterm():
 
 
 def _main_replica(args):
-    from mxnet_tpu import telemetry
+    from mxnet_tpu import compile_cache, telemetry
     from mxnet_tpu.serving import serve_decoder
 
+    compile_cache.enable()  # replicas compile; the router never does
     telemetry.enable()  # a server without metrics is not operable
     if args.trace:
         telemetry.tracing.enable_tracing()

@@ -1,8 +1,7 @@
 """On-silicon training convergence check (parity: the reference's
 tests/python/train suite — test_mlp/test_conv assert accuracy, not just
 op numerics).  Trains two small models through the bf16 FusedTrainer on
-the REAL chip and asserts accuracy above floor; the window watcher
-commits the output as the 'training works on silicon' artifact.
+the REAL chip and asserts accuracy above floor.
 
 Run on the bench chip:  python tools/tpu_train_check.py
 CPU smoke:  MXTPU_PLATFORM=cpu python tools/tpu_train_check.py
@@ -88,6 +87,9 @@ def main():
         jax.config.update("jax_platforms", "cpu")
     import jax
 
+    from mxnet_tpu import compile_cache
+
+    compile_cache.enable()
     print("devices:", jax.devices(), flush=True)
     tic = time.perf_counter()
     check_mlp()
