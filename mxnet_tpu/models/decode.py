@@ -74,12 +74,20 @@ class _WeightProgram:
     minutes of compile each (measured against the v5e compiler) and a
     private copy of the weights in every executable.  ``fn(p, *args)``
     reads the weights from ``p``; every call hands the decoder's in.
-    Callable and ``.lower()``-able like the jit it wraps."""
+    The jitted function carries ``name``, so the XLA module
+    (``jit_<name>``) and its operations in a device trace say which
+    program they belong to.  Callable and ``.lower()``-able like the
+    jit it wraps."""
 
-    def __init__(self, decoder, fn):
+    def __init__(self, decoder, fn, name):
         self._dec = decoder
         view = type(decoder.p)      # dict, or the dequantize-on-read view
-        self._jit = jax.jit(lambda weights, *args: fn(view(weights), *args))
+
+        def program(weights, *args):
+            return fn(view(weights), *args)
+
+        program.__name__ = program.__qualname__ = name
+        self._jit = jax.jit(program)
 
     def __call__(self, *args):
         return self._jit(dict(self._dec.p), *args)
@@ -181,14 +189,15 @@ class KVDecoder:
         self.quantize = quantize
         self.p = p
         self._step_jit = _WeightProgram(
-            self, partial(self._forward_positions, n=1))
+            self, partial(self._forward_positions, n=1), "decode_step")
         self._reorder_jit = jax.jit(
             lambda kc, vc, idx: (kc[:, idx], vc[:, idx]))
         self._prefill_cache = {}
         self._scan_cache = {}
         self._padded_prefill_cache = {}
         self._slot_step_jit = _WeightProgram(
-            self, _count_compiles(self._forward_slots, "decode_step"))
+            self, _count_compiles(self._forward_slots, "decode_step"),
+            "decode_step_slots")
         # perf plane (telemetry/perf.py): one analytical cost row per
         # compiled decode program, captured at first dispatch
         self._cost_step_done = False
@@ -231,30 +240,31 @@ class KVDecoder:
         mask = jnp.arange(self.max_len)[None, :] <= span[:, None]  # (n, S)
         for i in range(self.L):
             name = f"layer{i}"
-            h2 = _ln(h, p[f"{name}_ln1_gamma"], p[f"{name}_ln1_beta"])
-            q, k, v = self._block_qkv(p, i, h2)
-            sh = lambda a: a.reshape(B, n, H, dh).transpose(0, 2, 1, 3)
-            qh, kh, vh = sh(q), sh(k), sh(v)         # (B, H, n, dh)
-            kc = jax.lax.dynamic_update_slice(
-                kc, kh[None], (i, 0, 0, pos, 0))
-            vc = jax.lax.dynamic_update_slice(
-                vc, vh[None], (i, 0, 0, pos, 0))
-            scores = jnp.einsum("bhnd,bhsd->bhns", qh, kc[i]) \
-                / jnp.sqrt(jnp.asarray(dh, h.dtype))
-            scores = jnp.where(mask[None, None], scores, NEG_INF)
-            att = jax.nn.softmax(scores, axis=-1)
-            ctx = jnp.einsum("bhns,bhsd->bhnd", att, vc[i])
-            ctx = ctx.transpose(0, 2, 1, 3).reshape(B, n, D)
-            proj = _fc(ctx, p[f"{name}_proj_weight"],
-                       p[f"{name}_proj_bias"])
-            h = h + proj
-            h2 = _ln(h, p[f"{name}_ln2_gamma"], p[f"{name}_ln2_beta"])
-            f = _fc(h2, p[f"{name}_ffn_in_weight"],
-                    p[f"{name}_ffn_in_bias"])
-            f = jax.nn.gelu(f)
-            f = _fc(f, p[f"{name}_ffn_out_weight"],
-                    p[f"{name}_ffn_out_bias"])
-            h = h + f
+            with jax.named_scope(name):
+                h2 = _ln(h, p[f"{name}_ln1_gamma"], p[f"{name}_ln1_beta"])
+                q, k, v = self._block_qkv(p, i, h2)
+                sh = lambda a: a.reshape(B, n, H, dh).transpose(0, 2, 1, 3)
+                qh, kh, vh = sh(q), sh(k), sh(v)         # (B, H, n, dh)
+                kc = jax.lax.dynamic_update_slice(
+                    kc, kh[None], (i, 0, 0, pos, 0))
+                vc = jax.lax.dynamic_update_slice(
+                    vc, vh[None], (i, 0, 0, pos, 0))
+                scores = jnp.einsum("bhnd,bhsd->bhns", qh, kc[i]) \
+                    / jnp.sqrt(jnp.asarray(dh, h.dtype))
+                scores = jnp.where(mask[None, None], scores, NEG_INF)
+                att = jax.nn.softmax(scores, axis=-1)
+                ctx = jnp.einsum("bhns,bhsd->bhnd", att, vc[i])
+                ctx = ctx.transpose(0, 2, 1, 3).reshape(B, n, D)
+                proj = _fc(ctx, p[f"{name}_proj_weight"],
+                           p[f"{name}_proj_bias"])
+                h = h + proj
+                h2 = _ln(h, p[f"{name}_ln2_gamma"], p[f"{name}_ln2_beta"])
+                f = _fc(h2, p[f"{name}_ffn_in_weight"],
+                        p[f"{name}_ffn_in_bias"])
+                f = jax.nn.gelu(f)
+                f = _fc(f, p[f"{name}_ffn_out_weight"],
+                        p[f"{name}_ffn_out_bias"])
+                h = h + f
         h = _ln(h, p["final_ln_gamma"], p["final_ln_beta"])
         logits = _fc(h, p["lm_head_weight"], p["lm_head_bias"])
         return (kc, vc), logits                      # logits (B, n, V)
@@ -285,7 +295,8 @@ class KVDecoder:
             raise ValueError(f"prompt {T} > max_len {self.max_len}")
         if T not in self._prefill_cache:
             self._prefill_cache[T] = _WeightProgram(
-                self, partial(self._forward_positions, n=T))
+                self, partial(self._forward_positions, n=T),
+                f"decode_prefill_t{T}")
         kc, vc, pos = self.init_state(B)
         (kc, vc), logits = self._prefill_cache[T](kc, vc, pos, tokens)
         return (kc, vc, pos + T), logits
@@ -336,28 +347,29 @@ class KVDecoder:
         rows = jnp.arange(B)
         for i in range(self.L):
             name = f"layer{i}"
-            h2 = _ln(h, p[f"{name}_ln1_gamma"], p[f"{name}_ln1_beta"])
-            q, k, v = self._block_qkv(p, i, h2)
-            sh = lambda a: a.reshape(B, 1, H, dh).transpose(0, 2, 1, 3)
-            qh, kh, vh = sh(q), sh(k), sh(v)                 # (B, H, 1, dh)
-            kc = kc.at[i, rows, :, cursor].set(kh[:, :, 0])
-            vc = vc.at[i, rows, :, cursor].set(vh[:, :, 0])
-            scores = jnp.einsum("bhnd,bhsd->bhns", qh, kc[i]) \
-                / jnp.sqrt(jnp.asarray(dh, h.dtype))
-            scores = jnp.where(valid[:, None, None, :], scores, NEG_INF)
-            att = jax.nn.softmax(scores, axis=-1)
-            ctx = jnp.einsum("bhns,bhsd->bhnd", att, vc[i])
-            ctx = ctx.transpose(0, 2, 1, 3).reshape(B, 1, D)
-            proj = _fc(ctx, p[f"{name}_proj_weight"],
-                       p[f"{name}_proj_bias"])
-            h = h + proj
-            h2 = _ln(h, p[f"{name}_ln2_gamma"], p[f"{name}_ln2_beta"])
-            f = _fc(h2, p[f"{name}_ffn_in_weight"],
-                    p[f"{name}_ffn_in_bias"])
-            f = jax.nn.gelu(f)
-            f = _fc(f, p[f"{name}_ffn_out_weight"],
-                    p[f"{name}_ffn_out_bias"])
-            h = h + f
+            with jax.named_scope(name):
+                h2 = _ln(h, p[f"{name}_ln1_gamma"], p[f"{name}_ln1_beta"])
+                q, k, v = self._block_qkv(p, i, h2)
+                sh = lambda a: a.reshape(B, 1, H, dh).transpose(0, 2, 1, 3)
+                qh, kh, vh = sh(q), sh(k), sh(v)             # (B, H, 1, dh)
+                kc = kc.at[i, rows, :, cursor].set(kh[:, :, 0])
+                vc = vc.at[i, rows, :, cursor].set(vh[:, :, 0])
+                scores = jnp.einsum("bhnd,bhsd->bhns", qh, kc[i]) \
+                    / jnp.sqrt(jnp.asarray(dh, h.dtype))
+                scores = jnp.where(valid[:, None, None, :], scores, NEG_INF)
+                att = jax.nn.softmax(scores, axis=-1)
+                ctx = jnp.einsum("bhns,bhsd->bhnd", att, vc[i])
+                ctx = ctx.transpose(0, 2, 1, 3).reshape(B, 1, D)
+                proj = _fc(ctx, p[f"{name}_proj_weight"],
+                           p[f"{name}_proj_bias"])
+                h = h + proj
+                h2 = _ln(h, p[f"{name}_ln2_gamma"], p[f"{name}_ln2_beta"])
+                f = _fc(h2, p[f"{name}_ffn_in_weight"],
+                        p[f"{name}_ffn_in_bias"])
+                f = jax.nn.gelu(f)
+                f = _fc(f, p[f"{name}_ffn_out_weight"],
+                        p[f"{name}_ffn_out_bias"])
+                h = h + f
         h = _ln(h, p["final_ln_gamma"], p["final_ln_beta"])
         logits = _fc(h, p["lm_head_weight"], p["lm_head_bias"])
         return (kc, vc), logits[:, 0]                        # (B, V)
@@ -386,28 +398,31 @@ class KVDecoder:
             (s_idx[None, None, :] >= lo[:, :, None])         # (B, T, S)
         for i in range(self.L):
             name = f"layer{i}"
-            h2 = _ln(h, p[f"{name}_ln1_gamma"], p[f"{name}_ln1_beta"])
-            q, k, v = self._block_qkv(p, i, h2)
-            sh = lambda a: a.reshape(B, T, H, dh).transpose(0, 2, 1, 3)
-            qh, kh, vh = sh(q), sh(k), sh(v)                 # (B, H, T, dh)
-            kc = jax.lax.dynamic_update_slice(kc, kh[None], (i, 0, 0, 0, 0))
-            vc = jax.lax.dynamic_update_slice(vc, vh[None], (i, 0, 0, 0, 0))
-            scores = jnp.einsum("bhnd,bhsd->bhns", qh, kc[i]) \
-                / jnp.sqrt(jnp.asarray(dh, h.dtype))
-            scores = jnp.where(valid[:, None], scores, NEG_INF)
-            att = jax.nn.softmax(scores, axis=-1)
-            ctx = jnp.einsum("bhns,bhsd->bhnd", att, vc[i])
-            ctx = ctx.transpose(0, 2, 1, 3).reshape(B, T, D)
-            proj = _fc(ctx, p[f"{name}_proj_weight"],
-                       p[f"{name}_proj_bias"])
-            h = h + proj
-            h2 = _ln(h, p[f"{name}_ln2_gamma"], p[f"{name}_ln2_beta"])
-            f = _fc(h2, p[f"{name}_ffn_in_weight"],
-                    p[f"{name}_ffn_in_bias"])
-            f = jax.nn.gelu(f)
-            f = _fc(f, p[f"{name}_ffn_out_weight"],
-                    p[f"{name}_ffn_out_bias"])
-            h = h + f
+            with jax.named_scope(name):
+                h2 = _ln(h, p[f"{name}_ln1_gamma"], p[f"{name}_ln1_beta"])
+                q, k, v = self._block_qkv(p, i, h2)
+                sh = lambda a: a.reshape(B, T, H, dh).transpose(0, 2, 1, 3)
+                qh, kh, vh = sh(q), sh(k), sh(v)             # (B, H, T, dh)
+                kc = jax.lax.dynamic_update_slice(
+                    kc, kh[None], (i, 0, 0, 0, 0))
+                vc = jax.lax.dynamic_update_slice(
+                    vc, vh[None], (i, 0, 0, 0, 0))
+                scores = jnp.einsum("bhnd,bhsd->bhns", qh, kc[i]) \
+                    / jnp.sqrt(jnp.asarray(dh, h.dtype))
+                scores = jnp.where(valid[:, None], scores, NEG_INF)
+                att = jax.nn.softmax(scores, axis=-1)
+                ctx = jnp.einsum("bhns,bhsd->bhnd", att, vc[i])
+                ctx = ctx.transpose(0, 2, 1, 3).reshape(B, T, D)
+                proj = _fc(ctx, p[f"{name}_proj_weight"],
+                           p[f"{name}_proj_bias"])
+                h = h + proj
+                h2 = _ln(h, p[f"{name}_ln2_gamma"], p[f"{name}_ln2_beta"])
+                f = _fc(h2, p[f"{name}_ffn_in_weight"],
+                        p[f"{name}_ffn_in_bias"])
+                f = jax.nn.gelu(f)
+                f = _fc(f, p[f"{name}_ffn_out_weight"],
+                        p[f"{name}_ffn_out_bias"])
+                h = h + f
         h = _ln(h, p["final_ln_gamma"], p["final_ln_beta"])
         logits = _fc(h, p["lm_head_weight"], p["lm_head_bias"])
         return (kc, vc), logits                              # (B, T, V)
@@ -439,7 +454,8 @@ class KVDecoder:
         if T not in self._padded_prefill_cache:
             self._padded_prefill_cache[T] = _WeightProgram(
                 self,
-                _count_compiles(self._forward_padded, "decode_prefill"))
+                _count_compiles(self._forward_padded, "decode_prefill"),
+                f"decode_prefill_padded_t{T}")
         kc, vc, _ = self.init_state(B)
         start = (T - lengths).astype(np.int32)
         (kc, vc), logits = self._padded_prefill_cache[T](
@@ -621,7 +637,8 @@ class KVDecoder:
                 return kc, vc, buf.transpose(1, 0)
 
             fn = _WeightProgram(
-                self, loop if eos_id is None else loop_eos)
+                self, loop if eos_id is None else loop_eos,
+                "decode_generate_loop")
             self._scan_cache[key] = fn
         kc, vc, toks = fn(kc, vc, jnp.int32(pos),
                           logits[:, -1].astype(jnp.float32),

@@ -97,7 +97,8 @@ def get_symbol(num_classes=1000, **kwargs):
 # share, so the bench extra and the probe sweep can never desynchronize.
 # ---------------------------------------------------------------------------
 
-# the compute-bound headline config (~220M params): big enough matmuls to
+# the compute-bound headline config (~540M params with the untied head;
+# 613 M at the benchmark's 50257-token vocabulary): big enough matmuls to
 # feed the MXU, small enough that Adam state + activations fit one v5e
 # chosen by an on-silicon sweep taken before PR 1 (capture deleted in
 # PR 21; not measured this round): the d2048 8-layer config more than doubles the d1024 12-layer's MFU

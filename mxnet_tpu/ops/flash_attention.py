@@ -186,6 +186,7 @@ def _fwd_impl(q, k, v, scale, causal, block_q, block_k, interpret):
             jax.ShapeDtypeStruct((bh, t, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(q3, k3, v3)
     return o.reshape(b, h, t, d), lse.reshape(b, h, t)
 
@@ -217,6 +218,7 @@ def _bwd_impl(q, k, v, o, lse, do, scale, causal, block_q, block_k,
         out_specs=pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, t, d), q.dtype),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(q3, k3, v3, do3, lse3, delta3)
 
     dkv_kern = functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
@@ -241,6 +243,7 @@ def _bwd_impl(q, k, v, o, lse, do, scale, causal, block_q, block_k,
             jax.ShapeDtypeStruct((bh, t, d), v.dtype),
         ],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(q3, k3, v3, do3, lse3, delta3)
     return (dq.reshape(b, h, t, d), dk.reshape(b, h, t, d),
             dv.reshape(b, h, t, d))

@@ -259,6 +259,7 @@ def _pallas_attention(q, pool_k, pool_v, bt, cursor, layer, block,
         out_shape=jax.ShapeDtypeStruct((B, H, 1, dh), q.dtype),
         grid_spec=gs,
         interpret=interpret,
+        name="paged_attn",
     )(bt.astype(jnp.int32), cursor.astype(jnp.int32), q, pool_k, pool_v)
 
 

@@ -128,6 +128,7 @@ def _pallas_fwd(x2, s2, scale, bias, interpret, block_rows=None):
         out_specs=pl.BlockSpec((br, c), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, c), x2.dtype),
         interpret=interpret,
+        name="residual_epilogue",
     )(x2, s2, sc2, b2)
 
 

@@ -131,7 +131,7 @@ class _PagedPrograms:
         self.schedule = schedule if (
             schedule and schedule.get("impl") != "gather") else None
         self._step_jit = _WeightProgram(decoder, _count_compiles(
-            self._forward_step, "decode_step_paged"))
+            self._forward_step, "decode_step_paged"), "decode_step_paged")
         self._prefill_cache = {}
 
     def init_pool(self):
@@ -188,40 +188,41 @@ class _PagedPrograms:
             from ..ops import paged_attention as _pa
         for i in range(d.L):
             name = f"layer{i}"
-            h2 = _ln(h, p[f"{name}_ln1_gamma"], p[f"{name}_ln1_beta"])
-            q, k, v = d._block_qkv(p, i, h2)
-            sh = lambda a: a.reshape(B, 1, H, dh).transpose(0, 2, 1, 3)
-            qh, kh, vh = sh(q), sh(k), sh(v)                 # (B, H, 1, dh)
-            if sched is None:
-                kc = kc.at[i, rows, :, cursor].set(kh[:, :, 0])
-                vc = vc.at[i, rows, :, cursor].set(vh[:, :, 0])
-            pool_k = pool_k.at[pages, i, :, offs].set(kh[:, :, 0])
-            pool_v = pool_v.at[pages, i, :, offs].set(vh[:, :, 0])
-            if sched is None:
-                scores = jnp.einsum("bhnd,bhsd->bhns", qh, kc[i]) \
-                    / jnp.sqrt(jnp.asarray(dh, h.dtype))
-                scores = jnp.where(
-                    valid[:, None, None, :], scores, NEG_INF)
-                att = jax.nn.softmax(scores, axis=-1)
-                ctx = jnp.einsum("bhns,bhsd->bhnd", att, vc[i])
-            else:
-                # the kernel walks the block table over the pool the
-                # writes above just updated — same values the gathered
-                # table would hold, no materialization
-                ctx = _pa.paged_attention(
-                    qh, pool_k, pool_v, bt, cursor, i,
-                    block=self.block, schedule=sched)
-            ctx = ctx.transpose(0, 2, 1, 3).reshape(B, 1, D)
-            proj = _fc(ctx, p[f"{name}_proj_weight"],
-                       p[f"{name}_proj_bias"])
-            h = h + proj
-            h2 = _ln(h, p[f"{name}_ln2_gamma"], p[f"{name}_ln2_beta"])
-            f = _fc(h2, p[f"{name}_ffn_in_weight"],
-                    p[f"{name}_ffn_in_bias"])
-            f = jax.nn.gelu(f)
-            f = _fc(f, p[f"{name}_ffn_out_weight"],
-                    p[f"{name}_ffn_out_bias"])
-            h = h + f
+            with jax.named_scope(name):
+                h2 = _ln(h, p[f"{name}_ln1_gamma"], p[f"{name}_ln1_beta"])
+                q, k, v = d._block_qkv(p, i, h2)
+                sh = lambda a: a.reshape(B, 1, H, dh).transpose(0, 2, 1, 3)
+                qh, kh, vh = sh(q), sh(k), sh(v)             # (B, H, 1, dh)
+                if sched is None:
+                    kc = kc.at[i, rows, :, cursor].set(kh[:, :, 0])
+                    vc = vc.at[i, rows, :, cursor].set(vh[:, :, 0])
+                pool_k = pool_k.at[pages, i, :, offs].set(kh[:, :, 0])
+                pool_v = pool_v.at[pages, i, :, offs].set(vh[:, :, 0])
+                if sched is None:
+                    scores = jnp.einsum("bhnd,bhsd->bhns", qh, kc[i]) \
+                        / jnp.sqrt(jnp.asarray(dh, h.dtype))
+                    scores = jnp.where(
+                        valid[:, None, None, :], scores, NEG_INF)
+                    att = jax.nn.softmax(scores, axis=-1)
+                    ctx = jnp.einsum("bhns,bhsd->bhnd", att, vc[i])
+                else:
+                    # the kernel walks the block table over the pool the
+                    # writes above just updated — same values the gathered
+                    # table would hold, no materialization
+                    ctx = _pa.paged_attention(
+                        qh, pool_k, pool_v, bt, cursor, i,
+                        block=self.block, schedule=sched)
+                ctx = ctx.transpose(0, 2, 1, 3).reshape(B, 1, D)
+                proj = _fc(ctx, p[f"{name}_proj_weight"],
+                           p[f"{name}_proj_bias"])
+                h = h + proj
+                h2 = _ln(h, p[f"{name}_ln2_gamma"], p[f"{name}_ln2_beta"])
+                f = _fc(h2, p[f"{name}_ffn_in_weight"],
+                        p[f"{name}_ffn_in_bias"])
+                f = jax.nn.gelu(f)
+                f = _fc(f, p[f"{name}_ffn_out_weight"],
+                        p[f"{name}_ffn_out_bias"])
+                h = h + f
         h = _ln(h, p["final_ln_gamma"], p["final_ln_beta"])
         logits = _fc(h, p["lm_head_weight"], p["lm_head_bias"])
         return (pool_k, pool_v), logits[:, 0]                # (B, V)
@@ -267,32 +268,33 @@ class _PagedPrograms:
         vc = self._gather(pool_v, bt_row[None])
         for i in range(d.L):
             name = f"layer{i}"
-            h2 = _ln(h, p[f"{name}_ln1_gamma"], p[f"{name}_ln1_beta"])
-            q, k, v = d._block_qkv(p, i, h2)
-            sh = lambda a: a.reshape(1, T, H, dh).transpose(0, 2, 1, 3)
-            qh, kh, vh = sh(q), sh(k), sh(v)                 # (1, H, T, dh)
-            k_t = kh[0].transpose(1, 0, 2)                   # (T, H, dh)
-            v_t = vh[0].transpose(1, 0, 2)
-            kc = kc.at[i, 0, :, wpos].set(k_t)
-            vc = vc.at[i, 0, :, wpos].set(v_t)
-            pool_k = pool_k.at[pages, i, :, offs].set(k_t)
-            pool_v = pool_v.at[pages, i, :, offs].set(v_t)
-            scores = jnp.einsum("bhnd,bhsd->bhns", qh, kc[i]) \
-                / jnp.sqrt(jnp.asarray(dh, h.dtype))
-            scores = jnp.where(valid[None, None], scores, NEG_INF)
-            att = jax.nn.softmax(scores, axis=-1)
-            ctx = jnp.einsum("bhns,bhsd->bhnd", att, vc[i])
-            ctx = ctx.transpose(0, 2, 1, 3).reshape(1, T, D)
-            proj = _fc(ctx, p[f"{name}_proj_weight"],
-                       p[f"{name}_proj_bias"])
-            h = h + proj
-            h2 = _ln(h, p[f"{name}_ln2_gamma"], p[f"{name}_ln2_beta"])
-            f = _fc(h2, p[f"{name}_ffn_in_weight"],
-                    p[f"{name}_ffn_in_bias"])
-            f = jax.nn.gelu(f)
-            f = _fc(f, p[f"{name}_ffn_out_weight"],
-                    p[f"{name}_ffn_out_bias"])
-            h = h + f
+            with jax.named_scope(name):
+                h2 = _ln(h, p[f"{name}_ln1_gamma"], p[f"{name}_ln1_beta"])
+                q, k, v = d._block_qkv(p, i, h2)
+                sh = lambda a: a.reshape(1, T, H, dh).transpose(0, 2, 1, 3)
+                qh, kh, vh = sh(q), sh(k), sh(v)             # (1, H, T, dh)
+                k_t = kh[0].transpose(1, 0, 2)                   # (T, H, dh)
+                v_t = vh[0].transpose(1, 0, 2)
+                kc = kc.at[i, 0, :, wpos].set(k_t)
+                vc = vc.at[i, 0, :, wpos].set(v_t)
+                pool_k = pool_k.at[pages, i, :, offs].set(k_t)
+                pool_v = pool_v.at[pages, i, :, offs].set(v_t)
+                scores = jnp.einsum("bhnd,bhsd->bhns", qh, kc[i]) \
+                    / jnp.sqrt(jnp.asarray(dh, h.dtype))
+                scores = jnp.where(valid[None, None], scores, NEG_INF)
+                att = jax.nn.softmax(scores, axis=-1)
+                ctx = jnp.einsum("bhns,bhsd->bhnd", att, vc[i])
+                ctx = ctx.transpose(0, 2, 1, 3).reshape(1, T, D)
+                proj = _fc(ctx, p[f"{name}_proj_weight"],
+                           p[f"{name}_proj_bias"])
+                h = h + proj
+                h2 = _ln(h, p[f"{name}_ln2_gamma"], p[f"{name}_ln2_beta"])
+                f = _fc(h2, p[f"{name}_ffn_in_weight"],
+                        p[f"{name}_ffn_in_bias"])
+                f = jax.nn.gelu(f)
+                f = _fc(f, p[f"{name}_ffn_out_weight"],
+                        p[f"{name}_ffn_out_bias"])
+                h = h + f
         h = _ln(h, p["final_ln_gamma"], p["final_ln_beta"])
         logits = _fc(h, p["lm_head_weight"], p["lm_head_bias"])
         return (pool_k, pool_v), logits                      # (1, T, V)
@@ -305,7 +307,8 @@ class _PagedPrograms:
 
             self._prefill_cache[bucket] = _WeightProgram(
                 self.dec, _count_compiles(self._forward_prefill,
-                                          "decode_prefill_paged"))
+                                          "decode_prefill_paged"),
+                f"prefill_paged_b{bucket}")
         return self._prefill_cache[bucket]
 
 

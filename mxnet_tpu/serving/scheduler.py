@@ -87,6 +87,10 @@ class AdmissionQueueFull(MXNetError):
     """The bounded admission queue is full — shed load (HTTP 429)."""
 
 
+def _ms(seconds):
+    return None if seconds is None else round(seconds * 1000.0, 3)
+
+
 def _env_int(name, default):
     v = os.environ.get(name)
     return default if not v else int(v)
@@ -149,6 +153,9 @@ class Request:
         self.sampled = bool(sampled)
         self.queue_wait = None
         self.tokens = []
+        # one time.monotonic() stamp per token, on arrival's clock: the
+        # reply's token_ms and the terminal span's gaps_ms come from here
+        self.token_times = []
         self.outcome = None   # ok | timeout | error | shutdown
         self.error = None
         self.ttft = None
@@ -438,9 +445,10 @@ class SlotScheduler:
     def _run(self):
         while True:
             with self._cond:
-                while (not self._stop and not self._queue
-                       and all(r is None for r in self.slots)):
-                    self._cond.wait(self._idle_wait)
+                if self._nothing_to_do():
+                    with _tracing.phase("engine.idle", "engine"):
+                        while self._nothing_to_do():
+                            self._cond.wait(self._idle_wait)
                 if self._stop:
                     return
             # the engine thread must OUTLIVE any single bad request: an
@@ -459,6 +467,11 @@ class SlotScheduler:
                     if req is not None:
                         req.error = exc
                         self._finish_slot(i, "error")
+
+    def _nothing_to_do(self):
+        """Nothing queued, no slot busy, not stopping (under _cond)."""
+        return (not self._stop and not self._queue
+                and all(r is None for r in self.slots))
 
     def _expire_queued(self, now):
         with self._cond:
@@ -486,56 +499,69 @@ class SlotScheduler:
                     return
                 req = self._queue.popleft()
                 _TM_QUEUE.set(len(self._queue))
-            req.queue_wait = time.monotonic() - req.arrival
-            _TM_QWAIT.observe(req.queue_wait)
-            traced = req.sampled and _tracing.trace_on()
-            if traced:
-                _tracing.record_span(
-                    "queue_wait", "replica", req.trace, req.queue_wait,
-                    parent=req.parent, request=req.id)
-            t_admit0 = time.perf_counter()
-            try:
-                # the whole admission for THIS request — prefill, first
-                # sample, cache write — fails only this request; the
-                # slot stays free and the engine moves on
-                from .. import faults as _faults
+            self._admit_one(free, req)
 
+    def _admit_one(self, free, req):
+        """One request's whole admission — prefill, first sample, cache
+        write — as the span ``engine.admit``; it fails only this
+        request: the slot stays free and the engine moves on."""
+        from .. import faults as _faults
+
+        req.queue_wait = time.monotonic() - req.arrival
+        _TM_QWAIT.observe(req.queue_wait)
+        traced = req.sampled and _tracing.trace_on()
+        if traced:
+            _tracing.record_span(
+                "queue_wait", "replica", req.trace, req.queue_wait,
+                parent=req.parent, request=req.id)
+        plen = int(req.prompt.size)
+        bucket = next(b for b in self.prefill_buckets if b >= plen)
+        admitted = False
+        with _tracing.phase(
+                "engine.admit", "engine", request=req.id, slot=free,
+                prompt_len=plen, bucket=bucket,
+                queue_wait_ms=_ms(req.queue_wait)) as adm:
+            try:
                 _faults.maybe_fail("serve_admit")
-                t_pf0 = time.perf_counter()
-                logits = self.backend.admit(
-                    free, req.prompt,
-                    trace=(req.trace if traced else None))
-                pf_dur = time.perf_counter() - t_pf0
-                first = self._sample(req, np.asarray(logits, np.float32))
+                # backend.admit returns a device array without waiting:
+                # the fetch belongs to the prefill, not to sampling
+                with _tracing.phase("engine.prefill", "engine",
+                                    request=req.id, bucket=bucket) as pf:
+                    logits = self.backend.admit(
+                        free, req.prompt,
+                        trace=(req.trace if traced else None))
+                    logits = np.asarray(logits, np.float32)
+                first = self._sample(req, logits)
             except Exception as exc:  # noqa: BLE001
                 self.backend.release(free)
                 req.error = exc
                 self._terminal(req, "error")
-                continue
-            self._next_tok[free] = first
-            if self._slot_used[free]:
-                _TM_REUSE.inc()
-            self._slot_used[free] = True
-            self.slots[free] = req
-            req.tokens.append(first)
-            req.ttft = time.monotonic() - req.arrival
-            _TM_TTFT.observe(req.ttft)
-            _TM_TOKENS.inc()
-            self.stats["admitted"] += 1
-            _TM_OCCUPANCY.set(self.occupied)
-            if traced:
-                plen = int(req.prompt.size)
-                bucket = next(b for b in self.prefill_buckets
-                              if b >= plen)
-                _tracing.record_span(
-                    "prefill", "replica", req.trace, pf_dur,
-                    parent=req.parent, bucket=bucket, prompt_len=plen,
-                    request=req.id)
-                _tracing.record_span(
-                    "admit", "replica", req.trace,
-                    time.perf_counter() - t_admit0, parent=req.parent,
-                    slot=free, request=req.id)
-            self._maybe_finish(free, time.monotonic())
+            else:
+                admitted = True
+                self._next_tok[free] = first
+                if self._slot_used[free]:
+                    _TM_REUSE.inc()
+                self._slot_used[free] = True
+                self.slots[free] = req
+                req.tokens.append(first)
+                now = time.monotonic()
+                req.token_times.append(now)
+                req.ttft = now - req.arrival
+                _TM_TTFT.observe(req.ttft)
+                _TM_TOKENS.inc()
+                self.stats["admitted"] += 1
+                _TM_OCCUPANCY.set(self.occupied)
+                self._maybe_finish(free, now)
+        if traced and admitted and adm.t1 is not None:
+            # the per-request records of a router-sampled request, from
+            # the phases' own stamps (traced implies they were live)
+            _tracing.record_span(
+                "prefill", "replica", req.trace, pf.t1 - pf.t0,
+                parent=req.parent, bucket=bucket, prompt_len=plen,
+                request=req.id)
+            _tracing.record_span(
+                "admit", "replica", req.trace, adm.t1 - adm.t0,
+                parent=req.parent, slot=free, request=req.id)
 
     def _tick(self):
         """ONE jitted decode step over the whole pool + host sampling."""
@@ -550,56 +576,68 @@ class SlotScheduler:
         # inflate — the SLO plane's violation paths ride this in tests
         if _faults.active() and _faults.should_drop("serve_slow"):
             time.sleep(_tm.health._fault_slow_s())
-        t0 = time.perf_counter()
         occupied = [i for i, r in enumerate(self.slots) if r is not None]
-        # sampled decode-tick spans (ISSUE 16): every TICK_EVERY-th tick
-        # records one span per sampled live request — pure host dict
-        # writes after the tick, so the zero-host-sync invariant holds;
-        # requests are captured NOW because _finish_slot clears slots
-        tick_reqs = ()
-        if _tracing.trace_on() \
-                and self.stats["ticks"] % _tracing.TICK_EVERY == 0:
-            tick_reqs = [(i, self.slots[i]) for i in occupied
-                         if self.slots[i].sampled]
-        occ_mask = np.array([r is not None for r in self.slots])
-        logits, starved = self.backend.step(self._next_tok, occ_mask)
-        logits = np.asarray(logits, np.float32)   # the ONE host sync/tick
-        t_fetch = time.perf_counter()
-        now = time.monotonic()
-        for i in occupied:
-            if i in starved:
-                # page pool exhausted mid-generation: deliver what was
-                # generated so far (the paged analog of the contiguous
-                # cache-window truncation — documented in serving.md)
-                self._finish_slot(i, "ok")
-                continue
-            req = self.slots[i]
-            nxt = self._sample(req, logits[i])
-            req.tokens.append(nxt)
-            self._next_tok[i] = nxt
-            _TM_TOKENS.inc()
-            self._maybe_finish(i, now)
-        self.stats["ticks"] += 1
-        self.stats["slot_ticks"] += len(occupied)
-        tick_dur = time.perf_counter() - t0
-        _TM_TICK.observe(tick_dur)
-        if _tm.perf.enabled() and occupied:
-            # perf-attribution plane (docs/perf_attr.md): the tick wall
-            # splits into the decode dispatch (step + the one logits
-            # fetch above) and the host sampling loop — perf_counter
-            # stamps the tick already takes, no extra device sync
-            _tm.perf.record_dispatch(
-                "decode_step_paged"
-                if getattr(self.backend, "paged", False)
-                else "decode_step_slots", t_fetch - t0)
-            _tm.perf.record_step_buckets(
-                wall_s=tick_dur, dispatch=t_fetch - t0,
-                sample=tick_dur - (t_fetch - t0))
-        for i, req in tick_reqs:
-            _tracing.record_span(
-                "decode_tick", "replica", req.trace, tick_dur,
-                parent=req.parent, slot=i, tick=self.stats["ticks"] - 1,
-                tokens=len(req.tokens), request=req.id)
+        n = self.stats["ticks"]
+        with _tracing.phase("engine.tick", "engine", tick=n,
+                            occupied=len(occupied)) as tick:
+            # each stamp is read once: from the phase when someone is
+            # looking, else here (the tick histogram needs it always)
+            t0 = tick.t0 or time.perf_counter()
+            # sampled decode-tick spans (ISSUE 16): every TICK_EVERY-th
+            # tick records one span per sampled live request — pure host
+            # dict writes after the tick, so the zero-host-sync invariant
+            # holds; requests are captured NOW because _finish_slot
+            # clears slots
+            tick_reqs = ()
+            if _tracing.trace_on() and n % _tracing.TICK_EVERY == 0:
+                tick_reqs = [(i, self.slots[i]) for i in occupied
+                             if self.slots[i].sampled]
+            occ_mask = np.array([r is not None for r in self.slots])
+            with _tracing.phase("engine.step", "engine", tick=n) as step:
+                logits, starved = self.backend.step(self._next_tok,
+                                                    occ_mask)
+                # the ONE host sync/tick
+                logits = np.asarray(logits, np.float32)
+            t_fetch = step.t1 or time.perf_counter()
+            with _tracing.phase("engine.sample", "engine",
+                                tick=n) as sample:
+                now = time.monotonic()
+                for i in occupied:
+                    if i in starved:
+                        # page pool exhausted mid-generation: deliver
+                        # what was generated so far (the paged analog of
+                        # the contiguous cache-window truncation —
+                        # documented in serving.md)
+                        self._finish_slot(i, "ok")
+                        continue
+                    req = self.slots[i]
+                    nxt = self._sample(req, logits[i])
+                    req.tokens.append(nxt)
+                    req.token_times.append(now)
+                    self._next_tok[i] = nxt
+                    _TM_TOKENS.inc()
+                    self._maybe_finish(i, now)
+                self.stats["ticks"] += 1
+                self.stats["slot_ticks"] += len(occupied)
+            tick_dur = (sample.t1 or time.perf_counter()) - t0
+            _TM_TICK.observe(tick_dur)
+            if _tm.perf.enabled() and occupied:
+                # perf-attribution plane (docs/perf_attr.md): the tick
+                # wall splits into the decode dispatch (step + the one
+                # logits fetch above) and the host sampling loop — the
+                # stamps the tick already takes, no extra device sync
+                _tm.perf.record_dispatch(
+                    "decode_step_paged"
+                    if getattr(self.backend, "paged", False)
+                    else "decode_step_slots", t_fetch - t0)
+                _tm.perf.record_step_buckets(
+                    wall_s=tick_dur, dispatch=t_fetch - t0,
+                    sample=tick_dur - (t_fetch - t0))
+            for i, req in tick_reqs:
+                _tracing.record_span(
+                    "decode_tick", "replica", req.trace, tick_dur,
+                    parent=req.parent, slot=i, tick=n,
+                    tokens=len(req.tokens), request=req.id)
 
     def _maybe_finish(self, slot, now):
         req = self.slots[slot]
@@ -631,17 +669,24 @@ class SlotScheduler:
         _TM_REQS.inc(outcome=outcome)
         wall = time.monotonic() - req.arrival
         _TM_REQ_SEC.observe(wall)
-        if req.sampled and _tracing.trace_on():
+        if _tracing.recording():
             # the terminal span covers the whole request (arrival →
-            # outcome) and mirrors into the PR-5 flight ring so
-            # post-mortem dumps carry the trace id
+            # outcome), router-sampled or not, and carries what the
+            # request waited for: the queue, its first token, and each
+            # gap between two tokens
+            times = req.token_times
             _tracing.record_span(
                 "request", "replica", req.trace, wall,
                 parent=req.parent, outcome=outcome,
-                tokens=len(req.tokens), request=req.id)
-            _tm.record_step(
-                loop="serve", trace=req.trace, outcome=outcome,
-                wall_s=wall, ttft_s=req.ttft)
+                tokens=len(req.tokens), request=req.id,
+                queue_wait_ms=_ms(req.queue_wait), ttft_ms=_ms(req.ttft),
+                gaps_ms=[_ms(b - a) for a, b in zip(times, times[1:])])
+            if req.sampled and _tracing.trace_on():
+                # mirrored into the PR-5 flight ring so post-mortem
+                # dumps carry the trace id
+                _tm.record_step(
+                    loop="serve", trace=req.trace, outcome=outcome,
+                    wall_s=wall, ttft_s=req.ttft)
         req._event.set()
 
     @staticmethod

@@ -11,8 +11,10 @@ Endpoints:
 ``POST /generate``
     body: ``{"prompt": [token ids], "max_tokens": 16, "temperature": 0,
     "top_k": null, "eos_id": null, "deadline_ms": null, "seed": 0}``.
-    200: ``{"tokens": [...], "outcome": "ok", "ttft_ms": ..,
-    "queue_wait_ms": .., "latency_ms": ..}``.  429 when the bounded
+    200: ``{"tokens": [...], "n_tokens": .., "outcome": "ok",
+    "ttft_ms": .., "queue_wait_ms": .., "token_ms": [..]}``
+    (``token_ms``: each token's time from receipt; ``token_ms[0] ==
+    ttft_ms``).  429 when the bounded
     admission queue is full (body carries ``Retry-After`` guidance),
     504 when the deadline expires (partial ``tokens`` included), 400 on
     malformed input, 500 on an engine error.  A ``traceparent`` request
@@ -124,6 +126,11 @@ def _request_json(req):
         else None,
         "queue_wait_ms": round(req.queue_wait * 1000.0, 3)
         if req.queue_wait is not None else None,
+        # when each token was sampled, from receipt: token_ms[0] is
+        # ttft_ms, the differences are the gaps a streaming client
+        # would have felt
+        "token_ms": [round((t - req.arrival) * 1000.0, 3)
+                     for t in req.token_times],
     }
     if req.trace is not None:
         out["trace"] = req.trace

@@ -310,19 +310,29 @@ def span(name: str, category: str = "host", device: str = "host",
     with ``labels``.  ``trace`` additionally lands the region in the
     distributed-tracing span buffer under that trace id when request
     tracing is on (``telemetry/tracing.py`` — the ``GET /spans.json``
-    lens).  ``sync`` is an optional zero-arg callable run before
-    closing (e.g. ``block_until_ready``) so async dispatch doesn't
-    under-report.  When every sink is off the region runs untimed.
+    lens).  While a ``jax.profiler`` session runs, the region is also a
+    ``TraceAnnotation`` on the profiler's clock (the sink
+    ``tracing.phase`` writes).  ``sync`` is an optional zero-arg
+    callable run before closing (e.g. ``block_until_ready``) so async
+    dispatch doesn't under-report.  When every sink is off the region
+    runs untimed.
     """
     from .. import profiler as _prof
     from . import tracing as _tracing
 
     prof_on = _prof.is_running()
     trace_on = trace is not None and _tracing.trace_on()
-    if not (prof_on or _state.enabled or trace_on):
+    # a jax.profiler session: the region also goes on the profiler's
+    # own clock, beside tracing.phase()'s spans and the device's ops
+    annotation = _tracing._TraceAnnotation(name) \
+        if _tracing._TraceAnnotation.is_enabled() else None
+    if not (prof_on or _state.enabled or trace_on
+            or annotation is not None):
         yield
         return
     us0 = _prof.now_us() if prof_on else 0.0
+    if annotation is not None:
+        annotation.__enter__()
     t0 = time.perf_counter()
     try:
         yield
@@ -333,6 +343,8 @@ def span(name: str, category: str = "host", device: str = "host",
             except Exception:
                 pass
         dt = time.perf_counter() - t0
+        if annotation is not None:
+            annotation.__exit__(None, None, None)
         if prof_on:
             _prof.record(name, device, us0, _prof.now_us(), category)
         if trace_on:

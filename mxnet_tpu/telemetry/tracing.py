@@ -54,6 +54,9 @@ import threading
 import time
 from collections import deque
 
+from jax.profiler import StepTraceAnnotation as _StepTraceAnnotation
+from jax.profiler import TraceAnnotation as _TraceAnnotation
+
 from . import registry as _reg
 
 __all__ = [
@@ -61,6 +64,7 @@ __all__ = [
     "mint_traceparent", "parse_traceparent", "child_traceparent",
     "mint_span_id", "record_span", "spans", "spans_payload",
     "clear_spans", "slo_ttft_ms", "slo_avail", "SloPlane", "TICK_EVERY",
+    "recording", "phase",
 ]
 
 # --- tracing + SLO metric families (docs/telemetry.md) ----------------------
@@ -109,6 +113,14 @@ def trace_on() -> bool:
 def enable_tracing(on: bool = True):
     """Turn span recording on/off at runtime (bench A/B, tests)."""
     _state.enabled = bool(on)
+
+
+def recording() -> bool:
+    """Is anyone looking?  True when :func:`trace_on` or a
+    ``jax.profiler`` session is running: the two conditions under which
+    :func:`phase` records.  An attribute read and one call into the
+    profiler's flag -- cheap enough for every tick-path call site."""
+    return _state.enabled or _TraceAnnotation.is_enabled()
 
 
 def sample_rate() -> float:
@@ -199,6 +211,13 @@ _spans_lock = threading.Lock()
 _span_seq = 0
 
 
+def _next_sid() -> str:
+    global _span_seq
+    with _spans_lock:
+        _span_seq += 1
+        return "%d-%d" % (os.getpid(), _span_seq)
+
+
 def record_span(name, svc, trace, dur_s, t=None, parent=None, span=None,
                 **attrs):
     """Append one span: a pure host-side dict + deque write (the lint
@@ -209,12 +228,15 @@ def record_span(name, svc, trace, dur_s, t=None, parent=None, span=None,
     cross-host joins shift ``t`` by the clock offset and draw
     ``[t - dur_s, t]``.  ``trace`` may be None for ambient process
     events (e.g. a step-time KV eviction with no admitting request).
-    Extra ``attrs`` land flat on the record; reserved keys lose."""
-    global _spans, _span_seq
+    Extra ``attrs`` land flat on the record; reserved keys lose.  A
+    record written while a ``jax.profiler`` session runs says so
+    (``prof: true``): those are the spans a traced window holds."""
+    global _spans
     rec = dict(attrs)
+    if _TraceAnnotation.is_enabled():
+        rec["prof"] = True
+    sid = span or _next_sid()
     with _spans_lock:
-        _span_seq += 1
-        sid = span or ("%d-%d" % (os.getpid(), _span_seq))
         rec.update(sid=sid, trace=trace, parent=parent, name=str(name),
                    svc=str(svc), t=(time.time() if t is None else float(t)),
                    dur_s=float(dur_s))
@@ -223,6 +245,87 @@ def record_span(name, svc, trace, dur_s, t=None, parent=None, span=None,
         _spans.append(rec)
     _TM_SPANS.inc(svc=str(svc))
     return rec
+
+
+# ---------------------------------------------------------------------------
+# phases: the engine's and the trainer's own spans, on the profiler's clock
+# ---------------------------------------------------------------------------
+class _NoPhase:
+    """What :func:`phase` hands out while nobody is looking: one shared
+    object, no clock read, nothing recorded."""
+
+    __slots__ = ()
+    t0 = t1 = None
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+_NO_PHASE = _NoPhase()
+_open_phases = threading.local()
+
+
+class _Phase:
+    """One open :func:`phase`.  ``t0`` / ``t1`` are its
+    ``time.perf_counter()`` stamps (``t1`` once closed), so that a
+    caller which needs the same duration for another sink takes it from
+    here and not from a second pair of clock reads."""
+
+    __slots__ = ("name", "svc", "trace", "attrs", "parent", "sid", "t0",
+                 "t1", "_annotation")
+
+    def __init__(self, name, svc, trace, parent, step, attrs):
+        self.name, self.svc, self.trace = name, svc, trace
+        self.attrs, self.parent = attrs, parent
+        self.t0 = self.t1 = None
+        if step is None:
+            self._annotation = _TraceAnnotation(name, **attrs)
+        else:
+            self._annotation = _StepTraceAnnotation(name, step_num=step,
+                                                    **attrs)
+            self.attrs = dict(attrs, step=step)
+
+    def __enter__(self):
+        stack = getattr(_open_phases, "stack", None)
+        if stack is None:
+            stack = _open_phases.stack = []
+        self.sid = _next_sid()
+        if stack:
+            self.parent = stack[-1].sid
+        stack.append(self)
+        self._annotation.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        self.t1 = time.perf_counter()
+        self._annotation.__exit__(*exc)
+        _open_phases.stack.pop()
+        record_span(self.name, self.svc, self.trace, self.t1 - self.t0,
+                    parent=self.parent, span=self.sid, **self.attrs)
+        return False
+
+
+def phase(name, svc, trace=None, parent=None, step=None, **attrs):
+    """A span of the program itself (an engine tick, a train step), into
+    two sinks at once: a ``jax.profiler.TraceAnnotation`` -- so it lies
+    on the profiler's clock, on its thread's line of the host plane,
+    beside the device's operations, with ``attrs`` as its stats -- and,
+    on exit, one record of the span ring (``GET /spans.json``) holding
+    ``dur_s``, the end stamp ``t``, ``attrs``, ``parent`` (the ``sid``
+    of the enclosing open phase on this thread, else the ``parent``
+    given) and ``prof: true`` when a profiler session was running at
+    exit (:func:`record_span`).  ``step=<n>`` makes the annotation a
+    ``StepTraceAnnotation`` (the profiler's step view; the record holds
+    ``step`` too).  While :func:`recording` is false this returns one
+    shared do-nothing object: no allocation, no clock read, the ring
+    untouched."""
+    if not recording():
+        return _NO_PHASE
+    return _Phase(name, svc, trace, parent, step, attrs)
 
 
 def spans(trace=None):

@@ -494,41 +494,50 @@ class FusedTrainer:
 
     def step(self, **batch):
         """Run one fused train step; returns outputs (list of jax arrays)."""
-        import time as _time
-
         lr = np.float32(self.current_lr())  # single source of lr truth
         self._step += 1
+        with _tm.tracing.phase("train_step", "trainer", step=self._step):
+            return self._step_phases(batch, lr)
+
+    def _step_phases(self, batch, lr):
+        """``step`` under its ``train_step`` span: placing the batch
+        (``train.shard_batch``), then the call of the step program and
+        the unpacking of its result (``train.dispatch``)."""
+        import time as _time
+
         perf_on = _tm.perf.enabled()
         t0 = _time.perf_counter() if (_tm.enabled() or perf_on) else None
-        sb = self._shard_batch(batch)
-        self._record_step_memory(sb)
-        try:
-            res = self._step_fn(
-                self.params, self._cparams, self.aux, self.opt_state,
-                sb, _random.current_key(),
-                np.int32(self._step), lr)
-        except Exception as e:  # noqa: BLE001 — OOM gets a report
-            _tm.health.reraise_if_oom(e, site="trainer.step")
-            raise
-        if perf_on and not self._cost_recorded:
-            # one-time analytical cost row for the fused step program
-            # (telemetry/perf.py) — compile() is a cache lookup here,
-            # the dispatch above already built the executable
-            self._cost_recorded = True
-            _tm.perf.attach_cost_analysis(
-                f"fused_step[{self.symbol.name or 'graph'}]",
-                self._step_fn, self.params, self._cparams, self.aux,
-                self.opt_state, sb, _random.current_key(),
-                np.int32(self._step), lr)
-        if self._sentinel:
-            (self.params, self._cparams, self.aux, self.opt_state,
-             outs, sent) = res
-            _tm.health.sentinel_record(site="fused_step", step=self._step,
-                                       names=self._sent_names,
-                                       finite=sent, packed_norm=True)
-        else:
-            (self.params, self._cparams, self.aux, self.opt_state,
-             outs) = res
+        with _tm.tracing.phase("train.shard_batch", "trainer"):
+            sb = self._shard_batch(batch)
+            self._record_step_memory(sb)
+        with _tm.tracing.phase("train.dispatch", "trainer"):
+            try:
+                res = self._step_fn(
+                    self.params, self._cparams, self.aux, self.opt_state,
+                    sb, _random.current_key(),
+                    np.int32(self._step), lr)
+            except Exception as e:  # noqa: BLE001 — OOM gets a report
+                _tm.health.reraise_if_oom(e, site="trainer.step")
+                raise
+            if perf_on and not self._cost_recorded:
+                # one-time analytical cost row for the fused step program
+                # (telemetry/perf.py) — compile() is a cache lookup here,
+                # the dispatch above already built the executable
+                self._cost_recorded = True
+                _tm.perf.attach_cost_analysis(
+                    f"fused_step[{self.symbol.name or 'graph'}]",
+                    self._step_fn, self.params, self._cparams, self.aux,
+                    self.opt_state, sb, _random.current_key(),
+                    np.int32(self._step), lr)
+            if self._sentinel:
+                (self.params, self._cparams, self.aux, self.opt_state,
+                 outs, sent) = res
+                _tm.health.sentinel_record(
+                    site="fused_step", step=self._step,
+                    names=self._sent_names, finite=sent, packed_norm=True)
+            else:
+                (self.params, self._cparams, self.aux, self.opt_state,
+                 outs) = res
         if t0 is not None:
             _TM_STEP_SEC.observe(_time.perf_counter() - t0, loop="fused")
             _TM_SAMPLES.inc(next(iter(sb.values())).shape[0], loop="fused")
@@ -605,6 +614,14 @@ class FusedTrainer:
 
         Returns the per-step outputs stacked on axis 0, still lazy
         (async futures) — reading/blocking is the caller's sync point."""
+        with _tm.tracing.phase("train_step", "trainer",
+                               step=self._step + 1):
+            return self._step_multi_phases(_donate, stacked)
+
+    def _shard_stacked(self, stacked):
+        """``step_multi``'s inputs placed on the device(s); the second
+        return says whether every device buffer was made here (and so
+        may be donated)."""
         sb = {}
         owned = True
         for k_, v in stacked.items():
@@ -638,6 +655,17 @@ class FusedTrainer:
                     self.mesh, P(None, "data", *([None] * (raw.ndim - 2)))))
             else:
                 sb[k_] = raw
+        return sb, owned
+
+    def _step_multi_phases(self, _donate, stacked):
+        """``step_multi`` under its ``train_step`` span, in the same two
+        phases as ``step``."""
+        import time as _time
+        import warnings as _warnings
+
+        with _tm.tracing.phase("train.shard_batch", "trainer"):
+            sb, owned = self._shard_stacked(stacked)
+            self._record_step_memory(sb)
         first = next(iter(sb.values()))
         k = len(first) if isinstance(first, tuple) else first.shape[0]
         if self._lr_scheduler is not None:
@@ -647,15 +675,11 @@ class FusedTrainer:
             lrs = np.full((k,), self._base_lr, np.float32)
         step0 = np.int32(self._step)
         self._step += k
-        import time as _time
-
         donate = owned if _donate is None else bool(_donate)
         fn = self._multi_fn_donate if donate else self._multi_fn
         t0 = _time.perf_counter() if _tm.enabled() else None
-        import warnings as _warnings
-
-        self._record_step_memory(sb)
-        with _warnings.catch_warnings():
+        with _tm.tracing.phase("train.dispatch", "trainer"), \
+                _warnings.catch_warnings():
             if donate:
                 # batch donation is best-effort: when no output aliases
                 # the batch (or the platform can't donate) jax warns per
@@ -669,17 +693,17 @@ class FusedTrainer:
             except Exception as e:  # noqa: BLE001 — OOM gets a report
                 _tm.health.reraise_if_oom(e, site="trainer.step_multi")
                 raise
-        if self._sentinel:
-            (self.params, self._cparams, self.aux, self.opt_state,
-             outs, sents) = res
-            # sents rows map to steps step0+1 .. step0+k
-            _tm.health.sentinel_record(site="fused_step_multi",
-                                       step=int(step0) + 1,
-                                       names=self._sent_names,
-                                       finite=sents, packed_norm=True)
-        else:
-            (self.params, self._cparams, self.aux, self.opt_state,
-             outs) = res
+            if self._sentinel:
+                (self.params, self._cparams, self.aux, self.opt_state,
+                 outs, sents) = res
+                # sents rows map to steps step0+1 .. step0+k
+                _tm.health.sentinel_record(site="fused_step_multi",
+                                           step=int(step0) + 1,
+                                           names=self._sent_names,
+                                           finite=sents, packed_norm=True)
+            else:
+                (self.params, self._cparams, self.aux, self.opt_state,
+                 outs) = res
         if t0 is not None:
             _TM_STEP_SEC.observe(_time.perf_counter() - t0, loop="fused")
             per_step = (first[0].shape[0] if isinstance(first, tuple)

@@ -351,6 +351,56 @@ def test_server_generate_parity_and_validation(decoder):
         sched.close()
 
 
+def test_every_token_is_stamped_on_the_request_and_in_the_reply(decoder):
+    """``Request.token_times``: one monotonic stamp a token, always on;
+    the reply's ``token_ms`` are their offsets from receipt, so the
+    first is the TTFT and the differences are the gaps between tokens;
+    the terminal span carries the same gaps when someone is looking."""
+    from mxnet_tpu.telemetry import tracing
+
+    sched = SlotScheduler(decoder, num_slots=2, queue_size=8)
+    try:
+        reqs = [sched.submit([1, 2 + i, 3], max_new_tokens=n, temperature=0)
+                for i, n in enumerate((5, 1, 3))]
+        for r in reqs:
+            assert r.wait(120).outcome == "ok"
+    finally:
+        sched.close()
+    for r, n in zip(reqs, (5, 1, 3)):
+        assert len(r.token_times) == len(r.tokens) == n
+        assert all(b >= a for a, b in zip(r.token_times, r.token_times[1:]))
+        assert r.token_times[0] - r.arrival == r.ttft
+    assert tracing.spans() == []      # stamps need no tracing
+
+    was = tracing.trace_on()
+    tracing.enable_tracing(True)
+    tracing.clear_spans()
+    server, sched = serve_decoder(decoder, port=0, num_slots=2,
+                                  queue_size=4)
+    port = server.server_address[1]
+    try:
+        status, out = _post(port, {"prompt": [1, 5, 9, 2], "max_tokens": 6})
+        assert status == 200 and out["n_tokens"] == 6
+        assert len(out["token_ms"]) == 6
+        assert out["token_ms"][0] == out["ttft_ms"]
+        assert out["token_ms"] == sorted(out["token_ms"])
+        assert out["ttft_ms"] >= out["queue_wait_ms"]
+        done = [s for s in tracing.spans() if s["name"] == "request"
+                and s["request"] == out["id"]]
+        assert len(done) == 1
+        gaps = done[0]["gaps_ms"]
+        assert len(gaps) == out["n_tokens"] - 1
+        np.testing.assert_allclose(
+            gaps, np.diff(out["token_ms"]), atol=2e-3)
+        assert done[0]["ttft_ms"] == out["ttft_ms"]
+        assert done[0]["queue_wait_ms"] == out["queue_wait_ms"]
+    finally:
+        server.shutdown()
+        sched.close()
+        tracing.enable_tracing(was)
+        tracing.clear_spans()
+
+
 def test_server_backpressure_returns_429(decoder):
     server, sched = serve_decoder(decoder, port=0, num_slots=1,
                                   queue_size=1)

@@ -270,6 +270,36 @@ def test_span_zero_cost_when_both_sinks_off():
     assert tm.get_registry().get("t_dark_region_seconds") is None
 
 
+def test_span_lands_on_the_jax_profilers_clock(tmp_path):
+    """While a jax.profiler session runs -- and with telemetry and the
+    chrome-trace profiler both off -- a ``span`` region is a
+    ``TraceAnnotation`` on the host plane of the session's trace."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    tm.disable()
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        with tm.span("t_profiled_region", category="unit-test"):
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    with tm.span("t_after_the_session"):
+        pass
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    names = {ev.name for plane in ProfileData.from_file(path).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for ev in line.events}
+    assert "t_profiled_region" in names
+    assert "t_after_the_session" not in names
+    # its other sinks stayed off
+    assert tm.get_registry().get("t_profiled_region_seconds") is None
+
+
 # ---------------------------------------------------------------------------
 # subsystem instrumentation
 # ---------------------------------------------------------------------------

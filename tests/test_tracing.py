@@ -493,3 +493,194 @@ def test_bench_trend_directions_for_trace_metrics():
     assert bt.lower_is_better("trace_overhead_pct")
     assert not bt.lower_is_better("serve_trace_on_tokens_per_sec")
     assert not bt.lower_is_better("serve_trace_off_tokens_per_sec")
+
+
+# ---------------------------------------------------------------------------
+# phase(): the engine's and the trainer's own spans (ISSUE 25)
+# ---------------------------------------------------------------------------
+ENGINE_SPANS = {"engine.idle", "engine.admit", "engine.prefill",
+                "engine.tick", "engine.step", "engine.sample"}
+
+
+def _spans_total(reg):
+    fam = tm.json_snapshot(reg)["metrics"].get("trace_spans_total")
+    return sum(s["value"] for s in fam["samples"]) if fam else 0
+
+
+def _serve_a_few(decoder):
+    sched = SlotScheduler(decoder, num_slots=2, queue_size=16)
+    try:
+        reqs = [sched.submit([1 + i, 2, 3], max_new_tokens=4, temperature=0)
+                for i in range(3)]
+        for r in reqs:
+            assert r.wait(120).outcome == "ok"
+    finally:
+        sched.close()
+    return reqs
+
+
+def test_phase_off_is_one_shared_object_and_records_nothing(metrics):
+    tracing.enable_tracing(False)
+    tracing.clear_spans()
+    assert not tracing.recording()
+    before = _spans_total(metrics)
+    first = tracing.phase("engine.tick", "engine", tick=1)
+    with first as ph:
+        with tracing.phase("engine.step", "engine", tick=1) as inner:
+            assert inner is ph is first      # nothing was allocated
+    assert ph.t0 is None and ph.t1 is None   # and no clock was read
+    assert tracing.spans() == []
+    assert _spans_total(metrics) == before
+    tracing.enable_tracing(True)
+    try:
+        with tracing.phase("engine.tick", "engine") as live:
+            pass
+        assert live is not first and live.t1 >= live.t0
+        assert _spans_total(metrics) == before + 1
+    finally:
+        tracing.enable_tracing(False)
+        tracing.clear_spans()
+
+
+def test_phase_records_duration_parent_and_attributes(traced):
+    assert tracing.recording()
+    with tracing.phase("outer", "engine", tick=3) as outer:
+        with tracing.phase("inner", "engine", parent="ignored") as inner:
+            time.sleep(0.002)
+        with tracing.phase("second", "engine"):
+            pass
+    done = threading.Event()
+
+    def other_thread():
+        # the stack of open phases is per thread: no parent here, so the
+        # one handed in (a router's span id) stays
+        with tracing.phase("elsewhere", "replica", trace="t" * 32,
+                           parent="abcd"):
+            pass
+        done.set()
+
+    threading.Thread(target=other_thread).start()
+    assert done.wait(10)
+    by = {s["name"]: s for s in tracing.spans()}
+    assert by["inner"]["parent"] == by["outer"]["sid"] == outer.sid
+    assert by["second"]["parent"] == outer.sid
+    assert by["outer"]["parent"] is None and by["outer"]["tick"] == 3
+    assert by["elsewhere"]["parent"] == "abcd"
+    assert by["elsewhere"]["trace"] == "t" * 32
+    assert by["inner"]["dur_s"] == inner.t1 - inner.t0 >= 0.002
+    assert by["outer"]["dur_s"] >= by["inner"]["dur_s"]
+    assert not any("prof" in s for s in by.values())
+
+
+def test_engine_spans_nest_under_tracing_alone(decoder, traced):
+    """MXTPU_TRACE / enable_tracing() and no profiler: the six engine
+    spans land in the ring for requests no router sampled, nested, and
+    without the ``prof`` flag."""
+    _serve_a_few(decoder)
+    spans = tracing.spans()
+    assert ENGINE_SPANS <= {s["name"] for s in spans}
+    assert not any(s.get("prof") for s in spans)
+    by_sid = {s["sid"]: s for s in spans}
+    for s in spans:
+        if s["name"] in ("engine.step", "engine.sample"):
+            up = by_sid[s["parent"]]
+            assert up["name"] == "engine.tick" and up["tick"] == s["tick"]
+        elif s["name"] == "engine.prefill":
+            up = by_sid[s["parent"]]
+            assert up["name"] == "engine.admit"
+            assert up["request"] == s["request"]
+            assert up["dur_s"] >= s["dur_s"]
+        elif s["name"] in ("engine.tick", "engine.admit", "engine.idle"):
+            assert s["parent"] is None and s["svc"] == "engine"
+    admits = [s for s in spans if s["name"] == "engine.admit"]
+    assert len(admits) == 3
+    assert all({"request", "slot", "prompt_len", "bucket", "queue_wait_ms"}
+               <= set(a) for a in admits)
+    ticks = [s for s in spans if s["name"] == "engine.tick"]
+    assert [t["tick"] for t in ticks] == list(range(len(ticks)))
+    assert all(1 <= t["occupied"] <= 2 for t in ticks)
+    # the terminal record of every request, sampled by a router or not
+    done = [s for s in spans if s["name"] == "request"]
+    assert len(done) == 3
+    for r in done:
+        assert r["trace"] is None and r["tokens"] == 4
+        assert len(r["gaps_ms"]) == 3 and min(r["gaps_ms"]) >= 0
+        assert r["ttft_ms"] >= r["queue_wait_ms"] >= 0
+
+
+def test_engine_spans_reach_the_profilers_host_plane(decoder, tmp_path):
+    """A jax.profiler session and no MXTPU_TRACE: the same names lie on
+    the host plane of the .xplane.pb, and the ring's records say that a
+    session was running as they closed."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    tracing.enable_tracing(False)
+    tracing.clear_spans()
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        assert tracing.recording()
+        _serve_a_few(decoder)
+    finally:
+        jax.profiler.stop_trace()
+    try:
+        assert not tracing.recording()
+        spans = tracing.spans()
+        assert ENGINE_SPANS <= {s["name"] for s in spans}
+        assert all(s["prof"] is True for s in spans
+                   if s["name"] in ENGINE_SPANS | {"request"})
+        path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                          recursive=True)
+        on_host = {}
+        for plane in ProfileData.from_file(path).planes:
+            if plane.name.startswith("/host:"):
+                for line in plane.lines:
+                    for ev in line.events:
+                        if ev.name in ENGINE_SPANS:
+                            on_host.setdefault(ev.name, []).append(
+                                dict(ev.stats))
+        assert set(on_host) == ENGINE_SPANS
+        assert len(on_host["engine.admit"]) == 3
+        assert len(on_host["engine.tick"]) == sum(
+            s["name"] == "engine.tick" for s in spans)
+        assert {"request", "bucket"} <= set(on_host["engine.prefill"][0])
+        assert "tick" in on_host["engine.step"][0]
+    finally:
+        tracing.clear_spans()
+
+
+def test_prefill_span_holds_the_fetch(decoder, traced, monkeypatch):
+    """``backend.admit`` returns without waiting for the device; the
+    wait is the fetch of the logits, and it belongs to the prefill:
+    both the engine's span and the sampled request's record hold it."""
+    sched = SlotScheduler(decoder, num_slots=1, queue_size=4)
+    real = sched.backend.admit
+
+    class Slow:
+        def __init__(self, row):
+            self.row = row
+
+        def __array__(self, dtype=None, copy=None):
+            time.sleep(0.05)
+            return np.asarray(self.row, dtype)
+
+    monkeypatch.setattr(sched.backend, "admit",
+                        lambda *a, **kw: Slow(real(*a, **kw)))
+    try:
+        req = sched.submit([1, 2, 3], max_new_tokens=2, temperature=0,
+                           trace="%032x" % 5, sampled=True)
+        assert req.wait(120).outcome == "ok"
+    finally:
+        sched.close()
+    spans = tracing.spans()
+    engine = next(s for s in spans if s["name"] == "engine.prefill")
+    mine = next(s for s in spans if s["name"] == "prefill")
+    assert engine["dur_s"] >= 0.05
+    assert mine["dur_s"] == engine["dur_s"]       # one pair of stamps
+    admit = next(s for s in spans if s["name"] == "admit")
+    assert admit["dur_s"] == next(
+        s for s in spans if s["name"] == "engine.admit")["dur_s"]

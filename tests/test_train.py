@@ -240,6 +240,54 @@ def test_step_multi_matches_sequential_steps():
                                rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize("call", ["step", "step_multi"])
+def test_train_step_span_has_its_two_children(call):
+    """``FusedTrainer.step`` and ``step_multi`` under tracing: one
+    ``train_step`` span a call with ``train.shard_batch`` and
+    ``train.dispatch`` inside it; with nobody looking, nothing."""
+    from mxnet_tpu.telemetry import tracing
+    from mxnet_tpu.trainer import FusedTrainer
+
+    (xtr, ytr), _ = get_synthetic_mnist(64, 16)
+    b = 16
+    tr = FusedTrainer(_conv_sym(), optimizer="sgd",
+                      optimizer_params={"lr": 0.1},
+                      initializer=mx.init.Xavier())
+    tr.init(data=(b, 1, 28, 28))
+
+    def run():
+        if call == "step":
+            tr.step(data=xtr[:b], softmax_label=ytr[:b])
+        else:
+            tr.step_multi(data=np.stack([xtr[:b], xtr[b:2 * b]]),
+                          softmax_label=np.stack([ytr[:b], ytr[b:2 * b]]))
+
+    was = tracing.trace_on()
+    tracing.enable_tracing(False)
+    tracing.clear_spans()
+    try:
+        run()                                 # compiles; records nothing
+        assert tracing.spans() == []
+        tracing.enable_tracing(True)
+        first = tr._step + 1
+        run()
+        run()
+        spans = tracing.spans()
+    finally:
+        tracing.enable_tracing(was)
+        tracing.clear_spans()
+    assert [s["name"] for s in spans] == [
+        "train.shard_batch", "train.dispatch", "train_step"] * 2
+    assert {s["svc"] for s in spans} == {"trainer"}
+    for shard, dispatch, whole in (spans[:3], spans[3:]):
+        assert shard["parent"] == dispatch["parent"] == whole["sid"]
+        assert whole["parent"] is None
+        assert whole["dur_s"] >= shard["dur_s"] + dispatch["dur_s"]
+    # the step the profiler's step view is told: the first one a call runs
+    assert [spans[2]["step"], spans[5]["step"]] == [
+        first, first + (1 if call == "step" else 2)]
+
+
 def test_hwio_storage_excludes_multi_consumer_weights():
     """A conv weight with ANY consumer besides NHWC convs must stay in
     logical OIHW storage: the second reader (an in-graph weight norm
