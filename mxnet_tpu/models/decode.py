@@ -76,10 +76,13 @@ class _WeightProgram:
     reads the weights from ``p``; every call hands the decoder's in.
     The jitted function carries ``name``, so the XLA module
     (``jit_<name>``) and its operations in a device trace say which
-    program they belong to.  Callable and ``.lower()``-able like the
-    jit it wraps."""
+    program they belong to.  ``donate`` names the positions in ``args``
+    (the weights are never among them) whose buffers the program takes
+    over: the caller's arrays are deleted by the call and the outputs
+    that alias them are written in place.  Callable and
+    ``.lower()``-able like the jit it wraps; lowering consumes nothing."""
 
-    def __init__(self, decoder, fn, name):
+    def __init__(self, decoder, fn, name, donate=()):
         self._dec = decoder
         view = type(decoder.p)      # dict, or the dequantize-on-read view
 
@@ -87,7 +90,8 @@ class _WeightProgram:
             return fn(view(weights), *args)
 
         program.__name__ = program.__qualname__ = name
-        self._jit = jax.jit(program)
+        self._jit = jax.jit(
+            program, donate_argnums=tuple(1 + i for i in donate))
 
     def __call__(self, *args):
         return self._jit(dict(self._dec.p), *args)

@@ -34,6 +34,21 @@ Parity: the gathered table reconstructs exactly the contiguous layout
 ``models/decode.py``, and masked-out table entries contribute exact
 zeros — paged and contiguous decode are BITWISE equal on aligned
 prompts (tests pin it).
+
+The pool's layout is the kernel's.  Each side of the pool is one
+``(P, L, H, block, dh)`` array that the Mosaic kernel reads row-major
+(``{4,3,2,1,0}``).  A program that writes it in a way the TPU compiler
+would rather lay out otherwise — a scatter of rows gets
+``{4,2,3,1,0}`` — makes XLA copy the WHOLE pool into that layout and
+back before every layer's kernel (2 + 2 L copies of 1.6 GB a tick at
+the 1.3B width, 70% of the device's time before ISSUE 26).  So there
+are two ways into the pool, ``_PagedPrograms._write_rows`` (one
+``dynamic_update_slice`` a slot) and ``_write_pages`` (whole pages),
+both in place in that layout, and the programs take the pool's buffers
+over (``donate``): ``PagedSlots`` holds the only reference and replaces
+it with each call's outputs.  Any new program that touches the pool
+goes into ``tests/test_tpu_compile.py``'s compiled-program test, which
+fails on a pool-shaped copy; nothing on the CPU shows one.
 """
 from __future__ import annotations
 
@@ -104,6 +119,11 @@ def paged_kernel_mode() -> str:
     return raw
 
 
+# pool_k, pool_v among a paged program's arguments after the weights:
+# both are donated, each program's outputs are the pool from then on
+_POOL_ARGS = (0, 1)
+
+
 class _PagedPrograms:
     """The jitted decode programs over the page pool.
 
@@ -130,17 +150,60 @@ class _PagedPrograms:
         # always gathers (one admission-time cost, not the per-tick one)
         self.schedule = schedule if (
             schedule and schedule.get("impl") != "gather") else None
-        self._step_jit = _WeightProgram(decoder, _count_compiles(
-            self._forward_step, "decode_step_paged"), "decode_step_paged")
+        self._step_jit = _WeightProgram(
+            decoder, _count_compiles(self._forward_step,
+                                     "decode_step_paged"),
+            "decode_step_paged", donate=_POOL_ARGS)
         self._prefill_cache = {}
+
+    def pool_structs(self):
+        """Shape and dtype of ``(pool_k, pool_v)``: what a program is
+        lowered with when nothing may take the pool's buffers."""
+        import jax
+
+        d = self.dec
+        s = jax.ShapeDtypeStruct(
+            (self.num_pages, d.L, d.H, self.block, d.dh), d._cache_dtype)
+        return s, s
 
     def init_pool(self):
         import jax.numpy as jnp
 
-        d = self.dec
-        shape = (self.num_pages, d.L, d.H, self.block, d.dh)
-        return (jnp.zeros(shape, d._cache_dtype),
-                jnp.zeros(shape, d._cache_dtype))
+        return tuple(jnp.zeros(s.shape, s.dtype)
+                     for s in self.pool_structs())
+
+    # -------------------------------------------------------------- writes
+    # The two ways into the pool.  Both leave it in the row-major layout
+    # the Mosaic kernel reads (module docstring, "The pool's layout");
+    # tests/test_tpu_compile.py compiles every program that uses them.
+    def _write_rows(self, pool, new, layer, at):
+        """One row a slot: ``new[b]`` ``(H, 1, dh)`` lands at
+        ``pool[page, layer, :, off]`` for ``(page, off) = at[b]``, as
+        one ``dynamic_update_slice`` a slot (a ``fori_loop`` over the
+        slots is laid out like the scatter again).  A step's trace
+        holds ``2 L B`` of these writes, so ``at`` is a list of scalar
+        pairs made once a program, and the indices, never negative,
+        skip the wrap-around ``lax`` would stage for each."""
+        import jax
+
+        new = new[:, None]                           # (B, 1, H, 1, dh)
+        for b, (page, off) in enumerate(at):
+            pool = jax.lax.dynamic_update_slice(
+                pool, jax.lax.slice_in_dim(new, b, b + 1),
+                (page, layer, 0, off, 0), allow_negative_indices=False)
+        return pool
+
+    def _write_pages(self, pool, new, page_ids, layer):
+        """Whole pages: ``new`` ``(T, H, dh)`` holds consecutive
+        positions from a page boundary on, page ``n`` of it lands at
+        ``pool[page_ids[n], layer]``; an id out of bounds drops its
+        page."""
+        import jax.numpy as jnp
+
+        T, H, dh = new.shape
+        new = jnp.pad(new, ((0, -T % self.block), (0, 0), (0, 0)))
+        vals = new.reshape(-1, self.block, H, dh).transpose(0, 2, 1, 3)
+        return pool.at[page_ids, layer].set(vals, mode="drop")
 
     # ------------------------------------------------------------ gathers
     def _gather(self, pool, bt):
@@ -180,6 +243,7 @@ class _PagedPrograms:
         pages = jnp.take_along_axis(
             bt, (cursor // self.block)[:, None], axis=1)[:, 0]   # (B,)
         offs = cursor % self.block
+        at = [(pages[b], offs[b]) for b in range(B)]
         sched = self.schedule
         if sched is None:
             kc = self._gather(pool_k, bt)
@@ -196,8 +260,8 @@ class _PagedPrograms:
                 if sched is None:
                     kc = kc.at[i, rows, :, cursor].set(kh[:, :, 0])
                     vc = vc.at[i, rows, :, cursor].set(vh[:, :, 0])
-                pool_k = pool_k.at[pages, i, :, offs].set(kh[:, :, 0])
-                pool_v = pool_v.at[pages, i, :, offs].set(vh[:, :, 0])
+                pool_k = self._write_rows(pool_k, kh, i, at)
+                pool_v = self._write_rows(pool_v, vh, i, at)
                 if sched is None:
                     scores = jnp.einsum("bhnd,bhsd->bhns", qh, kc[i]) \
                         / jnp.sqrt(jnp.asarray(dh, h.dtype))
@@ -232,11 +296,20 @@ class _PagedPrograms:
                          t):
         """Tail prefill behind a (possibly reused) history: ``tokens``
         (1, T) RIGHT-padded, the ``t`` real tokens sit at absolute
-        positions ``hist .. hist+t-1``.  K/V of real tokens scatter
-        into their pages (and the gathered table, for intra-prefill
-        attention); pad tokens target out-of-bounds indices, which the
-        scatter drops.  ``hist``/``t`` ride as traced scalars, so the
-        program count is one per padded bucket length."""
+        positions ``hist .. hist+t-1``.  ``hist`` is a whole number of
+        pages (only full blocks are shared), so the tail starts on a
+        page boundary and its K/V go into the pool a PAGE at a time:
+        every page that holds a real token is written whole, the pages
+        of pad tokens alone aim out of bounds and are dropped.  The
+        rows of the last, partial page beyond the prompt therefore hold
+        the pad tokens' K/V: finite values at positions ``> cursor``,
+        which both step lowerings mask to exact zero weight and which
+        the decode write at each position replaces before the mask
+        reaches it; only full blocks are promoted to the prefix index,
+        so no shared page ever holds one.  The gathered table (for
+        intra-prefill attention) takes the real tokens' rows only.
+        ``hist``/``t`` ride as traced scalars, so the program count is
+        one per padded bucket length."""
         import jax
         import jax.numpy as jnp
 
@@ -257,11 +330,12 @@ class _PagedPrograms:
         h = tok + posv
         # write targets: pad tokens go out of bounds -> dropped writes
         wpos = jnp.where(real, qpos, S)                      # table scatter
-        pages = jnp.where(
-            real,
-            bt_row[jnp.clip(qpos // self.block, 0, self.max_blocks - 1)],
+        n = jnp.arange(-(-T // self.block))                  # tail pages
+        page_ids = jnp.where(
+            n * self.block < t,
+            bt_row[jnp.clip(hist // self.block + n, 0,
+                            self.max_blocks - 1)],
             self.num_pages)                                  # pool scatter
-        offs = qpos % self.block
         s_idx = jnp.arange(S)
         valid = s_idx[None, :] <= qpos[:, None]              # (T, S)
         kc = self._gather(pool_k, bt_row[None])              # (L, 1, H, S, dh)
@@ -277,8 +351,8 @@ class _PagedPrograms:
                 v_t = vh[0].transpose(1, 0, 2)
                 kc = kc.at[i, 0, :, wpos].set(k_t)
                 vc = vc.at[i, 0, :, wpos].set(v_t)
-                pool_k = pool_k.at[pages, i, :, offs].set(k_t)
-                pool_v = pool_v.at[pages, i, :, offs].set(v_t)
+                pool_k = self._write_pages(pool_k, k_t, page_ids, i)
+                pool_v = self._write_pages(pool_v, v_t, page_ids, i)
                 scores = jnp.einsum("bhnd,bhsd->bhns", qh, kc[i]) \
                     / jnp.sqrt(jnp.asarray(dh, h.dtype))
                 scores = jnp.where(valid[None, None], scores, NEG_INF)
@@ -308,7 +382,7 @@ class _PagedPrograms:
             self._prefill_cache[bucket] = _WeightProgram(
                 self.dec, _count_compiles(self._forward_prefill,
                                           "decode_prefill_paged"),
-                f"prefill_paged_b{bucket}")
+                f"prefill_paged_b{bucket}", donate=_POOL_ARGS)
         return self._prefill_cache[bucket]
 
 
@@ -363,14 +437,7 @@ class PagedSlots:
         self.programs = _PagedPrograms(
             decoder, self.block, self.max_blocks, self.num_pages + 1,
             schedule=self.schedule)
-        self.pool = self.programs.init_pool()
-        self.bt = np.zeros((self.num_slots, self.max_blocks), np.int32)
-        self.cursor = np.zeros(self.num_slots, np.int32)
-        self._free = list(range(self.num_pages, 0, -1))   # pop() -> page 1 last
-        self._ref = np.zeros(self.num_pages + 1, np.int64)
-        self._prefix = OrderedDict()      # chain hash -> page (LRU first)
-        self._page_hash = {}              # page -> chain hash
-        self._slot_pages = [[] for _ in range(self.num_slots)]
+        self._reset_pool()
         # trace id of the admission currently allocating, so _alloc can
         # attribute its prefix evictions; None for step-time evictions
         self._trace_ctx = None
@@ -379,6 +446,36 @@ class PagedSlots:
         self._cost_step_done = False
         self._cost_prefill_done = set()
         self._set_gauges()
+
+    # ----------------------------------------------------------------- pool
+    def _reset_pool(self):
+        """A zeroed pool with every page free, no slot holding any and
+        an empty prefix index."""
+        self.pool = self.programs.init_pool()
+        self.bt = np.zeros((self.num_slots, self.max_blocks), np.int32)
+        self.cursor = np.zeros(self.num_slots, np.int32)
+        self._free = list(range(self.num_pages, 0, -1))   # pop() -> page 1 last
+        self._ref = np.zeros(self.num_pages + 1, np.int64)
+        self._prefix = OrderedDict()      # chain hash -> page (LRU first)
+        self._page_hash = {}              # page -> chain hash
+        self._slot_pages = [[] for _ in range(self.num_slots)]
+
+    def _run(self, program, *args):
+        """Call a paged program on the pool, which it takes over
+        (``donate``): its outputs are the pool from here on.  A call
+        that raises after it took the buffers leaves nothing to serve
+        from, so the backend starts again from :meth:`_reset_pool` and
+        every slot loses its pages: :meth:`step` refuses to tick a slot
+        without pages, which is how the scheduler comes to fail the
+        requests that were live."""
+        try:
+            self.pool, logits = program(*self.pool, *args)
+        except Exception:
+            if any(a.is_deleted() for a in self.pool):
+                self._reset_pool()
+                self._set_gauges()
+            raise
+        return logits
 
     # ------------------------------------------------------------- schedule
     def _resolve_schedule(self):
@@ -540,17 +637,15 @@ class PagedSlots:
         padded[0, :t] = tail
         # _snap: self.bt is mutated in place by later admits/steps while
         # this dispatch may still be executing — never alias it
-        (pk, pv), logits = self.programs.prefill(bucket)(
-            self.pool[0], self.pool[1], _snap(self.bt[slot]),
-            jnp.asarray(padded), jnp.int32(hist), jnp.int32(t))
+        args = (_snap(self.bt[slot]), jnp.asarray(padded), jnp.int32(hist),
+                jnp.int32(t))
+        logits = self._run(self.programs.prefill(bucket), *args)
         if bucket not in self._cost_prefill_done and _tm.perf.enabled():
             self._cost_prefill_done.add(bucket)
             _tm.perf.attach_cost_analysis(
                 f"decode_prefill_paged[b{bucket}]",
                 self.programs.prefill(bucket),
-                pk, pv, _snap(self.bt[slot]), jnp.asarray(padded),
-                jnp.int32(hist), jnp.int32(t))
-        self.pool = (pk, pv)
+                *self.programs.pool_structs(), *args)
         self.cursor[slot] = p_len
         # promote this prompt's full blocks: they are never written
         # again (writes happen at cursor >= p_len), so they are safe to
@@ -587,6 +682,10 @@ class PagedSlots:
         starved = []
         for b in np.flatnonzero(occupied):
             b = int(b)
+            if not self._slot_pages[b]:
+                raise MXNetError(
+                    f"slot {b} holds no pages: it was never admitted, or "
+                    "the pool was lost to a failed call and started anew")
             c = int(self.cursor[b])
             if c >= self.decoder.max_len:
                 raise MXNetError(
@@ -603,16 +702,13 @@ class PagedSlots:
                 self._slot_pages[b].append(pg)
         # _snap: bt/cursor are mutated in place right below and on the
         # next tick — aliasing them into the async dispatch races
-        (pk, pv), logits = self.programs._step_jit(
-            self.pool[0], self.pool[1], _snap(self.bt),
-            _snap(tokens), _snap(self.cursor))
+        args = (_snap(self.bt), _snap(tokens), _snap(self.cursor))
+        logits = self._run(self.programs._step_jit, *args)
         if not self._cost_step_done and _tm.perf.enabled():
             self._cost_step_done = True
             _tm.perf.attach_cost_analysis(
                 "decode_step_paged", self.programs._step_jit,
-                pk, pv, _snap(self.bt), _snap(tokens),
-                _snap(self.cursor))
-        self.pool = (pk, pv)
+                *self.programs.pool_structs(), *args)
         adv = occupied.copy()
         adv[starved] = False
         self.cursor[adv] += 1
@@ -623,11 +719,12 @@ class PagedSlots:
     def lower_step(self):
         """The paged step program lowered for this pool's shapes,
         without running it: ``.compile().as_text()`` shows whether the
-        Pallas kernel (``tpu_custom_call``) is in it.  Inspection only."""
+        Pallas kernel (``tpu_custom_call``) is in it.  Inspection only:
+        it is handed the pool's shape, never its buffers."""
         from ..models.decode import _snap
 
         return self.programs._step_jit.lower(
-            self.pool[0], self.pool[1], _snap(self.bt),
+            *self.programs.pool_structs(), _snap(self.bt),
             _snap(np.zeros(self.num_slots, np.int64)), _snap(self.cursor))
 
     def exhausted(self, slot):

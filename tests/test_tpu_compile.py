@@ -14,18 +14,27 @@ called themselves with ``interpret=False`` — under a described topology
 ``jax.default_backend()`` is still ``cpu``, so the dispatchers
 (``paged_attention``, ``attention``, ``residual_epilogue``) would take
 their CPU branch.  All such tests live in this one file.
+
+The paged serving programs are compiled whole (abstract weights, a few
+layers) for what the kernels alone cannot show: which layout the
+compiler gives the page pool between them.  Any new program that takes
+the pool is added to ``test_paged_program_keeps_pool_layout``
+(docs/serving.md, "The pool's layout is the kernel's").
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from mxnet_tpu.models.decode import KVDecoder
 from mxnet_tpu.ops import flash_attention as fa
 from mxnet_tpu.ops import paged_attention as pa
 from mxnet_tpu.ops import residual_epilogue as repi
+from mxnet_tpu.serving.paged_kv import _PagedPrograms
 
 
 @pytest.fixture(scope="module")
@@ -119,6 +128,110 @@ def test_paged_gate_rejects_what_mosaic_rejects(one_chip):
                 {"grid": "bh", "live_only": True}, False),
             one_chip((B, H, 1, dh), "float32"), pool, pool,
             one_chip((B, M), "int32"), one_chip((B,), "int32"))
+
+
+# ------------------------------------------- paged programs and the pool
+# serve_batch's pool: 16 slots x 64 pages of 16 tokens and the scratch
+# page, 16 heads of 128; 3 of the 24 layers, and narrow where the pool
+# does not see it (ffn, vocabulary), keep a compile to seconds
+_SLOTS, _BLOCK, _MAX_LEN, _HEADS, _DH, _LAYERS = 16, 16, 1024, 16, 128, 3
+_PAGES = _SLOTS * _MAX_LEN // _BLOCK + 1
+_POOL = "bf16[%d,%d,%d,%d,%d]" % (_PAGES, _LAYERS, _HEADS, _BLOCK, _DH)
+_BUCKETS = (128, 256, 512, 1024)
+
+
+def _paged_programs(sds, cls=_PagedPrograms):
+    """``serving/paged_kv.py``'s programs over a decoder that holds
+    shapes for weights: nothing is allocated, everything lowers."""
+    D, F, V = _HEADS * _DH, 512, 1024
+    p = {"tok_embed_weight": (V, D), "pos_embed": (1, _MAX_LEN, D),
+         "final_ln_gamma": (D,), "final_ln_beta": (D,),
+         "lm_head_weight": (V, D), "lm_head_bias": (V,)}
+    for i in range(_LAYERS):
+        for w in ("q", "k", "v", "proj"):
+            p[f"layer{i}_{w}_weight"] = (D, D)
+            p[f"layer{i}_{w}_bias"] = (D,)
+        for ln in ("ln1", "ln2"):
+            p[f"layer{i}_{ln}_gamma"] = p[f"layer{i}_{ln}_beta"] = (D,)
+        p[f"layer{i}_ffn_in_weight"] = (F, D)
+        p[f"layer{i}_ffn_in_bias"] = (F,)
+        p[f"layer{i}_ffn_out_weight"] = (D, F)
+        p[f"layer{i}_ffn_out_bias"] = (D,)
+    dec = KVDecoder.__new__(KVDecoder)
+    dec.p = {k: sds(shape, "bfloat16") for k, shape in p.items()}
+    dec.L, dec.H, dec.dh, dec.d_model = _LAYERS, _HEADS, _DH, D
+    dec.max_len, dec.mesh = _MAX_LEN, None
+    dec._cache_dtype = jnp.dtype("bfloat16")
+    return cls(dec, _BLOCK, _MAX_LEN // _BLOCK, _PAGES,
+               schedule=pa.default_schedule("tpu", _BLOCK, _DH, "bfloat16"))
+
+
+def _compile_paged(progs, sds, which):
+    """The step program (``which`` = "step") or one prefill bucket's,
+    compiled with the pool handed over as ``PagedSlots`` does."""
+    pool = sds((_PAGES, _LAYERS, _HEADS, _BLOCK, _DH), "bfloat16")
+    M = _MAX_LEN // _BLOCK
+    if which == "step":
+        lowered = progs._step_jit.lower(
+            pool, pool, sds((_SLOTS, M), "int32"),
+            sds((_SLOTS,), "int32"), sds((_SLOTS,), "int32"))
+    else:
+        lowered = progs.prefill(which).lower(
+            pool, pool, sds((M,), "int32"), sds((1, which), "int32"),
+            sds((), "int32"), sds((), "int32"))
+    return lowered.compile()
+
+
+def _pool_copies(text):
+    """Instructions of the optimized HLO that copy the whole pool: a
+    ``copy`` (or its asynchronous start) or a copy fusion whose result
+    has the pool's shape."""
+    found = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = (.*?) ([\w-]+)\(", line)
+        if m is None or _POOL not in m.group(2):
+            continue
+        name, op = m.group(1), m.group(3)
+        if op in ("copy", "copy-start") or (op == "fusion"
+                                            and "copy" in name):
+            found.append(name)
+    return found
+
+
+@pytest.mark.parametrize("which", ("step",) + _BUCKETS)
+def test_paged_program_keeps_pool_layout(one_chip, which):
+    """No program reads or writes more of the pool than the pages it
+    addresses: the pool stays in the row-major layout the Mosaic kernel
+    demands from the entry parameter to the result, in place in the
+    donated buffers."""
+    compiled = _compile_paged(_paged_programs(one_chip), one_chip, which)
+    text = compiled.as_text()
+    assert _pool_copies(text) == []
+    layouts = set(re.findall(re.escape(_POOL) + r"\{([\d,]+)", text))
+    assert layouts == {"4,3,2,1,0"}, layouts
+    assert text.count('custom_call_target="tpu_custom_call"') == (
+        _LAYERS if which == "step" else 0)
+    alias = re.search(r"input_output_alias=\{(.*?) \}", text)
+    assert alias and re.findall(r"\{(\d)\}: \(\d+,", alias.group(1)) \
+        == ["0", "1"], "the pools are not both aliased to the outputs"
+    pool_bytes = 2 * _PAGES * _LAYERS * _HEADS * _BLOCK * _DH
+    assert compiled.memory_analysis().alias_size_in_bytes == 2 * pool_bytes
+
+
+def test_row_scatter_relayouts_the_pool(one_chip):
+    """The control: with the row scatter that the step program used to
+    write with, compiled the same way, the compiler moves the scattered
+    dimension out of the tiled pair and copies the whole pool into that
+    layout and back for every kernel operand: 2 + 2 L copies.  So the
+    test above is known to see them."""
+    class RowScatter(_PagedPrograms):
+        def _write_rows(self, pool, new, layer, at):
+            pages, offs = (jnp.stack(x) for x in zip(*at))
+            return pool.at[pages, layer, :, offs].set(new[:, :, 0])
+
+    text = _compile_paged(_paged_programs(one_chip, RowScatter), one_chip,
+                          "step").as_text()
+    assert len(_pool_copies(text)) == 2 + 2 * _LAYERS
 
 
 # ------------------------------------------------------ residual epilogue
