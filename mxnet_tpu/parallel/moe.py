@@ -21,6 +21,21 @@ GShard/Mixtral configuration):
 
 Everything is differentiable: gates get gradients through the combine
 weights, experts through their matmuls.
+
+Two expert layers live here, and they differ in what they may lose:
+
+- :func:`moe_ffn` -- the TRAINING layer above (``examples/`` only):
+  softmax gate, a capacity factor, all_to_all over an ``expert`` mesh
+  axis.  A choice beyond its expert's capacity is DROPPED.
+- :func:`moe_serve` -- the SERVING layer (``models/ling.py``): it is
+  told which experts it holds (``expert_offset`` and the leading axis
+  of the expert weights), routes over ALL of the router's experts in
+  float32 (:func:`route_group_limited`), and computes its own experts'
+  part of the result by a sort-and-segment grouped matmul
+  (``lax.ragged_dot``) sized for the worst case.  It NEVER drops a
+  token; what the experts held elsewhere would add is simply not in
+  its result.  On one chip it runs without the exchange that would sum
+  the shares.
 """
 from __future__ import annotations
 
@@ -29,6 +44,11 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
+
+from ..base import mxu_precision
+
+__all__ = ["init_moe_params", "moe_ffn", "route_group_limited",
+           "moe_serve"]
 
 
 def init_moe_params(rng, d_model, d_hidden, n_experts, scale=0.02):
@@ -120,3 +140,88 @@ def moe_ffn(params, x, mesh: Mesh, axis_name: str = "expert",
         return out, aux.astype(xs.dtype)
 
     return run(params["gate_w"], params["w_in"], params["w_out"], x)
+
+
+# ------------------------------------------------------------- serving
+def route_group_limited(scores, bias, *, top_k, n_group, topk_group, scale):
+    """DeepSeek-V3 style choice (``topk_method`` ``noaux_tc``) over
+    ``scores`` (N, E) float32 in [0, 1].  The choice is made on ``scores
+    + bias``: the experts lie in ``n_group`` groups, a group scores the
+    sum of its two best, the ``topk_group`` best groups stay, the
+    ``top_k`` best experts among them are chosen.  Their weights are
+    ``scores`` WITHOUT the bias, normalised to sum 1, times ``scale``.
+    Returns ``(idx (N, top_k) int32, weights (N, top_k) float32)``."""
+    N, E = scores.shape
+    sel = scores + bias
+    best2 = jnp.sum(jax.lax.top_k(
+        sel.reshape(N, n_group, E // n_group), 2)[0], -1)      # (N, G)
+    _, kept = jax.lax.top_k(best2, topk_group)
+    keep = jnp.any(kept[:, :, None] == jnp.arange(n_group), axis=1)
+    masked = jnp.where(jnp.repeat(keep, E // n_group, axis=1), sel,
+                       -jnp.inf)
+    _, idx = jax.lax.top_k(masked, top_k)
+    w = jnp.take_along_axis(scores, idx, axis=1)
+    w = w / (jnp.sum(w, -1, keepdims=True) + 1e-20) * scale
+    return idx.astype(jnp.int32), w
+
+
+def moe_serve(x, router_w, router_b, w_gate, w_up, w_down, *,
+              expert_offset, top_k, n_group, topk_group, scale,
+              valid=None):
+    """This chip's share of a routed expert layer, dropless.
+
+    ``x`` (N, D) tokens; ``router_w`` (E, D) and ``router_b`` (E,) over
+    ALL ``E`` experts; ``w_gate``/``w_up`` (held, D, F) and ``w_down``
+    (held, F, D) are experts ``[expert_offset, expert_offset + held)``.
+    The router runs in float32 at ``highest`` precision: the 8th and 9th
+    expert of a token can lie closer than bfloat16 resolves, and a
+    flipped choice moves the result by a whole expert's part.  The
+    token-expert pairs are sorted by expert (pairs on absent experts
+    last), and one ``lax.ragged_dot`` a matrix runs every held expert
+    over exactly its rows; the buffers hold all ``N * top_k`` pairs, so
+    nothing is ever dropped.  Named scopes ``moe.route`` and
+    ``moe.experts`` carry the two parts in a device trace.
+
+    Returns ``(y (N, D), counts (3,) int32)``: ``y`` is the routed part
+    of the held experts alone; ``counts`` = pairs that fell on held
+    experts, pairs that fell on absent ones, distinct held experts hit
+    -- over the rows ``valid`` (N,) marks (all rows when None)."""
+    N, D = x.shape
+    held = w_gate.shape[0]
+    with jax.named_scope("moe.route"):
+        scores = jax.nn.sigmoid(jnp.dot(
+            x.astype(jnp.float32), router_w.astype(jnp.float32).T,
+            precision=jax.lax.Precision.HIGHEST))
+        idx, wts = route_group_limited(
+            scores, router_b.astype(jnp.float32), top_k=top_k,
+            n_group=n_group, topk_group=topk_group, scale=scale)
+        local = idx - expert_offset
+        here = (local >= 0) & (local < held)
+        # absent pairs carry the id ``held``: they sort last and belong
+        # to no group
+        flat = jnp.where(here, local, held).reshape(-1)          # (N k,)
+        real = True if valid is None else valid[:, None]
+        counted, absent = here & real, ~here & real
+        hits = jnp.zeros(held + 1, jnp.int32).at[
+            jnp.where(counted, local, held).reshape(-1)].add(1)[:held]
+        counts = jnp.stack([jnp.sum(counted), jnp.sum(absent),
+                            jnp.sum(hits > 0)]).astype(jnp.int32)
+    with jax.named_scope("moe.experts"):
+        order = jnp.argsort(flat, stable=True)
+        sizes = jnp.zeros(held + 1, jnp.int32).at[flat].add(1)[:held]
+        xs = jnp.take(x, order // top_k, axis=0)                 # (N k, D)
+        # one MXU pass for low-precision operands whatever the package's
+        # default says (the TPU's grouped matmul refuses bf16 operands
+        # at "float32" precision); float32 operands keep the default
+        grouped = partial(jax.lax.ragged_dot, group_sizes=sizes,
+                          precision=mxu_precision(xs, w_gate))
+        h = jax.nn.silu(grouped(xs, w_gate)) * grouped(xs, w_up)
+        ys = grouped(h, w_down)                                  # (N k, D)
+        # rows past the last group belong to absent experts: whatever
+        # the grouped matmul left there is not part of the result
+        w_sorted = jnp.where(here, wts, 0.0).reshape(-1)[order]
+        ys = jnp.where((flat[order] < held)[:, None],
+                       ys.astype(jnp.float32) * w_sorted[:, None], 0.0)
+        back = jnp.argsort(order)
+        y = jnp.sum(jnp.take(ys, back, axis=0).reshape(N, top_k, D), 1)
+    return y.astype(x.dtype), counts

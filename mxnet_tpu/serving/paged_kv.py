@@ -29,13 +29,35 @@ one jitted step over all slots per tick, one bucketed prefill per
 admission, zero traces on a warm server
 (``executor_compile_total{kind=decode_step_paged|decode_prefill_paged}``).
 
-Parity: the gathered table reconstructs exactly the contiguous layout
-(absolute positions, ``start=0``), the layer math is shared with
-``models/decode.py``, and masked-out table entries contribute exact
-zeros — paged and contiguous decode are BITWISE equal on aligned
-prompts (tests pin it).
+What a page holds, and what lives beside the pages, is the decoder's
+to say.  Two kinds of decoder are served:
 
-The pool's layout is the kernel's.  Each side of the pool is one
+- ``models/decode.py:KVDecoder`` (GPT-2 block) declares nothing and
+  gets the K/V pool of :class:`_PagedPrograms`: two ``(P, L, H, block,
+  dh)`` arrays, whose programs hold that block's mathematics
+  (``_block_qkv`` + ``_ln``/``_fc`` of ``models/decode.py``).  The
+  gathered table reconstructs exactly the contiguous layout (absolute
+  positions, ``start=0``) and masked-out entries contribute exact
+  zeros — paged and contiguous decode are BITWISE equal on aligned
+  prompts (tests pin it).
+- a decoder with ``paged_layout()`` (``models/ling.py:LingDecoder``)
+  declares its page rows by layer kind (``pages``: name -> layers, row
+  width, dtype; one ``(layers, P, block * width)`` array each, a page
+  one row, so that gathering a table is a lookup of whole rows) and a
+  FIXED-SIZE STATE PER SLOT beside them (``state``: name -> shape,
+  dtype; one ``(num_slots, *shape)`` array each — a linear-attention
+  layer's recurrent state).  :class:`_DeclaredPrograms` holds no model
+  mathematics: its two programs call the decoder's one ``forward`` over
+  a *cache view* (:class:`_StepView`, :class:`_PrefillView`).  Still
+  one block table a slot; a prefill starts from a zero state and
+  overwrites the slot's row, so a reused slot never sees its
+  predecessor; pages and state are donated through every program like
+  the K/V pool.  A cached prefix would also need the state at that
+  block boundary, which nothing snapshots: such a decoder says
+  ``prefix_reuse: False`` and no page of it is ever shared
+  (``stats()["prefix_reuse"]``).
+
+The K/V pool's layout is the kernel's.  Each side of the pool is one
 ``(P, L, H, block, dh)`` array that the Mosaic kernel reads row-major
 (``{4,3,2,1,0}``).  A program that writes it in a way the TPU compiler
 would rather lay out otherwise — a scatter of rows gets
@@ -78,6 +100,20 @@ _TM_PAGES = _tm.gauge(
     "KV-cache page pool occupancy: total usable pages, currently free "
     "pages, and pages pinned by the prompt-prefix cache",
     labels=("state",))
+_TM_STATE_SLOTS = _tm.gauge(
+    "serve_state_slots",
+    "slots whose per-slot recurrent state is in use (decoders that "
+    "declare state beside their pages: linear-attention layers)")
+_TM_LATENT_PAGES = _tm.gauge(
+    "serve_latent_pages",
+    "pages in use of a decoder that declares its own page rows (one "
+    "latent row a token and MLA layer)")
+_TM_EXPERT_PAIRS = _tm.gauge(
+    "serve_expert_assignments",
+    "token-expert assignments of the served MoE layers since start, as "
+    "of the last stats() read: on experts held here, on experts held "
+    "elsewhere, and distinct held experts hit summed over layers and "
+    "programs", labels=("where",))
 
 
 class PoolExhausted(MXNetError):
@@ -386,6 +422,189 @@ class _PagedPrograms:
         return self._prefill_cache[bucket]
 
 
+# the cache pytree among a declared program's arguments after the weights
+_CACHE_ARG = (0,)
+# the counters a declared program adds to (never donated: stats() reads
+# them from another thread): assignments on held experts, on absent
+# ones, distinct held experts hit
+_N_COUNTERS = 3
+
+
+class _CacheView:
+    """What ``decoder.forward`` sees of the cache: the per-slot state,
+    the pages, the positions of its tokens and which of them are real.
+    ``step`` tells the decode step's view from a prefill's."""
+
+    def __init__(self, programs, cache, positions, valid):
+        self._pg = programs
+        self.pages = dict(cache["pages"])
+        self._state = dict(cache["state"])
+        self.positions, self.valid = positions, valid
+        self.counts = 0
+
+    def count(self, counts):
+        self.counts = self.counts + counts
+
+    def cache(self):
+        return {"pages": self.pages, "state": self._state}
+
+
+class _StepView(_CacheView):
+    """One token a slot: state rows are the slots themselves; a page row
+    a slot is written at its cursor and every slot's table is gathered
+    back for attention."""
+    step = True
+
+    def __init__(self, programs, cache, bt, cursor, occupied):
+        super().__init__(programs, cache, cursor, occupied)
+        self._bt, self._cursor = bt, cursor
+
+    def state(self, name):
+        return self._state[name]
+
+    def set_state(self, name, value):
+        self._state[name] = value.astype(self._state[name].dtype)
+
+    def append(self, name, layer, rows):
+        """``rows`` (B, W) land at each slot's cursor; returns the
+        gathered ``(B, S, W)`` table and its ``(B, S)`` validity."""
+        import jax
+        import jax.numpy as jnp
+
+        pg, bt, cursor = self._pg, self._bt, self._cursor
+        pool = self.pages[name]
+        pages = jnp.take_along_axis(
+            bt, (cursor // pg.block)[:, None], axis=1)[:, 0]
+        offs = cursor % pg.block
+        W = rows.shape[-1]
+        rows = rows.astype(pool.dtype)[None, :, None]        # (1, B, 1, W)
+        # one dynamic_update_slice a slot, as the K/V pool's row writes
+        # (a scatter of rows asks the TPU compiler for another layout)
+        for b in range(rows.shape[1]):
+            pool = jax.lax.dynamic_update_slice(
+                pool, rows[:, b], (layer, pages[b], offs[b] * W),
+                allow_negative_indices=False)
+        self.pages[name] = pool
+        # a page is one row of ``block * W`` numbers: gathering a slot's
+        # table is a lookup of whole rows, in the layout they lie in
+        S = pg.max_blocks * pg.block
+        table = jnp.take(pool[layer], bt.reshape(-1), axis=0).reshape(
+            bt.shape[0], S, W)
+        return table, jnp.arange(S)[None, :] <= cursor[:, None]
+
+
+class _PrefillView(_CacheView):
+    """One sequence from position 0 into slot ``slot``: the state starts
+    at zero and the state after token ``length - 1`` overwrites the
+    slot's row; page rows go in a page at a time."""
+    step = False
+
+    def __init__(self, programs, cache, bt_row, slot, tokens, length):
+        import jax.numpy as jnp
+
+        j = jnp.arange(tokens.shape[0])
+        super().__init__(programs, cache, j, j < length)
+        self._bt_row, self._slot, self.length = bt_row, slot, length
+
+    def state(self, name):
+        import jax.numpy as jnp
+
+        s = self._state[name]
+        return jnp.zeros(s.shape[1:], s.dtype)
+
+    def set_state(self, name, value):
+        import jax
+
+        s = self._state[name]
+        self._state[name] = jax.lax.dynamic_update_slice(
+            s, value.astype(s.dtype)[None],
+            (self._slot,) + (0,) * (s.ndim - 1),
+            allow_negative_indices=False)
+
+    def append(self, name, layer, rows):
+        """``rows`` (T, W), right-padded: every page that holds a real
+        token is written whole (the rows beyond the prompt in the last
+        page are masked by the step until its own writes replace them),
+        pages of pad tokens alone aim out of bounds and are dropped."""
+        import jax.numpy as jnp
+
+        pg = self._pg
+        pool = self.pages[name]
+        T, W = rows.shape
+        rows = jnp.pad(rows.astype(pool.dtype), ((0, -T % pg.block), (0, 0)))
+        n = jnp.arange(rows.shape[0] // pg.block)
+        page_ids = jnp.where(
+            n * pg.block < self.length,
+            self._bt_row[jnp.clip(n, 0, pg.max_blocks - 1)], pg.num_pages)
+        self.pages[name] = pool.at[layer, page_ids].set(
+            rows.reshape(-1, pg.block * W), mode="drop")
+        return None
+
+
+class _DeclaredPrograms:
+    """The two jitted programs of a decoder that declares its cache
+    (``decoder.paged_layout()``): ``jit_decode_step_<family>`` and
+    ``jit_prefill_<family>_b<bucket>``.  Both call ``decoder.forward``
+    over a cache view and nothing else; the cache, one pytree
+    ``{"pages": {...}, "state": {...}}``, is donated to each call."""
+
+    schedule = None
+
+    def __init__(self, decoder, block, max_blocks, num_pages, num_slots):
+        from ..models.decode import _WeightProgram, _count_compiles
+
+        self.dec = decoder
+        self.layout = decoder.paged_layout()
+        self.block, self.max_blocks = int(block), int(max_blocks)
+        self.num_pages, self.num_slots = int(num_pages), int(num_slots)
+        self._WeightProgram, self._count = _WeightProgram, _count_compiles
+        self._step_jit = _WeightProgram(
+            decoder, _count_compiles(self._forward_step,
+                                     "decode_step_paged"),
+            f"decode_step_{decoder.family}", donate=_CACHE_ARG)
+        self._prefill_cache = {}
+
+    def pool_structs(self):
+        """Shapes of the cache pytree, as the 1-tuple ``PagedSlots``
+        keeps in ``pool``."""
+        import jax
+
+        pages = {n: jax.ShapeDtypeStruct(
+            (layers, self.num_pages, self.block * width), dtype)
+            for n, (layers, width, dtype) in self.layout["pages"].items()}
+        state = {n: jax.ShapeDtypeStruct((self.num_slots,) + tuple(shape),
+                                         dtype)
+                 for n, (shape, dtype) in self.layout["state"].items()}
+        return ({"pages": pages, "state": state},)
+
+    def init_pool(self):
+        import jax
+        import jax.numpy as jnp
+
+        return jax.tree_util.tree_map(
+            lambda s: jnp.zeros(s.shape, s.dtype), self.pool_structs())
+
+    def _forward_step(self, p, cache, counters, bt, tokens, cursor,
+                      occupied):
+        view = _StepView(self, cache, bt, cursor, occupied)
+        logits = self.dec.forward(p, tokens, view)
+        return (view.cache(),), (logits, counters + view.counts)
+
+    def _forward_prefill(self, p, cache, counters, bt_row, tokens, slot,
+                         t):
+        view = _PrefillView(self, cache, bt_row, slot, tokens[0], t)
+        logits = self.dec.forward(p, tokens[0], view)
+        return (view.cache(),), (logits, counters + view.counts)
+
+    def prefill(self, bucket):
+        if bucket not in self._prefill_cache:
+            self._prefill_cache[bucket] = self._WeightProgram(
+                self.dec, self._count(self._forward_prefill,
+                                      "decode_prefill_paged"),
+                f"prefill_{self.dec.family}_b{bucket}", donate=_CACHE_ARG)
+        return self._prefill_cache[bucket]
+
+
 class PagedSlots:
     """Paged scheduler backend: the device pool + pure-host page
     bookkeeping (block tables, refcounts, prefix index).
@@ -431,12 +650,29 @@ class PagedSlots:
         self.prefix_on = (prefix_cache_on() if prefix_cache is None
                           else bool(prefix_cache))
         self.prefill_buckets = tuple(prefill_buckets or ())
-        self.kernel_mode = (paged_kernel_mode() if kernel is None
-                            else str(kernel).strip().lower())
-        self.schedule = self._resolve_schedule()
-        self.programs = _PagedPrograms(
-            decoder, self.block, self.max_blocks, self.num_pages + 1,
-            schedule=self.schedule)
+        # a decoder that declares its cache brings its own forward; the
+        # K/V pool, its kernel and its schedule are the GPT-2 block's
+        self.declared = hasattr(decoder, "paged_layout")
+        if self.declared:
+            self.kernel_mode, self.schedule = "none", None
+            self.programs = _DeclaredPrograms(
+                decoder, self.block, self.max_blocks, self.num_pages + 1,
+                self.num_slots)
+            # per-slot state cannot be picked up at a block boundary
+            self.prefix_on = self.prefix_on \
+                and self.programs.layout["prefix_reuse"]
+        else:
+            self.kernel_mode = (paged_kernel_mode() if kernel is None
+                                else str(kernel).strip().lower())
+            self.schedule = self._resolve_schedule()
+            self.programs = _PagedPrograms(
+                decoder, self.block, self.max_blocks, self.num_pages + 1,
+                schedule=self.schedule)
+        # device counters of a declared decoder's programs, and what
+        # stats() has read of them so far (host side, never wraps)
+        self._counters = None
+        self._counted = np.zeros(_N_COUNTERS, np.int64)
+        self._counters_seen = np.zeros(_N_COUNTERS, np.int64)
         self._reset_pool()
         # trace id of the admission currently allocating, so _alloc can
         # attribute its prefix evictions; None for step-time evictions
@@ -449,9 +685,16 @@ class PagedSlots:
 
     # ----------------------------------------------------------------- pool
     def _reset_pool(self):
-        """A zeroed pool with every page free, no slot holding any and
-        an empty prefix index."""
+        """A zeroed pool (and, where the decoder declares one, a zeroed
+        state a slot) with every page free, no slot holding any and an
+        empty prefix index."""
         self.pool = self.programs.init_pool()
+        if self.declared:
+            import jax.numpy as jnp
+
+            self._read_counters()       # keep what the lost pool counted
+            self._counters = jnp.zeros(_N_COUNTERS, jnp.int32)
+            self._counters_seen[:] = 0
         self.bt = np.zeros((self.num_slots, self.max_blocks), np.int32)
         self.cursor = np.zeros(self.num_slots, np.int32)
         self._free = list(range(self.num_pages, 0, -1))   # pop() -> page 1 last
@@ -468,14 +711,21 @@ class PagedSlots:
         every slot loses its pages: :meth:`step` refuses to tick a slot
         without pages, which is how the scheduler comes to fail the
         requests that were live."""
+        import jax
+
+        if self.declared:
+            args = (self._counters,) + args
         try:
-            self.pool, logits = program(*self.pool, *args)
+            self.pool, out = program(*self.pool, *args)
         except Exception:
-            if any(a.is_deleted() for a in self.pool):
+            if any(a.is_deleted()
+                   for a in jax.tree_util.tree_leaves(self.pool)):
                 self._reset_pool()
                 self._set_gauges()
             raise
-        return logits
+        if self.declared:
+            out, self._counters = out
+        return out
 
     # ------------------------------------------------------------- schedule
     def _resolve_schedule(self):
@@ -521,14 +771,48 @@ class PagedSlots:
         _TM_PAGES.set(self.num_pages, state="total")
         _TM_PAGES.set(len(self._free), state="free")
         _TM_PAGES.set(len(self._prefix), state="prefix")
+        if self.declared:
+            _TM_STATE_SLOTS.set(self._slots_in_use())
+            _TM_LATENT_PAGES.set(self.num_pages - len(self._free))
+
+    def _slots_in_use(self):
+        return sum(1 for pages in self._slot_pages if pages)
+
+    def _read_counters(self):
+        """Fold the device counters into the host's totals.  The device
+        side is int32 and may wrap (after ~2**31 assignments, hours of
+        traffic): the host adds the difference modulo 2**32 since its
+        last read, so a reader that comes by now and then never sees
+        the wrap.  The one device fetch of the counters; never called
+        from a tick."""
+        if self._counters is not None:
+            now = np.asarray(self._counters).astype(np.int64)
+            self._counted += (now - self._counters_seen) % (1 << 32)
+            self._counters_seen = now
+        return self._counted
 
     def stats(self):
         """The ``/healthz`` ``paged`` payload."""
-        return {"block": self.block,
-                "pages_total": self.num_pages,
-                "pages_free": len(self._free),
-                "prefix_pages": len(self._prefix),
-                "kernel": (self.schedule or {"impl": "gather"})["impl"]}
+        out = {"block": self.block,
+               "pages_total": self.num_pages,
+               "pages_free": len(self._free),
+               "prefix_pages": len(self._prefix),
+               "prefix_reuse": self.prefix_on,
+               "kernel": "none" if self.declared
+               else (self.schedule or {"impl": "gather"})["impl"]}
+        if self.declared:
+            held, absent, distinct = (int(n) for n in self._read_counters())
+            out.update(
+                family=self.decoder.family,
+                state_slots_in_use=self._slots_in_use(),
+                latent_pages_in_use=self.num_pages - len(self._free),
+                expert_assignments_held=held,
+                expert_assignments_absent=absent,
+                expert_distinct_hits=distinct)
+            for where, n in (("held", held), ("absent", absent),
+                             ("distinct_hit", distinct)):
+                _TM_EXPERT_PAIRS.set(n, where=where)
+        return out
 
     def _alloc(self, n):
         """``n`` pages off the free list, evicting LRU prefix-only pages
@@ -637,15 +921,17 @@ class PagedSlots:
         padded[0, :t] = tail
         # _snap: self.bt is mutated in place by later admits/steps while
         # this dispatch may still be executing — never alias it
-        args = (_snap(self.bt[slot]), jnp.asarray(padded), jnp.int32(hist),
-                jnp.int32(t))
+        # the K/V programs prefill a tail behind ``hist`` shared tokens; a
+        # declared decoder shares none and is told its slot instead
+        args = (_snap(self.bt[slot]), jnp.asarray(padded),
+                jnp.int32(slot if self.declared else hist), jnp.int32(t))
         logits = self._run(self.programs.prefill(bucket), *args)
         if bucket not in self._cost_prefill_done and _tm.perf.enabled():
             self._cost_prefill_done.add(bucket)
             _tm.perf.attach_cost_analysis(
                 f"decode_prefill_paged[b{bucket}]",
                 self.programs.prefill(bucket),
-                *self.programs.pool_structs(), *args)
+                *self._lowering_args(), *args)
         self.cursor[slot] = p_len
         # promote this prompt's full blocks: they are never written
         # again (writes happen at cursor >= p_len), so they are safe to
@@ -668,7 +954,8 @@ class PagedSlots:
                 time.perf_counter() - t_kv0, slot=slot,
                 pages_shared=n_shared, pages_owned=len(owned),
                 bucket=bucket)
-        return logits[0, t - 1]
+        # a declared decoder's prefill returns the last real token's row
+        return logits if self.declared else logits[0, t - 1]
 
     # ----------------------------------------------------------------- tick
     def step(self, tokens, occupied):
@@ -703,12 +990,14 @@ class PagedSlots:
         # _snap: bt/cursor are mutated in place right below and on the
         # next tick — aliasing them into the async dispatch races
         args = (_snap(self.bt), _snap(tokens), _snap(self.cursor))
+        if self.declared:
+            args += (_snap(occupied, bool),)
         logits = self._run(self.programs._step_jit, *args)
         if not self._cost_step_done and _tm.perf.enabled():
             self._cost_step_done = True
             _tm.perf.attach_cost_analysis(
                 "decode_step_paged", self.programs._step_jit,
-                *self.programs.pool_structs(), *args)
+                *self._lowering_args(), *args)
         adv = occupied.copy()
         adv[starved] = False
         self.cursor[adv] += 1
@@ -723,9 +1012,32 @@ class PagedSlots:
         it is handed the pool's shape, never its buffers."""
         from ..models.decode import _snap
 
-        return self.programs._step_jit.lower(
-            *self.programs.pool_structs(), _snap(self.bt),
-            _snap(np.zeros(self.num_slots, np.int64)), _snap(self.cursor))
+        args = (_snap(self.bt), _snap(np.zeros(self.num_slots, np.int64)),
+                _snap(self.cursor))
+        if self.declared:
+            args += (_snap(np.zeros(self.num_slots), bool),)
+        return self.programs._step_jit.lower(*self._lowering_args(), *args)
+
+    def lower_prefill(self, bucket):
+        """One prefill bucket's program, lowered the same way."""
+        import jax.numpy as jnp
+
+        from ..models.decode import _snap
+
+        return self.programs.prefill(bucket).lower(
+            *self._lowering_args(), _snap(self.bt[0]),
+            jnp.asarray(np.zeros((1, bucket), np.int64)), jnp.int32(0),
+            jnp.int32(1))
+
+    def _lowering_args(self):
+        """What stands for the pool (and the counters) when a program
+        is lowered without being run: shapes, never the buffers."""
+        import jax
+
+        structs = self.programs.pool_structs()
+        if self.declared:
+            structs += (jax.ShapeDtypeStruct((_N_COUNTERS,), np.int32),)
+        return structs
 
     def exhausted(self, slot):
         return self.cursor[slot] >= self.decoder.max_len

@@ -19,7 +19,12 @@ The paged serving programs are compiled whole (abstract weights, a few
 layers) for what the kernels alone cannot show: which layout the
 compiler gives the page pool between them.  Any new program that takes
 the pool is added to ``test_paged_program_keeps_pool_layout``
-(docs/serving.md, "The pool's layout is the kernel's").
+(docs/serving.md, "The pool's layout is the kernel's").  The programs of
+a decoder that declares its own cache (``models/ling.py``: latent pages
+and a recurrent state a slot) are compiled the same way at the
+benchmark's real sizes, where a copy of a state array would cost what
+the pool's copies cost before ISSUE 26:
+``test_declared_program_copies_neither_state_nor_pages``.
 """
 import functools
 import os
@@ -34,7 +39,8 @@ from mxnet_tpu.models.decode import KVDecoder
 from mxnet_tpu.ops import flash_attention as fa
 from mxnet_tpu.ops import paged_attention as pa
 from mxnet_tpu.ops import residual_epilogue as repi
-from mxnet_tpu.serving.paged_kv import _PagedPrograms
+from mxnet_tpu.serving.paged_kv import (_N_COUNTERS, _DeclaredPrograms,
+                                       _PagedPrograms)
 
 
 @pytest.fixture(scope="module")
@@ -232,6 +238,124 @@ def test_row_scatter_relayouts_the_pool(one_chip):
     text = _compile_paged(_paged_programs(one_chip, RowScatter), one_chip,
                           "step").as_text()
     assert len(_pool_copies(text)) == 2 + 2 * _LAYERS
+
+
+# ------------------------------- declared cache: state beside the pages
+# serve_batch_ling as the benchmark runs it: the configuration's own
+# widths and layers, 128 slots of 2304 positions
+_LING_SLOTS, _LING_MAX_LEN = 128, 2304
+
+
+def _ling_programs(sds, donate=True):
+    """``_DeclaredPrograms`` over a ``LingDecoder`` that holds shapes for
+    weights, from the benchmark's configuration file."""
+    import json
+
+    from mxnet_tpu.models.ling import LingConfig, LingDecoder
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "ling-3.0-flash-ep4-l7.json")) as f:
+        config = json.load(f)
+    config.pop("rehearse")
+    import sys
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmark.families import ling as fam
+
+    dec = LingDecoder.__new__(LingDecoder)
+    dec.cfg = LingConfig.from_dict(config)
+    dec.p = {k: sds(tuple(v["shape"]),
+                    "float32" if k.endswith(fam.KEPT_FLOAT32)
+                    else "bfloat16")
+             for k, v in fam.param_specs(config).items()}
+    dec.max_len, dec.vocab = _LING_MAX_LEN, config["vocab_size"]
+    dec._cache_dtype = jnp.dtype("bfloat16")
+    M = _LING_MAX_LEN // _BLOCK
+    progs = _DeclaredPrograms(dec, _BLOCK, M, _LING_SLOTS * M + 1,
+                              _LING_SLOTS)
+    if not donate:
+        from mxnet_tpu.models.decode import _WeightProgram
+        progs._step_jit = _WeightProgram(
+            dec, progs._forward_step, "decode_step_ling_kept")
+    return progs
+
+
+def _compile_ling(progs, sds, which):
+    cache = jax.tree_util.tree_map(lambda s: sds(s.shape, s.dtype),
+                                   progs.pool_structs())
+    counters = sds((_N_COUNTERS,), "int32")
+    B, M = _LING_SLOTS, _LING_MAX_LEN // _BLOCK
+    if which == "step":
+        lowered = progs._step_jit.lower(
+            *cache, counters, sds((B, M), "int32"), sds((B,), "int32"),
+            sds((B,), "int32"), sds((B,), "bool"))
+    else:
+        lowered = progs.prefill(which).lower(
+            *cache, counters, sds((M,), "int32"), sds((1, which), "int32"),
+            sds((), "int32"), sds((), "int32"))
+    return lowered.compile(), cache
+
+
+def _copies_of(text, shapes):
+    """Instructions of the optimized HLO that copy an array of one of
+    ``shapes`` (``f32[128,32,128,128]``): a ``copy``, its asynchronous
+    start, or a copy fusion."""
+    found = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = (.*?) ([\w-]+)\(", line)
+        if m is None or not any(s in m.group(2) for s in shapes):
+            continue
+        name, op = m.group(1), m.group(3)
+        if op in ("copy", "copy-start") or (op == "fusion"
+                                            and "copy" in name):
+            found.append(name)
+    return found
+
+
+def _shape_text(s):
+    short = {"float32": "f32", "bfloat16": "bf16"}[jnp.dtype(s.dtype).name]
+    return "%s[%s]" % (short, ",".join(str(d) for d in s.shape))
+
+
+@pytest.mark.parametrize("which", ("step", 256, 2048))
+def test_declared_program_copies_neither_state_nor_pages(one_chip, which):
+    """The step and the prefills of the Ling decoder at the benchmark's
+    sizes: no instruction copies a recurrent state (268 MB a KDA layer,
+    1.61 GB in all) or the latent pool (340 MB), every leaf of the
+    cache is written in place in the donated buffers, and the program
+    fits the chip beside its 12.3 GB of arguments.  The convolution
+    tails (9 MB a layer) are not held to this: the step's compiler
+    stages them through its fast memory, which costs microseconds."""
+    compiled, cache = _compile_ling(_ling_programs(one_chip), one_chip,
+                                    which)
+    text = compiled.as_text()
+    leaves = jax.tree_util.tree_leaves(cache)
+    nbytes = lambda s: s.dtype.itemsize * functools.reduce(
+        lambda a, b: a * b, s.shape)
+    big = {_shape_text(s) for s in leaves if nbytes(s) > 64e6}
+    assert big == {"f32[128,32,128,128]", "bf16[1,18433,9216]"}
+    assert _copies_of(text, big) == []
+    cache_bytes = sum(nbytes(s) for s in leaves)
+    assert 1.9e9 < cache_bytes < 2.1e9
+    mem = compiled.memory_analysis()
+    # the device pads the pool's 18,433 pages to a whole tile: 126 KB
+    assert cache_bytes <= mem.alias_size_in_bytes < 1.001 * cache_bytes
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.0e9
+    if which == "step":
+        # the TPU compiler's own grouped-matmul kernels (lax.ragged_dot),
+        # three a MoE layer and one for its group metadata: no kernel of
+        # this repo is in the step
+        assert text.count('custom_call_target="tpu_custom_call"') == 24
+        assert "paged_attn" not in text
+
+
+def test_a_cache_that_is_not_donated_is_not_written_in_place(one_chip):
+    """The control: compiled without ``donate``, nothing aliases, so the
+    test above is known to see a state that is not carried in place."""
+    compiled, _ = _compile_ling(_ling_programs(one_chip, donate=False),
+                                one_chip, "step")
+    assert compiled.memory_analysis().alias_size_in_bytes == 0
 
 
 # ------------------------------------------------------ residual epilogue
