@@ -7,13 +7,22 @@ for every allocated page whether or not the slot's cursor ever reached
 it.  This module computes the same decode attention straight off the
 page pool, three lowerings behind one schedule-driven entry:
 
-- **pallas** — the TPU kernel: grid over ``(B, H)`` (or flattened,
-  a schedule knob), per-slot block table and cursors ride as scalar
-  prefetch, and the kernel DMAs ONE ``(block, dh)`` VMEM tile per KV
-  page from the HBM-resident pool — optionally only the pages the
-  cursor has reached (``live_only``).  Decode is forward-only, so no
-  custom VJP.  ``interpret=True`` runs the same kernel on CPU: the
-  parity-test hook, bitwise against the gather path on aligned shapes.
+- **pallas** — the TPU kernel: one program a slot (grid ``(B,)``), the
+  block table and the cursors ride as scalar prefetch.  The pool is
+  ``(P, L, H, block, dh)`` row-major, so ``pool[pg, layer]`` is one
+  contiguous ``(H, block, dh)`` run: a page comes in for ALL heads in
+  one DMA.  The program walks its slot's live pages (those the cursor
+  has reached) a chunk at a time: every copy of a chunk is started
+  before any is waited for, the next chunk's copies — after a slot's
+  last chunk, the next slot's first — fly while this chunk's scores,
+  softmax and weighted sum are computed (two VMEM buffers), and a
+  running max / sum / accumulator in f32 joins the chunks — so neither
+  the copies nor the arithmetic ever cover a page past the cursor, and
+  VMEM holds two chunks whatever ``max_len`` is (:func:`chunk_pages`).
+  Decode is forward-only, so no custom VJP.  ``interpret=True`` runs
+  the same kernel on CPU: the parity-test hook.  The sums over
+  positions are taken chunk by chunk, so against gather it is allclose
+  in f32 (a few ulp), not bitwise.
 - **pagewalk** — a lax lowering of the same idea for hosts without a
   TPU: a ``fori_loop`` whose trip count is the *live* page count
   (``max(cursor)``-bounded, a traced scalar — no host sync, no
@@ -43,7 +52,7 @@ from ..base import mxu_precision
 
 __all__ = [
     "supports", "keysig", "default_schedule", "candidate_schedules",
-    "paged_attention", "gather_tables", "make_bench_fn",
+    "chunk_pages", "paged_attention", "gather_tables", "make_bench_fn",
 ]
 
 # the masking constant of the decode stack (== models.decode.NEG_INF;
@@ -55,11 +64,11 @@ _PAGEWALK_CHUNKS = (1, 2, 4, 8)
 
 def supports(block: int, dh: int, dtype) -> bool:
     """Will Mosaic take the Pallas kernel at ``(block, dh)`` KV pages?
-    One page is one VMEM tile DMA'd out of the pool: ``dh`` must fill
-    whole 128-wide lanes (the v5e compiler refuses a narrower slice of
-    the pool: "must be aligned to tiling (128)") and ``block`` whole
-    sublane tiles — 8 rows of f32, 16 of packed bf16.  Everything else
-    takes gather, by this gate."""
+    A page is DMA'd out of the pool as ``(H, block, dh)`` tiles: ``dh``
+    must fill whole 128-wide lanes (the v5e compiler refuses a narrower
+    slice of the pool: "must be aligned to tiling (128)") and ``block``
+    whole sublane tiles — 8 rows of f32, 16 of packed bf16.  Everything
+    else takes gather, by this gate."""
     dt = jnp.dtype(dtype)
     if dt == jnp.dtype(jnp.float32):
         rows = 8
@@ -78,9 +87,9 @@ def keysig(B: int, H: int, M: int, block: int, dh: int, dtype) -> str:
 
 def default_schedule(platform: str, block: int, dh: int, dtype) -> dict:
     """What runs with no tuned winner: the kernel on a TPU whose shape
-    qualifies, the bitwise gather path everywhere else."""
+    qualifies, the gather path everywhere else."""
     if platform == "tpu" and supports(block, dh, dtype):
-        return {"impl": "pallas", "grid": "bh", "live_only": True}
+        return {"impl": "pallas"}
     return {"impl": "gather"}
 
 
@@ -88,17 +97,15 @@ def candidate_schedules(platform: str, block: int, dh: int, M: int,
                         dtype) -> list:
     """The search space for one shape signature.  Gather is always a
     candidate (the winner can never lose to not tuning); pagewalk chunk
-    sizes must divide the block-table width; pallas variants (grid
-    layout x live-page DMA) only where the compiled kernel can run."""
+    sizes must divide the block-table width; the pallas kernel (it has
+    no knob: its chunk follows from the shapes) only where the compiled
+    kernel can run."""
     cands = [{"impl": "gather"}]
     for ch in _PAGEWALK_CHUNKS:
         if ch <= M and M % ch == 0:
             cands.append({"impl": "pagewalk", "chunk": ch})
     if platform == "tpu" and supports(block, dh, dtype):
-        for grid in ("bh", "flat"):
-            for live in (True, False):
-                cands.append({"impl": "pallas", "grid": grid,
-                              "live_only": live})
+        cands.append({"impl": "pallas"})
     return cands
 
 
@@ -176,88 +183,156 @@ def _pagewalk_attention(q, pool_k, pool_v, bt, cursor, layer, block,
 
 
 # ---------------------------------------------------------------- pallas
+# VMEM the kernel may hold in page buffers (K and V, two chunks each);
+# the chunk — pages in flight — follows from it and the page's shape, so
+# VMEM never grows with the block table.  A chunk is unrolled (one DMA
+# descriptor a page), hence the second cap.
+_VMEM_BUDGET = 2 << 20
+_MAX_CHUNK_PAGES = 16
+
+
+def chunk_pages(H: int, block: int, dh: int, dtype, M: int) -> int:
+    """Pages of one slot the kernel keeps in flight: what fits the VMEM
+    budget twice over (two buffers) for K and V, at most the table."""
+    page = H * block * dh * jnp.dtype(dtype).itemsize
+    return int(max(1, min(M, _MAX_CHUNK_PAGES, _VMEM_BUDGET // (4 * page))))
+
+
 def _pallas_attention(q, pool_k, pool_v, bt, cursor, layer, block,
-                      schedule, interpret):
+                      interpret):
     B, H, _n, dh = q.shape
     M = bt.shape[1]
-    S = M * block
-    flat = schedule.get("grid") == "flat"
-    live_only = bool(schedule.get("live_only", True))
+    C = chunk_pages(H, block, dh, q.dtype, M)
+    Cb = C * block
 
     def kernel(bt_ref, cur_ref, q_ref, pk_ref, pv_ref, o_ref,
-               kbuf, vbuf, sem):
-        if flat:
-            i = pl.program_id(0)
-            b, h = i // H, i % H
-        else:
-            b, h = pl.program_id(0), pl.program_id(1)
+               kbuf, vbuf, sem, first_ref):
+        b = pl.program_id(0)
         cur = cur_ref[b]
-        if live_only:
-            # skipped (dead) pages leave vbuf unread-after-write garbage;
-            # their attention weights are exact zeros, but 0 * NaN is
-            # NaN — zero the value tiles so dead pages contribute exact
-            # zeros like the gather path.  kbuf garbage is safe: dead
-            # scores are replaced wholesale by NEG_INF below.
-            vbuf[...] = jnp.zeros((S, dh), vbuf.dtype)
-        for m in range(M):
-            def _dma(m=m):
-                pg = bt_ref[b, m]
-                cp = pltpu.make_async_copy(
-                    pk_ref.at[pg, layer, h],
-                    kbuf.at[pl.ds(m * block, block)], sem)
-                cp.start()
-                cp.wait()
-                cp = pltpu.make_async_copy(
-                    pv_ref.at[pg, layer, h],
-                    vbuf.at[pl.ds(m * block, block)], sem)
-                cp.start()
-                cp.wait()
-            if live_only:
-                pl.when(m * block <= cur)(_dma)
-            else:
-                _dma()
-        qv = q_ref[0, 0]                                 # (1, dh)
+
+        def live_pages(slot):
+            # pages holding [0, cursor]: at least one, so every program
+            # runs a chunk and the chain of prefetches never breaks
+            return jnp.clip(cur_ref[slot] // block + 1, 1, M)
+
+        n_live = live_pages(b)
+        n_chunks = (n_live + C - 1) // C
+
+        def copies(slot, c, buf, j):
+            # page j of the slot's chunk c, all heads at once:
+            # pool[pg, layer] is (H, block, dh), contiguous in HBM
+            pg = bt_ref[slot, c * C + j]
+            rows = pl.ds(j * block, block)
+            return (pltpu.make_async_copy(pk_ref.at[pg, layer],
+                                          kbuf.at[buf, :, rows],
+                                          sem.at[0, buf]),
+                    pltpu.make_async_copy(pv_ref.at[pg, layer],
+                                          vbuf.at[buf, :, rows],
+                                          sem.at[1, buf]))
+
+        def start(slot, c, buf):
+            live = live_pages(slot)
+            for j in range(C):
+                @pl.when(c * C + j < live)
+                def _(j=j):
+                    for cp in copies(slot, c, buf, j):
+                        cp.start()
+
+        def wait(c, buf):
+            for j in range(C):
+                live = c * C + j < n_live
+
+                @pl.when(live)
+                def _(j=j):
+                    for cp in copies(b, c, buf, j):
+                        cp.wait()
+
+                # a page past the cursor was never fetched: its buffer
+                # rows hold whatever was there, and 0 x NaN is NaN, so
+                # the value rows are zeroed before they meet a weight.
+                # The key rows may stay: their scores are replaced
+                # wholesale by NEG_INF below
+                @pl.when(jnp.logical_not(live))
+                def _(j=j):
+                    vbuf[buf, :, pl.ds(j * block, block)] = jnp.zeros(
+                        (H, block, dh), vbuf.dtype)
+
+        # programs run one after another (one core walks the grid): each
+        # starts the NEXT slot's first chunk behind its own last one and
+        # leaves in ``first_ref`` which buffer that went to, so only
+        # the first slot's first fetch is waited for in the open
+        @pl.when(b == 0)
+        def _():
+            first_ref[0] = 0
+            start(0, 0, 0)
+
+        first = first_ref[0]
+        qv = q_ref[0]                                    # (H, 1, dh)
         # Mosaic only takes a matmul that accumulates in 32 bits, and a
         # bf16 one only at single-pass precision (the package default is
         # fp32 passes): both products and the softmax between them run
         # in f32 whatever the cache dtype; the weights drop back to the
         # cache dtype for the MXU
         prec = mxu_precision(qv)
-        scores = jnp.einsum("nd,sd->ns", qv, kbuf[...], precision=prec,
-                            preferred_element_type=jnp.float32) \
-            / jnp.sqrt(jnp.asarray(dh, jnp.float32))
-        s_idx = jax.lax.broadcasted_iota(jnp.int32, (1, S), 1)
-        scores = jnp.where(s_idx <= cur, scores, NEG_INF)
-        att = jax.nn.softmax(scores, axis=-1)
-        o_ref[0, 0] = jnp.einsum(
-            "ns,sd->nd", att.astype(vbuf.dtype), vbuf[...],
-            precision=prec,
-            preferred_element_type=jnp.float32).astype(o_ref.dtype)
+        scale = jnp.sqrt(jnp.asarray(dh, jnp.float32))
 
-    if flat:
-        grid = (B * H,)
-        qmap = lambda i, *_: (i // H, i % H, 0, 0)
-    else:
-        grid = (B, H)
-        qmap = lambda b, h, *_: (b, h, 0, 0)
+        def body(c, carry):
+            m, l, acc = carry
+            buf = (first + c) % 2
+
+            # what flies during this chunk's arithmetic: the slot's next
+            # chunk, or behind its last one the next slot's first
+            last = c + 1 == n_chunks
+
+            @pl.when(jnp.logical_or(jnp.logical_not(last), b + 1 < B))
+            def _():
+                start(jnp.where(last, b + 1, b), jnp.where(last, 0, c + 1),
+                      1 - buf)
+
+            wait(c, buf)
+            s = jnp.einsum("hnd,hsd->hns", qv, kbuf[buf], precision=prec,
+                           preferred_element_type=jnp.float32) / scale
+            pos = c * Cb + jax.lax.broadcasted_iota(jnp.int32, (1, 1, Cb), 2)
+            s = jnp.where(pos <= cur, s, NEG_INF)
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(s - m_new)
+            l = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
+            acc = alpha * acc + jnp.einsum(
+                "hns,hsd->hnd", p.astype(vbuf.dtype), vbuf[buf],
+                precision=prec, preferred_element_type=jnp.float32)
+            return m_new, l, acc
+
+        _m, l, acc = jax.lax.fori_loop(
+            0, n_chunks, body,
+            (jnp.full((H, 1, 1), NEG_INF, jnp.float32),
+             jnp.zeros((H, 1, 1), jnp.float32),
+             jnp.zeros((H, 1, dh), jnp.float32)))
+        o_ref[0] = (acc / l).astype(o_ref.dtype)
+        first_ref[0] = (first + n_chunks) % 2    # the buffer after my last
+
+    qmap = lambda b, *_: (b, 0, 0, 0)
     gs = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,                   # bt, cursor
-        grid=grid,
+        grid=(B,),
         in_specs=[
-            pl.BlockSpec((1, 1, 1, dh), qmap),
+            pl.BlockSpec((1, H, 1, dh), qmap),
             pl.BlockSpec(memory_space=pl.ANY),   # pool_k stays in HBM
             pl.BlockSpec(memory_space=pl.ANY),   # pool_v stays in HBM
         ],
-        out_specs=pl.BlockSpec((1, 1, 1, dh), qmap),
+        out_specs=pl.BlockSpec((1, H, 1, dh), qmap),
         scratch_shapes=[
-            pltpu.VMEM((S, dh), q.dtype),
-            pltpu.VMEM((S, dh), q.dtype),
-            pltpu.SemaphoreType.DMA,
+            pltpu.VMEM((2, H, Cb, dh), q.dtype),
+            pltpu.VMEM((2, H, Cb, dh), q.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),     # (K | V, buffer)
+            pltpu.SMEM((1,), jnp.int32),         # first chunk's buffer
         ])
     return pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((B, H, 1, dh), q.dtype),
         grid_spec=gs,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),  # in order: see above
         interpret=interpret,
         name="paged_attn",
     )(bt.astype(jnp.int32), cursor.astype(jnp.int32), q, pool_k, pool_v)
@@ -284,7 +359,7 @@ def paged_attention(q, pool_k, pool_v, bt, cursor, layer, *, block,
         # says so
         interp = bool(interpret or sched.get("interpret", False))
         return _pallas_attention(
-            q, pool_k, pool_v, bt, cursor, layer, block, sched, interp)
+            q, pool_k, pool_v, bt, cursor, layer, block, interp)
     if impl == "pagewalk":
         return _pagewalk_attention(q, pool_k, pool_v, bt, cursor, layer,
                                    block, sched.get("chunk", 1))

@@ -747,8 +747,7 @@ class PagedSlots:
         if mode in ("pallas", "interpret"):
             if not _pa.supports(blk, d.dh, dtype):
                 return None         # shape gate even when forced
-            return {"impl": "pallas", "grid": "bh", "live_only": True,
-                    "interpret": mode == "interpret"}
+            return {"impl": "pallas", "interpret": mode == "interpret"}
         if mode == "pagewalk":
             return {"impl": "pagewalk", "chunk": 1}
         if mode != "auto":

@@ -105,35 +105,50 @@ def test_flash_bwd_compiles(one_chip, tile):
 
 
 # ------------------------------------------------------------------ paged
-@pytest.mark.parametrize("grid", ["bh", "flat"])
-@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
-def test_paged_attention_compiles(one_chip, dtype, grid):
-    """The serving width of chip_smoke.py: 8 slots, 16 heads of 128,
-    16-token pages, max_len 1024 (64 pages a slot)."""
-    B, H, dh, block, M, L = 8, 16, 128, 16, 64, 2
-    assert pa.supports(block, dh, dtype)
-    pool = one_chip((B * M + 1, L, H, block, dh), dtype)
-    _compile(
+def _compile_paged_kernel(sds, B, H, dh, block, M, dtype, L=2):
+    pool = sds((B * M + 1, L, H, block, dh), dtype)
+    return _compile(
         lambda q, pk, pv, bt, cur: pa._pallas_attention(
-            q, pk, pv, bt, cur, 1, block,
-            {"grid": grid, "live_only": True}, False),
-        one_chip((B, H, 1, dh), dtype), pool, pool,
-        one_chip((B, M), "int32"), one_chip((B,), "int32"))
+            q, pk, pv, bt, cur, 1, block, False),
+        sds((B, H, 1, dh), dtype), pool, pool,
+        sds((B, M), "int32"), sds((B,), "int32"))
+
+
+@pytest.mark.parametrize("M", [64, 256, 1024])
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_paged_attention_compiles(one_chip, dtype, M):
+    """serve_batch's width: 16 slots, 16 heads of 128, 16-token pages,
+    at its ``max_len`` 1024 (64 pages a slot) and at 4096 and 16384:
+    the kernel holds two chunks of pages in VMEM whatever the table's
+    length (the old one held the whole table, 2 x 4 MB at 16384)."""
+    B, H, dh, block = 16, 16, 128, 16
+    assert pa.supports(block, dh, dtype)
+    chunk = pa.chunk_pages(H, block, dh, dtype, M)
+    assert chunk == pa.chunk_pages(H, block, dh, dtype, 64)
+    assert 4 * chunk * H * block * dh * jnp.dtype(dtype).itemsize \
+        <= pa._VMEM_BUDGET
+    _compile_paged_kernel(one_chip, B, H, dh, block, M, dtype)
+
+
+@pytest.mark.parametrize("B,H,block,M,dtype", [
+    (8, 16, 16, 64, "bfloat16"),     # chip_smoke.py's serve_paged_http
+    (3, 2, 8, 4, "float32"),         # the CPU parity tests' small pages
+    (4, 32, 32, 16, "bfloat16"),     # 256 KB pages: two in flight
+])
+def test_paged_attention_compiles_other_callers(one_chip, B, H, block, M,
+                                                dtype):
+    """One algorithm, its chunk read from the shapes: the other
+    callers' sizes compile by the same path."""
+    _compile_paged_kernel(one_chip, B, H, 128, block, M, dtype)
 
 
 def test_paged_gate_rejects_what_mosaic_rejects(one_chip):
     """A head narrower than the 128-lane tiling: the compiler refuses
     the page slice, and ``supports()`` must have said so first."""
-    B, H, dh, block, M, L = 8, 2, 32, 16, 8, 2
+    B, H, dh, block, M = 8, 2, 32, 16, 8
     assert not pa.supports(block, dh, "float32")
-    pool = one_chip((B * M + 1, L, H, block, dh), "float32")
     with pytest.raises(Exception, match="aligned to tiling"):
-        _compile(
-            lambda q, pk, pv, bt, cur: pa._pallas_attention(
-                q, pk, pv, bt, cur, 1, block,
-                {"grid": "bh", "live_only": True}, False),
-            one_chip((B, H, 1, dh), "float32"), pool, pool,
-            one_chip((B, M), "int32"), one_chip((B,), "int32"))
+        _compile_paged_kernel(one_chip, B, H, dh, block, M, "float32")
 
 
 # ------------------------------------------- paged programs and the pool
@@ -142,18 +157,24 @@ def test_paged_gate_rejects_what_mosaic_rejects(one_chip):
 # does not see it (ffn, vocabulary), keep a compile to seconds
 _SLOTS, _BLOCK, _MAX_LEN, _HEADS, _DH, _LAYERS = 16, 16, 1024, 16, 128, 3
 _PAGES = _SLOTS * _MAX_LEN // _BLOCK + 1
-_POOL = "bf16[%d,%d,%d,%d,%d]" % (_PAGES, _LAYERS, _HEADS, _BLOCK, _DH)
+
+
+def _pool_shape(layers):
+    return "bf16[%d,%d,%d,%d,%d]" % (_PAGES, layers, _HEADS, _BLOCK, _DH)
+
+
+_POOL = _pool_shape(_LAYERS)
 _BUCKETS = (128, 256, 512, 1024)
 
 
-def _paged_programs(sds, cls=_PagedPrograms):
+def _paged_programs(sds, cls=_PagedPrograms, layers=_LAYERS):
     """``serving/paged_kv.py``'s programs over a decoder that holds
     shapes for weights: nothing is allocated, everything lowers."""
     D, F, V = _HEADS * _DH, 512, 1024
     p = {"tok_embed_weight": (V, D), "pos_embed": (1, _MAX_LEN, D),
          "final_ln_gamma": (D,), "final_ln_beta": (D,),
          "lm_head_weight": (V, D), "lm_head_bias": (V,)}
-    for i in range(_LAYERS):
+    for i in range(layers):
         for w in ("q", "k", "v", "proj"):
             p[f"layer{i}_{w}_weight"] = (D, D)
             p[f"layer{i}_{w}_bias"] = (D,)
@@ -165,7 +186,7 @@ def _paged_programs(sds, cls=_PagedPrograms):
         p[f"layer{i}_ffn_out_bias"] = (D,)
     dec = KVDecoder.__new__(KVDecoder)
     dec.p = {k: sds(shape, "bfloat16") for k, shape in p.items()}
-    dec.L, dec.H, dec.dh, dec.d_model = _LAYERS, _HEADS, _DH, D
+    dec.L, dec.H, dec.dh, dec.d_model = layers, _HEADS, _DH, D
     dec.max_len, dec.mesh = _MAX_LEN, None
     dec._cache_dtype = jnp.dtype("bfloat16")
     return cls(dec, _BLOCK, _MAX_LEN // _BLOCK, _PAGES,
@@ -175,7 +196,7 @@ def _paged_programs(sds, cls=_PagedPrograms):
 def _compile_paged(progs, sds, which):
     """The step program (``which`` = "step") or one prefill bucket's,
     compiled with the pool handed over as ``PagedSlots`` does."""
-    pool = sds((_PAGES, _LAYERS, _HEADS, _BLOCK, _DH), "bfloat16")
+    pool = sds((_PAGES, progs.dec.L, _HEADS, _BLOCK, _DH), "bfloat16")
     M = _MAX_LEN // _BLOCK
     if which == "step":
         lowered = progs._step_jit.lower(
@@ -188,14 +209,14 @@ def _compile_paged(progs, sds, which):
     return lowered.compile()
 
 
-def _pool_copies(text):
+def _pool_copies(text, pool=_POOL):
     """Instructions of the optimized HLO that copy the whole pool: a
     ``copy`` (or its asynchronous start) or a copy fusion whose result
     has the pool's shape."""
     found = []
     for line in text.splitlines():
         m = re.match(r"\s*(?:ROOT )?%(\S+) = (.*?) ([\w-]+)\(", line)
-        if m is None or _POOL not in m.group(2):
+        if m is None or pool not in m.group(2):
             continue
         name, op = m.group(1), m.group(3)
         if op in ("copy", "copy-start") or (op == "fusion"
@@ -222,6 +243,22 @@ def test_paged_program_keeps_pool_layout(one_chip, which):
         == ["0", "1"], "the pools are not both aliased to the outputs"
     pool_bytes = 2 * _PAGES * _LAYERS * _HEADS * _BLOCK * _DH
     assert compiled.memory_analysis().alias_size_in_bytes == 2 * pool_bytes
+
+
+def test_paged_step_at_full_depth_holds_one_kernel_a_layer(one_chip):
+    """serve_batch's step at its 24 layers: 24 Mosaic calls (what
+    ``kernels_in_step`` counts on the chip), no copy of the pool, both
+    pools aliased, and temporaries that stay in the megabytes."""
+    layers = 24
+    compiled = _compile_paged(_paged_programs(one_chip, layers=layers),
+                              one_chip, "step")
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == layers
+    assert _pool_copies(text, _pool_shape(layers)) == []
+    mem = compiled.memory_analysis()
+    pool_bytes = 2 * _PAGES * layers * _HEADS * _BLOCK * _DH
+    assert mem.alias_size_in_bytes == 2 * pool_bytes
+    assert mem.temp_size_in_bytes < 64 << 20
 
 
 def test_row_scatter_relayouts_the_pool(one_chip):
