@@ -1477,7 +1477,7 @@ def _autotune_micro():
         platform = jax.default_backend()
         sig = pa.keysig(B, H, M, block, dh, dtype)
         default = pa.default_schedule(platform, block, dh, dtype)
-        cands = pa.candidate_schedules(platform, block, dh, M, dtype)
+        cands = pa.candidate_schedules(platform, block, dh, dtype)
         bench = functools.partial(pa.make_bench_fn, B=B, H=H, M=M,
                                   block=block, dh=dh, L=L, dtype=dtype)
         tic = time.perf_counter()

@@ -23,8 +23,6 @@ see :meth:`KVDecoder.prefill_padded`, :meth:`KVDecoder.step_slots`, and
 int8 + per-channel scales and dequantizes inside the compiled programs
 (`serving/quantize.py`).
 """
-from functools import partial
-
 import numpy as np
 
 import jax
@@ -128,12 +126,128 @@ def _fc(x, w, b=None):
     return y if b is None else y + b
 
 
+# ------------------------------------------------------- cache views
+# What `KVDecoder.forward` sees of the contiguous (L, B, H, max_len, dh)
+# cache.  A view says where its tokens stand (``embed``: the hidden
+# state the blocks start from, token rows plus the positional table's at
+# the view's positions, and the mask of what each query may see) and
+# takes a layer's new K/V rows in before attending over the cache
+# (``attend``).  serving/paged_kv.py holds the two views of the page
+# pool, under the same two names.
+
+class _PositionsView:
+    """``n`` new positions from ``pos`` on, every row at the same ones
+    (``prefill``, ``step``, the generate loops).  ``pos`` rides as a
+    traced scalar; the HOST tracks the counter so no step ever fetches
+    device state (a per-step sync would serialize the host behind every
+    decode step)."""
+    step, length = False, None
+
+    def __init__(self, kc, vc, pos):
+        self.kc, self.vc, self._pos = kc, vc, pos
+
+    def embed(self, tok, table):
+        _, n, D = tok.shape
+        h = tok + jax.lax.dynamic_slice(table, (0, self._pos, 0), (1, n, D))
+        # positions 0..max_len-1 valid iff <= pos + the query's offset
+        span = self._pos + jnp.arange(n)                     # (n,)
+        self._mask = jnp.arange(self.kc.shape[3])[None, :] <= span[:, None]
+        return h
+
+    def attend(self, i, qh, kh, vh):
+        self.kc = jax.lax.dynamic_update_slice(
+            self.kc, kh[None], (i, 0, 0, self._pos, 0))
+        self.vc = jax.lax.dynamic_update_slice(
+            self.vc, vh[None], (i, 0, 0, self._pos, 0))
+        scores = jnp.einsum("bhnd,bhsd->bhns", qh, self.kc[i]) \
+            / jnp.sqrt(jnp.asarray(qh.shape[-1], qh.dtype))
+        scores = jnp.where(self._mask[None, None], scores, NEG_INF)
+        att = jax.nn.softmax(scores, axis=-1)
+        return jnp.einsum("bhns,bhsd->bhnd", att, self.vc[i])
+
+
+class _SlotsView:
+    """One decode position for EVERY slot at once, each row at its own
+    cache position: row ``b`` writes its new K/V at ``cursor[b]`` and
+    attends over ``[start[b], cursor[b]]`` with position embedding
+    ``cursor[b] - start[b]``.  Rows whose slot is free still ride along
+    (fixed batch keeps this ONE compiled program); their outputs are
+    garbage the caller ignores and their writes land at position
+    ``cursor[b]`` of a row :meth:`KVDecoder.adopt_row` fully overwrites
+    on the next admission."""
+    step, length = True, None
+
+    def __init__(self, kc, vc, start, cursor):
+        self.kc, self.vc, self._start, self._cursor = kc, vc, start, cursor
+
+    def embed(self, tok, table):
+        S = self.kc.shape[3]
+        pos_ids = jnp.clip(self._cursor - self._start, 0, S - 1)
+        h = (tok + jnp.take(table[0], pos_ids, axis=0))[:, None]
+        s_idx = jnp.arange(S)
+        self._valid = (s_idx[None, :] >= self._start[:, None]) & \
+            (s_idx[None, :] <= self._cursor[:, None])        # (B, S)
+        self._rows = jnp.arange(tok.shape[0])
+        return h
+
+    def attend(self, i, qh, kh, vh):
+        at = (i, self._rows, slice(None), self._cursor)
+        self.kc = self.kc.at[at].set(kh[:, :, 0])
+        self.vc = self.vc.at[at].set(vh[:, :, 0])
+        scores = jnp.einsum("bhnd,bhsd->bhns", qh, self.kc[i]) \
+            / jnp.sqrt(jnp.asarray(qh.shape[-1], qh.dtype))
+        scores = jnp.where(self._valid[:, None, None, :], scores, NEG_INF)
+        att = jax.nn.softmax(scores, axis=-1)
+        return jnp.einsum("bhns,bhsd->bhnd", att, self.vc[i])
+
+
+class _PaddedView:
+    """Left-padded prefill: row ``b``'s real prompt right-aligned in the
+    last ``T - start[b]`` positions.  Real tokens write K/V at their
+    padded index and attend over ``[start[b], n]``; pad queries
+    (n < start) attend to themselves only -- finite garbage that every
+    real query's window excludes.  Left-padding makes ``logits[:, -1]``
+    the next-token logits of EVERY row regardless of its prompt
+    length."""
+    step, length = False, None
+
+    def __init__(self, kc, vc, start):
+        self.kc, self.vc, self._start = kc, vc, start
+
+    def embed(self, tok, table):
+        T, S, start = tok.shape[1], self.kc.shape[3], self._start
+        pos_ids = jnp.clip(jnp.arange(T)[None, :] - start[:, None],
+                           0, S - 1)                         # (B, T)
+        h = tok + jnp.take(table[0], pos_ids, axis=0)
+        n_idx = jnp.arange(T)
+        s_idx = jnp.arange(S)
+        lo = jnp.minimum(start[:, None], n_idx[None, :])     # (B, T)
+        self._valid = (s_idx[None, None, :] <= n_idx[None, :, None]) & \
+            (s_idx[None, None, :] >= lo[:, :, None])         # (B, T, S)
+        return h
+
+    def attend(self, i, qh, kh, vh):
+        self.kc = jax.lax.dynamic_update_slice(
+            self.kc, kh[None], (i, 0, 0, 0, 0))
+        self.vc = jax.lax.dynamic_update_slice(
+            self.vc, vh[None], (i, 0, 0, 0, 0))
+        scores = jnp.einsum("bhnd,bhsd->bhns", qh, self.kc[i]) \
+            / jnp.sqrt(jnp.asarray(qh.shape[-1], qh.dtype))
+        scores = jnp.where(self._valid[:, None], scores, NEG_INF)
+        att = jax.nn.softmax(scores, axis=-1)
+        return jnp.einsum("bhns,bhsd->bhnd", att, self.vc[i])
+
+
 class KVDecoder:
     """One instance per (checkpoint, batch, max_len) combination.
 
     state = (k_cache, v_cache, pos):
       k/v_cache (L, B, H, max_len, dh); pos int32 — tokens filled so far.
     """
+
+    # the paged programs' names: jit_decode_step_paged,
+    # jit_prefill_paged_b<bucket> (serving/paged_kv.py)
+    family = "paged"
 
     def __init__(self, arg_params, num_layers, num_heads, max_len,
                  dtype=jnp.float32, mesh=None, model_axis="model",
@@ -193,14 +307,17 @@ class KVDecoder:
         self.quantize = quantize
         self.p = p
         self._step_jit = _WeightProgram(
-            self, partial(self._forward_positions, n=1), "decode_step")
+            self, self._positions, "decode_step")
         self._reorder_jit = jax.jit(
             lambda kc, vc, idx: (kc[:, idx], vc[:, idx]))
         self._prefill_cache = {}
         self._scan_cache = {}
         self._padded_prefill_cache = {}
         self._slot_step_jit = _WeightProgram(
-            self, _count_compiles(self._forward_slots, "decode_step"),
+            self, _count_compiles(
+                lambda p, kc, vc, tokens, start, cursor: self._over(
+                    _SlotsView(kc, vc, start, cursor), p, tokens),
+                "decode_step"),
             "decode_step_slots")
         # perf plane (telemetry/perf.py): one analytical cost row per
         # compiled decode program, captured at first dispatch
@@ -218,6 +335,12 @@ class KVDecoder:
         # (L, B, H, max_len, dh): split the head axis
         return NamedSharding(self.mesh, P(None, None, self.model_axis))
 
+    def paged_layout(self):
+        """What ``serving/paged_kv.py`` keeps for this decoder: K/V
+        pages, which a cached prefix can share."""
+        return {"kv_pages": (self.L, self.H, self.dh, self._cache_dtype),
+                "pages": {}, "state": {}, "prefix_reuse": True}
+
     # ---------------------------------------------------------------- core
     def _block_qkv(self, p, i, h2):
         name = f"layer{i}"
@@ -226,38 +349,30 @@ class KVDecoder:
         v = _fc(h2, p[f"{name}_v_weight"], p[f"{name}_v_bias"])
         return q, k, v
 
-    def _forward_positions(self, p, kc, vc, pos, tokens, n):
-        """Run ``n`` new positions (tokens (B, n)) against the cache.
-        ``pos`` rides as a traced scalar; the HOST tracks the counter so
-        no step ever fetches device state (a per-step sync would
-        serialize the host behind every decode step)."""
-        B = tokens.shape[0]
-        H, dh, D = self.H, self.dh, self.d_model
-
+    def forward(self, p, tokens, view):
+        """The GPT-2 forward, the only one served: ``tokens`` embedded at
+        the view's positions, the blocks, the head.  What differs
+        between the programs that call it -- where a token stands, where
+        its K/V rows are written, what its query attends over -- is the
+        cache view's (``embed``, ``attend``: the views below for the
+        contiguous cache, ``serving/paged_kv.py``'s for the page pool).
+        Logits ``(B, n, V)``; ``(B, V)`` from a view of one position a
+        row (``view.step``), ``(V,)``, the last real token's row, from
+        a paged prefill (``view.length`` real tokens of one row)."""
+        H, dh = self.H, self.dh
+        if view.length is not None:     # one sequence, handed over flat
+            tokens = tokens[None]
         tok = jnp.take(p["tok_embed_weight"], tokens.astype(jnp.int32),
-                       axis=0)                       # (B, n, D)
-        posv = jax.lax.dynamic_slice(
-            p["pos_embed"], (0, pos, 0), (1, n, D))
-        h = tok + posv
-        # positions 0..max_len-1 valid iff < pos+ their offset
-        span = pos + jnp.arange(n)                   # (n,)
-        mask = jnp.arange(self.max_len)[None, :] <= span[:, None]  # (n, S)
+                       axis=0)
+        h = view.embed(tok, p["pos_embed"])                  # (B, n, D)
+        B, n, D = h.shape
         for i in range(self.L):
             name = f"layer{i}"
             with jax.named_scope(name):
                 h2 = _ln(h, p[f"{name}_ln1_gamma"], p[f"{name}_ln1_beta"])
                 q, k, v = self._block_qkv(p, i, h2)
                 sh = lambda a: a.reshape(B, n, H, dh).transpose(0, 2, 1, 3)
-                qh, kh, vh = sh(q), sh(k), sh(v)         # (B, H, n, dh)
-                kc = jax.lax.dynamic_update_slice(
-                    kc, kh[None], (i, 0, 0, pos, 0))
-                vc = jax.lax.dynamic_update_slice(
-                    vc, vh[None], (i, 0, 0, pos, 0))
-                scores = jnp.einsum("bhnd,bhsd->bhns", qh, kc[i]) \
-                    / jnp.sqrt(jnp.asarray(dh, h.dtype))
-                scores = jnp.where(mask[None, None], scores, NEG_INF)
-                att = jax.nn.softmax(scores, axis=-1)
-                ctx = jnp.einsum("bhns,bhsd->bhnd", att, vc[i])
+                ctx = view.attend(i, sh(q), sh(k), sh(v))    # (B, H, n, dh)
                 ctx = ctx.transpose(0, 2, 1, 3).reshape(B, n, D)
                 proj = _fc(ctx, p[f"{name}_proj_weight"],
                            p[f"{name}_proj_bias"])
@@ -271,7 +386,18 @@ class KVDecoder:
                 h = h + f
         h = _ln(h, p["final_ln_gamma"], p["final_ln_beta"])
         logits = _fc(h, p["lm_head_weight"], p["lm_head_bias"])
-        return (kc, vc), logits                      # logits (B, n, V)
+        if view.step:
+            return logits[:, 0]
+        return logits if view.length is None else logits[0, view.length - 1]
+
+    def _over(self, view, p, tokens):
+        """:meth:`forward` over a view of the contiguous cache ->
+        ``((kc, vc), logits)``: the body of every program below."""
+        logits = self.forward(p, tokens, view)
+        return (view.kc, view.vc), logits
+
+    def _positions(self, p, kc, vc, pos, tokens):
+        return self._over(_PositionsView(kc, vc, pos), p, tokens)
 
     # ----------------------------------------------------------------- API
     def init_state(self, batch):
@@ -299,8 +425,7 @@ class KVDecoder:
             raise ValueError(f"prompt {T} > max_len {self.max_len}")
         if T not in self._prefill_cache:
             self._prefill_cache[T] = _WeightProgram(
-                self, partial(self._forward_positions, n=T),
-                f"decode_prefill_t{T}")
+                self, self._positions, f"decode_prefill_t{T}")
         kc, vc, pos = self.init_state(B)
         (kc, vc), logits = self._prefill_cache[T](kc, vc, pos, tokens)
         return (kc, vc, pos + T), logits
@@ -322,114 +447,9 @@ class KVDecoder:
     # independent request slot whose cache window [start, cursor] the
     # CALLER tracks as host int arrays — no step reads device state, so
     # the scheduler's bookkeeping costs zero syncs, exactly like the
-    # shared-pos API's host counter.  serving/paged_kv.py builds the
-    # paged twin of these programs (block-table gather over a shared
-    # page pool, same layer math via _block_qkv/_ln/_fc) — bitwise
-    # equal to this path on aligned prompts, test-pinned.
-
-    def _forward_slots(self, p, kc, vc, tokens, start, cursor):
-        """One decode position for EVERY slot at once, each row at its
-        own cache position.  ``tokens``/``start``/``cursor`` are (B,)
-        int32: row ``b`` writes its new K/V at cache position
-        ``cursor[b]`` and attends over ``[start[b], cursor[b]]`` with
-        position embedding ``cursor[b] - start[b]``.  Rows whose slot is
-        free still ride along (fixed batch keeps this ONE compiled
-        program); their outputs are garbage the caller ignores and their
-        writes land at position ``cursor[b]`` of a row :meth:`adopt_row`
-        fully overwrites on the next admission."""
-        B = tokens.shape[0]
-        H, dh, D = self.H, self.dh, self.d_model
-
-        tok = jnp.take(p["tok_embed_weight"], tokens.astype(jnp.int32),
-                       axis=0)                               # (B, D)
-        pos_ids = jnp.clip(cursor - start, 0, self.max_len - 1)
-        posv = jnp.take(p["pos_embed"][0], pos_ids, axis=0)  # (B, D)
-        h = (tok + posv)[:, None]                            # (B, 1, D)
-        s_idx = jnp.arange(self.max_len)
-        valid = (s_idx[None, :] >= start[:, None]) & \
-            (s_idx[None, :] <= cursor[:, None])              # (B, S)
-        rows = jnp.arange(B)
-        for i in range(self.L):
-            name = f"layer{i}"
-            with jax.named_scope(name):
-                h2 = _ln(h, p[f"{name}_ln1_gamma"], p[f"{name}_ln1_beta"])
-                q, k, v = self._block_qkv(p, i, h2)
-                sh = lambda a: a.reshape(B, 1, H, dh).transpose(0, 2, 1, 3)
-                qh, kh, vh = sh(q), sh(k), sh(v)             # (B, H, 1, dh)
-                kc = kc.at[i, rows, :, cursor].set(kh[:, :, 0])
-                vc = vc.at[i, rows, :, cursor].set(vh[:, :, 0])
-                scores = jnp.einsum("bhnd,bhsd->bhns", qh, kc[i]) \
-                    / jnp.sqrt(jnp.asarray(dh, h.dtype))
-                scores = jnp.where(valid[:, None, None, :], scores, NEG_INF)
-                att = jax.nn.softmax(scores, axis=-1)
-                ctx = jnp.einsum("bhns,bhsd->bhnd", att, vc[i])
-                ctx = ctx.transpose(0, 2, 1, 3).reshape(B, 1, D)
-                proj = _fc(ctx, p[f"{name}_proj_weight"],
-                           p[f"{name}_proj_bias"])
-                h = h + proj
-                h2 = _ln(h, p[f"{name}_ln2_gamma"], p[f"{name}_ln2_beta"])
-                f = _fc(h2, p[f"{name}_ffn_in_weight"],
-                        p[f"{name}_ffn_in_bias"])
-                f = jax.nn.gelu(f)
-                f = _fc(f, p[f"{name}_ffn_out_weight"],
-                        p[f"{name}_ffn_out_bias"])
-                h = h + f
-        h = _ln(h, p["final_ln_gamma"], p["final_ln_beta"])
-        logits = _fc(h, p["lm_head_weight"], p["lm_head_bias"])
-        return (kc, vc), logits[:, 0]                        # (B, V)
-
-    def _forward_padded(self, p, kc, vc, tokens, start):
-        """Left-padded prefill: ``tokens`` (B, T) with row ``b``'s real
-        prompt right-aligned in the last ``T - start[b]`` positions.
-        Real tokens write K/V at their padded index and attend over
-        ``[start[b], n]``; pad queries (n < start) attend to themselves
-        only — finite garbage that every real query's window excludes.
-        Left-padding makes ``logits[:, -1]`` the next-token logits of
-        EVERY row regardless of its prompt length."""
-        B, T = tokens.shape
-        H, dh, D = self.H, self.dh, self.d_model
-
-        tok = jnp.take(p["tok_embed_weight"], tokens.astype(jnp.int32),
-                       axis=0)                               # (B, T, D)
-        pos_ids = jnp.clip(jnp.arange(T)[None, :] - start[:, None],
-                           0, self.max_len - 1)              # (B, T)
-        posv = jnp.take(p["pos_embed"][0], pos_ids, axis=0)  # (B, T, D)
-        h = tok + posv
-        n_idx = jnp.arange(T)
-        s_idx = jnp.arange(self.max_len)
-        lo = jnp.minimum(start[:, None], n_idx[None, :])     # (B, T)
-        valid = (s_idx[None, None, :] <= n_idx[None, :, None]) & \
-            (s_idx[None, None, :] >= lo[:, :, None])         # (B, T, S)
-        for i in range(self.L):
-            name = f"layer{i}"
-            with jax.named_scope(name):
-                h2 = _ln(h, p[f"{name}_ln1_gamma"], p[f"{name}_ln1_beta"])
-                q, k, v = self._block_qkv(p, i, h2)
-                sh = lambda a: a.reshape(B, T, H, dh).transpose(0, 2, 1, 3)
-                qh, kh, vh = sh(q), sh(k), sh(v)             # (B, H, T, dh)
-                kc = jax.lax.dynamic_update_slice(
-                    kc, kh[None], (i, 0, 0, 0, 0))
-                vc = jax.lax.dynamic_update_slice(
-                    vc, vh[None], (i, 0, 0, 0, 0))
-                scores = jnp.einsum("bhnd,bhsd->bhns", qh, kc[i]) \
-                    / jnp.sqrt(jnp.asarray(dh, h.dtype))
-                scores = jnp.where(valid[:, None], scores, NEG_INF)
-                att = jax.nn.softmax(scores, axis=-1)
-                ctx = jnp.einsum("bhns,bhsd->bhnd", att, vc[i])
-                ctx = ctx.transpose(0, 2, 1, 3).reshape(B, T, D)
-                proj = _fc(ctx, p[f"{name}_proj_weight"],
-                           p[f"{name}_proj_bias"])
-                h = h + proj
-                h2 = _ln(h, p[f"{name}_ln2_gamma"], p[f"{name}_ln2_beta"])
-                f = _fc(h2, p[f"{name}_ffn_in_weight"],
-                        p[f"{name}_ffn_in_bias"])
-                f = jax.nn.gelu(f)
-                f = _fc(f, p[f"{name}_ffn_out_weight"],
-                        p[f"{name}_ffn_out_bias"])
-                h = h + f
-        h = _ln(h, p["final_ln_gamma"], p["final_ln_beta"])
-        logits = _fc(h, p["lm_head_weight"], p["lm_head_bias"])
-        return (kc, vc), logits                              # (B, T, V)
+    # shared-pos API's host counter.  serving/paged_kv.py runs the same
+    # forward over its views of a shared page pool — bitwise equal to
+    # this path on aligned prompts, test-pinned.
 
     def init_slot_state(self, num_slots):
         """Empty slot-pool cache ``(k_cache, v_cache)`` for ``num_slots``
@@ -458,7 +478,10 @@ class KVDecoder:
         if T not in self._padded_prefill_cache:
             self._padded_prefill_cache[T] = _WeightProgram(
                 self,
-                _count_compiles(self._forward_padded, "decode_prefill"),
+                _count_compiles(
+                    lambda p, kc, vc, tokens, start: self._over(
+                        _PaddedView(kc, vc, start), p, tokens),
+                    "decode_prefill"),
                 f"decode_prefill_padded_t{T}")
         kc, vc, _ = self.init_state(B)
         start = (T - lengths).astype(np.int32)
@@ -593,8 +616,8 @@ class KVDecoder:
             def step_once(p, kc, vc, pos, tok, k_):
                 """ONE decode position + next-token pick — shared by the
                 scan and while_loop bodies so they cannot diverge."""
-                (kc, vc), lg = self._forward_positions(
-                    p, kc, vc, pos, tok[:, None], n=1)
+                (kc, vc), lg = self._positions(
+                    p, kc, vc, pos, tok[:, None])
                 k_, sub = jax.random.split(k_)
                 return kc, vc, pick(lg[:, 0], sub), k_
 

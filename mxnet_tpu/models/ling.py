@@ -326,6 +326,10 @@ class LingDecoder:
         return {"pages": {"latent": (n_mla, c.kv_rank + c.rope,
                                      self._cache_dtype)},
                 "state": state,
+                # what moe_block counts (parallel/moe.py:moe_serve)
+                "counters": ("expert_assignments_held",
+                             "expert_assignments_absent",
+                             "expert_distinct_hits"),
                 # a hit would also need the state at that block boundary
                 "prefix_reuse": not state}
 

@@ -5,7 +5,7 @@ every tick — ``pool[bt]`` + transpose + reshape rebuilds the contiguous
 ``(L, B, H, S, dh)`` layout before a single score is computed, paying
 for every allocated page whether or not the slot's cursor ever reached
 it.  This module computes the same decode attention straight off the
-page pool, three lowerings behind one schedule-driven entry:
+page pool, two lowerings behind one schedule-driven entry:
 
 - **pallas** — the TPU kernel: one program a slot (grid ``(B,)``), the
   block table and the cursors ride as scalar prefetch.  The pool is
@@ -23,18 +23,10 @@ page pool, three lowerings behind one schedule-driven entry:
   the same kernel on CPU: the parity-test hook.  The sums over
   positions are taken chunk by chunk, so against gather it is allclose
   in f32 (a few ulp), not bitwise.
-- **pagewalk** — a lax lowering of the same idea for hosts without a
-  TPU: a ``fori_loop`` whose trip count is the *live* page count
-  (``max(cursor)``-bounded, a traced scalar — no host sync, no
-  recompile), gathering ``chunk`` pages per iteration.  Same attention
-  math, but loop-carried accumulation reassociates the reductions, so
-  it is allclose-not-bitwise vs gather — which is why it is installed
-  by the autotuner or an explicit ``MXTPU_PAGED_KERNEL=pagewalk``,
-  never silently.
-- **gather** — the PR-15 reference math on the materialized table, kept
-  as the structural fallback behind :func:`supports` (same pattern as
-  ``ops/residual_epilogue.py``) and as the search baseline every
-  candidate must beat.
+- **gather** — the PR-15 reference math on one layer's materialized
+  table, kept as the structural fallback behind :func:`supports` (same
+  pattern as ``ops/residual_epilogue.py``), as the reference the tests
+  hold the kernel to, and as the search baseline it must beat.
 
 Schedules are plain dicts (``{"impl": ..., ...knobs}``) chosen by
 ``mxnet_tpu.autotune`` at ``PagedSlots`` construction — never per
@@ -52,14 +44,13 @@ from ..base import mxu_precision
 
 __all__ = [
     "supports", "keysig", "default_schedule", "candidate_schedules",
-    "chunk_pages", "paged_attention", "gather_tables", "make_bench_fn",
+    "chunk_pages", "paged_attention", "gather_tables", "dense_attention",
+    "make_bench_fn",
 ]
 
 # the masking constant of the decode stack (== models.decode.NEG_INF;
 # kept literal so this op module never imports the models package)
 NEG_INF = -1e30
-
-_PAGEWALK_CHUNKS = (1, 2, 4, 8)
 
 
 def supports(block: int, dh: int, dtype) -> bool:
@@ -93,93 +84,53 @@ def default_schedule(platform: str, block: int, dh: int, dtype) -> dict:
     return {"impl": "gather"}
 
 
-def candidate_schedules(platform: str, block: int, dh: int, M: int,
-                        dtype) -> list:
+def candidate_schedules(platform: str, block: int, dh: int, dtype) -> list:
     """The search space for one shape signature.  Gather is always a
-    candidate (the winner can never lose to not tuning); pagewalk chunk
-    sizes must divide the block-table width; the pallas kernel (it has
-    no knob: its chunk follows from the shapes) only where the compiled
-    kernel can run."""
+    candidate (the winner can never lose to not tuning); the pallas
+    kernel (it has no knob: its chunk follows from the shapes) only
+    where the compiled kernel can run."""
     cands = [{"impl": "gather"}]
-    for ch in _PAGEWALK_CHUNKS:
-        if ch <= M and M % ch == 0:
-            cands.append({"impl": "pagewalk", "chunk": ch})
     if platform == "tpu" and supports(block, dh, dtype):
         cands.append({"impl": "pallas"})
     return cands
 
 
 # ---------------------------------------------------------------- gather
-def gather_tables(pool, bt, block: int):
-    """``(P, L, H, blk, dh)[bt (B, M)] -> (L, B, H, M*blk, dh)`` — the
-    PR-15 materialization, shared here so the op-level baseline and the
-    serving gather path stay the same expression."""
+def gather_tables(pool, bt):
+    """``(P, L, H, blk, dh)[bt (B, M)] -> (L, B, H, M*blk, dh)``: every
+    layer's table in the contiguous layout (a paged prefill attends
+    over it)."""
     B, M = bt.shape
     _P, L, H, blk, dh = pool.shape
     t = pool[bt]                                 # (B, M, L, H, blk, dh)
     t = t.transpose(2, 0, 3, 1, 4, 5)            # (L, B, H, M, blk, dh)
-    return t.reshape(L, B, H, M * block, dh)
+    return t.reshape(L, B, H, M * blk, dh)
 
 
-def _attend(q, kc, vc, cursor):
-    """The reference decode attention over a contiguous table slice —
-    exactly the PR-15 step math (bitwise anchor for every lowering)."""
-    S = kc.shape[2]
-    dh = q.shape[-1]
-    valid = jnp.arange(S)[None, :] <= cursor[:, None]
-    scores = jnp.einsum("bhnd,bhsd->bhns", q, kc) \
-        / jnp.sqrt(jnp.asarray(dh, q.dtype))
-    scores = jnp.where(valid[:, None, None, :], scores, NEG_INF)
+def dense_attention(q, k, v, mask):
+    """Masked softmax attention over a contiguous table: ``q`` ``(B, H,
+    n, dh)``, ``k``/``v`` ``(B, H, S, dh)``, ``mask`` broadcast against
+    ``(B, H, n, S)``.  The decode stack's math (``models/decode.py``'s
+    views hold the same lines): the bitwise anchor for every lowering;
+    a masked-out entry weighs exactly zero."""
+    scores = jnp.einsum("bhnd,bhsd->bhns", q, k) \
+        / jnp.sqrt(jnp.asarray(q.shape[-1], q.dtype))
+    scores = jnp.where(mask, scores, NEG_INF)
     att = jax.nn.softmax(scores, axis=-1)
-    return jnp.einsum("bhns,bhsd->bhnd", att, vc)
+    return jnp.einsum("bhns,bhsd->bhnd", att, v)
 
 
-def _gather_attention(q, pool_k, pool_v, bt, cursor, layer, block):
-    kc = gather_tables(pool_k, bt, block)[layer]
-    vc = gather_tables(pool_v, bt, block)[layer]
-    return _attend(q, kc, vc, cursor)
+def _gather_attention(q, pool_k, pool_v, bt, cursor, layer):
+    B, M = bt.shape
+    H, blk, dh = pool_k.shape[2:]
 
+    def table(pool):                             # one layer's, (B, H, S, dh)
+        return pool[bt, layer].transpose(0, 2, 1, 3, 4) \
+            .reshape(B, H, M * blk, dh)
 
-# -------------------------------------------------------------- pagewalk
-def _pagewalk_attention(q, pool_k, pool_v, bt, cursor, layer, block,
-                        chunk):
-    B, H, _n, dh = q.shape
-    M = bt.shape[1]
-    ch = int(chunk)
-    if ch < 1 or M % ch:
-        ch = 1                                   # always-valid fallback
-    S = M * block
-    qs = q[:, :, 0, :]                           # (B, H, dh)
-    # live trip count: pages any slot's cursor has reached — a traced
-    # scalar, so raggedness never retraces and never syncs the host
-    n_live = (jnp.max(cursor) + block) // block
-    n_it = (n_live + ch - 1) // ch
-    scale = jnp.sqrt(jnp.asarray(dh, q.dtype))
-    valid = (jnp.arange(S)[None, :] <= cursor[:, None])[:, None, :]
-
-    def scores_body(it, buf):
-        pgs = jax.lax.dynamic_slice(bt, (0, it * ch), (B, ch))
-        k = pool_k[pgs, layer]                   # (B, ch, H, blk, dh)
-        s = jnp.einsum("bhd,bchkd->bhck", qs, k) \
-            .reshape(B, H, ch * block) / scale
-        return jax.lax.dynamic_update_slice(buf, s, (0, 0, it * ch * block))
-
-    scores = jax.lax.fori_loop(
-        0, n_it, scores_body, jnp.full((B, H, S), NEG_INF, q.dtype))
-    scores = jnp.where(valid, scores, NEG_INF)
-    att = jax.nn.softmax(scores, axis=-1)        # dead pages: exact 0
-
-    def ctx_body(it, acc):
-        pgs = jax.lax.dynamic_slice(bt, (0, it * ch), (B, ch))
-        v = pool_v[pgs, layer]
-        a = jax.lax.dynamic_slice(
-            att, (0, 0, it * ch * block),
-            (B, H, ch * block)).reshape(B, H, ch, block)
-        return acc + jnp.einsum("bhck,bchkd->bhd", a, v)
-
-    ctx = jax.lax.fori_loop(
-        0, n_it, ctx_body, jnp.zeros((B, H, dh), q.dtype))
-    return ctx[:, :, None, :]
+    valid = jnp.arange(M * blk)[None, :] <= cursor[:, None]
+    return dense_attention(q, table(pool_k), table(pool_v),
+                           valid[:, None, None, :])
 
 
 # ---------------------------------------------------------------- pallas
@@ -360,10 +311,7 @@ def paged_attention(q, pool_k, pool_v, bt, cursor, layer, *, block,
         interp = bool(interpret or sched.get("interpret", False))
         return _pallas_attention(
             q, pool_k, pool_v, bt, cursor, layer, block, interp)
-    if impl == "pagewalk":
-        return _pagewalk_attention(q, pool_k, pool_v, bt, cursor, layer,
-                                   block, sched.get("chunk", 1))
-    return _gather_attention(q, pool_k, pool_v, bt, cursor, layer, block)
+    return _gather_attention(q, pool_k, pool_v, bt, cursor, layer)
 
 
 # ------------------------------------------------------------- benchmark
@@ -371,10 +319,9 @@ def make_bench_fn(schedule, *, B, H, M, block, dh, L, dtype=jnp.float32):
     """A thunk timing one decode step's attention (all ``L`` layers)
     under ``schedule``, on a synthetic steady-state pool: per-slot
     cursors spread raggedly across the context (mean ~half full — the
-    regime a serving mix actually sits in), block tables dense.  The
-    gather baseline amortizes ONE materialization over all layers,
-    exactly like the serving step, so the comparison is never rigged
-    against it.  Used by the ``PagedSlots`` tuning call site and
+    regime a serving mix actually sits in), block tables dense.  Every
+    lowering is called a layer at a time, exactly like the serving
+    step.  Used by the ``PagedSlots`` tuning call site and
     ``bench.py::_autotune_micro``."""
     S = M * block
     P = B * M + 1
@@ -390,21 +337,14 @@ def make_bench_fn(schedule, *, B, H, M, block, dh, L, dtype=jnp.float32):
         .astype(np.int32))
     cursor = jnp.asarray(np.linspace(block, S - 1, B).astype(np.int32))
 
-    sched = schedule or {"impl": "gather"}
     # the arrays are jit ARGUMENTS, not closure captures: captured
     # device values become compile-time constants and XLA folds part of
     # the work into the executable, timing a fiction
-    if sched.get("impl", "gather") == "gather":
-        def step(q, pool_k, pool_v, bt, cursor):
-            kc = gather_tables(pool_k, bt, block)
-            vc = gather_tables(pool_v, bt, block)
-            return sum(_attend(q, kc[i], vc[i], cursor)
-                       for i in range(L))
-    else:
-        def step(q, pool_k, pool_v, bt, cursor):
-            return sum(
-                paged_attention(q, pool_k, pool_v, bt, cursor, i,
-                                block=block, schedule=sched)
-                for i in range(L))
+    def step(q, pool_k, pool_v, bt, cursor):
+        return sum(
+            paged_attention(q, pool_k, pool_v, bt, cursor, i,
+                            block=block, schedule=schedule)
+            for i in range(L))
+
     jitted = jax.jit(step)
     return lambda: jitted(q, pool_k, pool_v, bt, cursor)

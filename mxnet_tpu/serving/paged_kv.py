@@ -30,47 +30,59 @@ admission, zero traces on a warm server
 (``executor_compile_total{kind=decode_step_paged|decode_prefill_paged}``).
 
 What a page holds, and what lives beside the pages, is the decoder's
-to say.  Two kinds of decoder are served:
+to say (``decoder.paged_layout()``); what its block computes is the
+decoder's too.  This module holds no model mathematics: its two
+programs (:class:`_CachePrograms`) call the decoder's one ``forward(p,
+tokens, view)`` over a *cache view* (:class:`_StepView`,
+:class:`_PrefillView`), and the view is where K/V rows, page rows and
+per-slot state live and how a query reaches them.  A layout may
+declare:
 
-- ``models/decode.py:KVDecoder`` (GPT-2 block) declares nothing and
-  gets the K/V pool of :class:`_PagedPrograms`: two ``(P, L, H, block,
-  dh)`` arrays, whose programs hold that block's mathematics
-  (``_block_qkv`` + ``_ln``/``_fc`` of ``models/decode.py``).  The
-  gathered table reconstructs exactly the contiguous layout (absolute
-  positions, ``start=0``) and masked-out entries contribute exact
+- ``kv_pages``: ``(layers, heads, head_dim, dtype)`` — the pair of
+  ``(P, layers, heads, block, head_dim)`` K/V pools
+  (``models/decode.py:KVDecoder``, the GPT-2 block).  ``view.attend``
+  writes a layer's new rows and attends over the slot's table: in the
+  step by the schedule's lowering (``ops/paged_attention.py``: the
+  Pallas kernel, or gather), in a prefill over the gathered table,
+  which reconstructs exactly the contiguous layout (absolute
+  positions, ``start=0``) with masked-out entries contributing exact
   zeros — paged and contiguous decode are BITWISE equal on aligned
-  prompts (tests pin it).
-- a decoder with ``paged_layout()`` (``models/ling.py:LingDecoder``)
-  declares its page rows by layer kind (``pages``: name -> layers, row
-  width, dtype; one ``(layers, P, block * width)`` array each, a page
-  one row, so that gathering a table is a lookup of whole rows) and a
-  FIXED-SIZE STATE PER SLOT beside them (``state``: name -> shape,
-  dtype; one ``(num_slots, *shape)`` array each — a linear-attention
-  layer's recurrent state).  :class:`_DeclaredPrograms` holds no model
-  mathematics: its two programs call the decoder's one ``forward`` over
-  a *cache view* (:class:`_StepView`, :class:`_PrefillView`).  Still
-  one block table a slot; a prefill starts from a zero state and
+  prompts (tests pin it);
+- ``pages``: name -> ``(layers, row width, dtype)`` — page rows of the
+  decoder's own, one ``(layers, P, block * width)`` array each, a page
+  one row, so that gathering a table is a lookup of whole rows
+  (``models/ling.py``: one latent row a token and MLA layer;
+  ``view.append``);
+- ``state``: name -> ``(shape, dtype)`` — a FIXED-SIZE STATE PER SLOT
+  beside the pages, one ``(num_slots, *shape)`` array each (a
+  linear-attention layer's recurrent state; ``view.state`` /
+  ``view.set_state``).  A prefill starts from a zero state and
   overwrites the slot's row, so a reused slot never sees its
-  predecessor; pages and state are donated through every program like
-  the K/V pool.  A cached prefix would also need the state at that
-  block boundary, which nothing snapshots: such a decoder says
-  ``prefix_reuse: False`` and no page of it is ever shared
-  (``stats()["prefix_reuse"]``).
+  predecessor;
+- ``counters``: names of int32 device counters the forward adds to
+  (``view.count``), reported by ``stats()`` under those names;
+- ``prefix_reuse``: whether a cached prefix can be picked up at a block
+  boundary.  It would also need the state there, which nothing
+  snapshots: a decoder with state says ``False`` and no page of it is
+  ever shared (``stats()["prefix_reuse"]``).
 
-The K/V pool's layout is the kernel's.  Each side of the pool is one
-``(P, L, H, block, dh)`` array that the Mosaic kernel reads row-major
-(``{4,3,2,1,0}``).  A program that writes it in a way the TPU compiler
-would rather lay out otherwise — a scatter of rows gets
-``{4,2,3,1,0}`` — makes XLA copy the WHOLE pool into that layout and
-back before every layer's kernel (2 + 2 L copies of 1.6 GB a tick at
-the 1.3B width, 70% of the device's time before ISSUE 26).  So there
-are two ways into the pool, ``_PagedPrograms._write_rows`` (one
-``dynamic_update_slice`` a slot) and ``_write_pages`` (whole pages),
-both in place in that layout, and the programs take the pool's buffers
-over (``donate``): ``PagedSlots`` holds the only reference and replaces
-it with each call's outputs.  Any new program that touches the pool
-goes into ``tests/test_tpu_compile.py``'s compiled-program test, which
-fails on a pool-shaped copy; nothing on the CPU shows one.
+Still one block table a slot, whatever is declared, and the whole cache
+is donated through every program.
+
+The K/V pools' layout is the kernel's.  Each is one ``(P, L, H, block,
+dh)`` array that the Mosaic kernel reads row-major (``{4,3,2,1,0}``).
+A program that writes it in a way the TPU compiler would rather lay out
+otherwise — a scatter of rows gets ``{4,2,3,1,0}`` — makes XLA copy the
+WHOLE pool into that layout and back before every layer's kernel (2 +
+2 L copies of 1.6 GB a tick at the 1.3B width, 70% of the device's time
+before ISSUE 26).  So there are two ways into the pools,
+``_StepView._write_rows`` (one ``dynamic_update_slice`` a slot) and
+``_PrefillView._write_pages`` (whole pages), both in place in that
+layout, and the programs take the cache's buffers over (``donate``):
+``PagedSlots`` holds the only reference and replaces it with each
+call's outputs.  Any new program that touches the pools goes into
+``tests/test_tpu_compile.py``'s compiled-program test, which fails on a
+pool-shaped copy; nothing on the CPU shows one.
 """
 from __future__ import annotations
 
@@ -78,7 +90,6 @@ import hashlib
 import os
 import time
 from collections import OrderedDict
-from functools import partial
 
 import numpy as np
 
@@ -108,12 +119,12 @@ _TM_LATENT_PAGES = _tm.gauge(
     "serve_latent_pages",
     "pages in use of a decoder that declares its own page rows (one "
     "latent row a token and MLA layer)")
-_TM_EXPERT_PAIRS = _tm.gauge(
-    "serve_expert_assignments",
-    "token-expert assignments of the served MoE layers since start, as "
-    "of the last stats() read: on experts held here, on experts held "
-    "elsewhere, and distinct held experts hit summed over layers and "
-    "programs", labels=("where",))
+_TM_COUNTED = _tm.gauge(
+    "serve_decoder_counted",
+    "what the served decoder's programs have counted on the device "
+    "since start, under the names the decoder declares "
+    "(paged_layout()[\"counters\"]), as of the last stats() read",
+    labels=("name",))
 
 
 class PoolExhausted(MXNetError):
@@ -144,9 +155,9 @@ def paged_kernel_mode() -> str:
     schedule cache, the tuned winner; without one, the Pallas kernel on
     a TPU whose shape qualifies and the PR-15 gather path everywhere
     else.  ``0``/``off``/``gather``: pin the gather path (bit-identical
-    to PR 15).  ``pallas`` / ``interpret`` / ``pagewalk``: force one
-    lowering of ``ops/paged_attention.py`` (``interpret`` is the
-    CPU-parity hook; ``pagewalk`` the lax live-page walk)."""
+    to PR 15).  ``pallas`` / ``interpret``: force the kernel of
+    ``ops/paged_attention.py`` (``interpret`` is the CPU-parity
+    hook)."""
     raw = os.environ.get("MXTPU_PAGED_KERNEL", "auto").strip().lower()
     if raw in ("", "1", "auto"):
         return "auto"
@@ -155,288 +166,21 @@ def paged_kernel_mode() -> str:
     return raw
 
 
-# pool_k, pool_v among a paged program's arguments after the weights:
-# both are donated, each program's outputs are the pool from then on
-_POOL_ARGS = (0, 1)
-
-
-class _PagedPrograms:
-    """The jitted decode programs over the page pool.
-
-    Pool layout ``(P, L, H, block, dh)`` — page-major so one gather by
-    page id reconstructs a slot's table.  The layer math is the
-    decoder's own (``_block_qkv`` + shared ``_ln``/``_fc``), run over
-    the gathered table in the contiguous layout, so a paged step is
-    bitwise the contiguous step whenever the table contents match.
-    """
-
-    def __init__(self, decoder, block, max_blocks, num_pages,
-                 schedule=None):
-        import jax
-
-        from ..models.decode import _WeightProgram, _count_compiles
-
-        self.dec = decoder
-        self.block = int(block)
-        self.max_blocks = int(max_blocks)
-        self.num_pages = int(num_pages)
-        # step-attention schedule (ops/paged_attention.py, picked by
-        # mxnet_tpu.autotune at PagedSlots construction).  None/"gather"
-        # keeps the PR-15 materialized-table math verbatim; prefill
-        # always gathers (one admission-time cost, not the per-tick one)
-        self.schedule = schedule if (
-            schedule and schedule.get("impl") != "gather") else None
-        self._step_jit = _WeightProgram(
-            decoder, _count_compiles(self._forward_step,
-                                     "decode_step_paged"),
-            "decode_step_paged", donate=_POOL_ARGS)
-        self._prefill_cache = {}
-
-    def pool_structs(self):
-        """Shape and dtype of ``(pool_k, pool_v)``: what a program is
-        lowered with when nothing may take the pool's buffers."""
-        import jax
-
-        d = self.dec
-        s = jax.ShapeDtypeStruct(
-            (self.num_pages, d.L, d.H, self.block, d.dh), d._cache_dtype)
-        return s, s
-
-    def init_pool(self):
-        import jax.numpy as jnp
-
-        return tuple(jnp.zeros(s.shape, s.dtype)
-                     for s in self.pool_structs())
-
-    # -------------------------------------------------------------- writes
-    # The two ways into the pool.  Both leave it in the row-major layout
-    # the Mosaic kernel reads (module docstring, "The pool's layout");
-    # tests/test_tpu_compile.py compiles every program that uses them.
-    def _write_rows(self, pool, new, layer, at):
-        """One row a slot: ``new[b]`` ``(H, 1, dh)`` lands at
-        ``pool[page, layer, :, off]`` for ``(page, off) = at[b]``, as
-        one ``dynamic_update_slice`` a slot (a ``fori_loop`` over the
-        slots is laid out like the scatter again).  A step's trace
-        holds ``2 L B`` of these writes, so ``at`` is a list of scalar
-        pairs made once a program, and the indices, never negative,
-        skip the wrap-around ``lax`` would stage for each."""
-        import jax
-
-        new = new[:, None]                           # (B, 1, H, 1, dh)
-        for b, (page, off) in enumerate(at):
-            pool = jax.lax.dynamic_update_slice(
-                pool, jax.lax.slice_in_dim(new, b, b + 1),
-                (page, layer, 0, off, 0), allow_negative_indices=False)
-        return pool
-
-    def _write_pages(self, pool, new, page_ids, layer):
-        """Whole pages: ``new`` ``(T, H, dh)`` holds consecutive
-        positions from a page boundary on, page ``n`` of it lands at
-        ``pool[page_ids[n], layer]``; an id out of bounds drops its
-        page."""
-        import jax.numpy as jnp
-
-        T, H, dh = new.shape
-        new = jnp.pad(new, ((0, -T % self.block), (0, 0), (0, 0)))
-        vals = new.reshape(-1, self.block, H, dh).transpose(0, 2, 1, 3)
-        return pool.at[page_ids, layer].set(vals, mode="drop")
-
-    # ------------------------------------------------------------ gathers
-    def _gather(self, pool, bt):
-        """(P, L, H, blk, dh)[bt (B, M)] -> contiguous (L, B, H, S, dh)."""
-        d = self.dec
-        t = pool[bt]                                 # (B, M, L, H, blk, dh)
-        t = t.transpose(2, 0, 3, 1, 4, 5)            # (L, B, H, M, blk, dh)
-        return t.reshape(d.L, bt.shape[0], d.H,
-                         self.max_blocks * self.block, d.dh)
-
-    # ---------------------------------------------------------------- step
-    def _forward_step(self, p, pool_k, pool_v, bt, tokens, cursor):
-        """One decode position for every slot: row ``b`` writes its new
-        K/V at absolute cache position ``cursor[b]`` (page
-        ``bt[b, cursor//block]``, offset ``cursor%block``) and attends
-        over ``[0, cursor[b]]``.  Free rows ride along with
-        ``bt[b]=0``/``cursor=0`` — their writes land in the scratch
-        page the allocator never hands out."""
-        import jax
-        import jax.numpy as jnp
-
-        from ..models.decode import NEG_INF, _fc, _ln
-
-        d = self.dec
-        B = tokens.shape[0]
-        H, dh, D = d.H, d.dh, d.d_model
-        S = self.max_blocks * self.block
-
-        tok = jnp.take(p["tok_embed_weight"], tokens.astype(jnp.int32),
-                       axis=0)                               # (B, D)
-        pos_ids = jnp.clip(cursor, 0, d.max_len - 1)
-        posv = jnp.take(p["pos_embed"][0], pos_ids, axis=0)  # (B, D)
-        h = (tok + posv)[:, None]                            # (B, 1, D)
-        s_idx = jnp.arange(S)
-        valid = s_idx[None, :] <= cursor[:, None]            # (B, S)
-        rows = jnp.arange(B)
-        pages = jnp.take_along_axis(
-            bt, (cursor // self.block)[:, None], axis=1)[:, 0]   # (B,)
-        offs = cursor % self.block
-        at = [(pages[b], offs[b]) for b in range(B)]
-        sched = self.schedule
-        if sched is None:
-            kc = self._gather(pool_k, bt)
-            vc = self._gather(pool_v, bt)
-        else:
-            from ..ops import paged_attention as _pa
-        for i in range(d.L):
-            name = f"layer{i}"
-            with jax.named_scope(name):
-                h2 = _ln(h, p[f"{name}_ln1_gamma"], p[f"{name}_ln1_beta"])
-                q, k, v = d._block_qkv(p, i, h2)
-                sh = lambda a: a.reshape(B, 1, H, dh).transpose(0, 2, 1, 3)
-                qh, kh, vh = sh(q), sh(k), sh(v)             # (B, H, 1, dh)
-                if sched is None:
-                    kc = kc.at[i, rows, :, cursor].set(kh[:, :, 0])
-                    vc = vc.at[i, rows, :, cursor].set(vh[:, :, 0])
-                pool_k = self._write_rows(pool_k, kh, i, at)
-                pool_v = self._write_rows(pool_v, vh, i, at)
-                if sched is None:
-                    scores = jnp.einsum("bhnd,bhsd->bhns", qh, kc[i]) \
-                        / jnp.sqrt(jnp.asarray(dh, h.dtype))
-                    scores = jnp.where(
-                        valid[:, None, None, :], scores, NEG_INF)
-                    att = jax.nn.softmax(scores, axis=-1)
-                    ctx = jnp.einsum("bhns,bhsd->bhnd", att, vc[i])
-                else:
-                    # the kernel walks the block table over the pool the
-                    # writes above just updated — same values the gathered
-                    # table would hold, no materialization
-                    ctx = _pa.paged_attention(
-                        qh, pool_k, pool_v, bt, cursor, i,
-                        block=self.block, schedule=sched)
-                ctx = ctx.transpose(0, 2, 1, 3).reshape(B, 1, D)
-                proj = _fc(ctx, p[f"{name}_proj_weight"],
-                           p[f"{name}_proj_bias"])
-                h = h + proj
-                h2 = _ln(h, p[f"{name}_ln2_gamma"], p[f"{name}_ln2_beta"])
-                f = _fc(h2, p[f"{name}_ffn_in_weight"],
-                        p[f"{name}_ffn_in_bias"])
-                f = jax.nn.gelu(f)
-                f = _fc(f, p[f"{name}_ffn_out_weight"],
-                        p[f"{name}_ffn_out_bias"])
-                h = h + f
-        h = _ln(h, p["final_ln_gamma"], p["final_ln_beta"])
-        logits = _fc(h, p["lm_head_weight"], p["lm_head_bias"])
-        return (pool_k, pool_v), logits[:, 0]                # (B, V)
-
-    # ------------------------------------------------------------- prefill
-    def _forward_prefill(self, p, pool_k, pool_v, bt_row, tokens, hist,
-                         t):
-        """Tail prefill behind a (possibly reused) history: ``tokens``
-        (1, T) RIGHT-padded, the ``t`` real tokens sit at absolute
-        positions ``hist .. hist+t-1``.  ``hist`` is a whole number of
-        pages (only full blocks are shared), so the tail starts on a
-        page boundary and its K/V go into the pool a PAGE at a time:
-        every page that holds a real token is written whole, the pages
-        of pad tokens alone aim out of bounds and are dropped.  The
-        rows of the last, partial page beyond the prompt therefore hold
-        the pad tokens' K/V: finite values at positions ``> cursor``,
-        which both step lowerings mask to exact zero weight and which
-        the decode write at each position replaces before the mask
-        reaches it; only full blocks are promoted to the prefix index,
-        so no shared page ever holds one.  The gathered table (for
-        intra-prefill attention) takes the real tokens' rows only.
-        ``hist``/``t`` ride as traced scalars, so the program count is
-        one per padded bucket length."""
-        import jax
-        import jax.numpy as jnp
-
-        from ..models.decode import NEG_INF, _fc, _ln
-
-        d = self.dec
-        T = tokens.shape[1]
-        H, dh, D = d.H, d.dh, d.d_model
-        S = self.max_blocks * self.block
-
-        j = jnp.arange(T)
-        real = j < t                                         # (T,)
-        qpos = hist + j                                      # absolute
-        tok = jnp.take(p["tok_embed_weight"], tokens.astype(jnp.int32),
-                       axis=0)                               # (1, T, D)
-        posv = jnp.take(p["pos_embed"][0],
-                        jnp.clip(qpos, 0, d.max_len - 1), axis=0)[None]
-        h = tok + posv
-        # write targets: pad tokens go out of bounds -> dropped writes
-        wpos = jnp.where(real, qpos, S)                      # table scatter
-        n = jnp.arange(-(-T // self.block))                  # tail pages
-        page_ids = jnp.where(
-            n * self.block < t,
-            bt_row[jnp.clip(hist // self.block + n, 0,
-                            self.max_blocks - 1)],
-            self.num_pages)                                  # pool scatter
-        s_idx = jnp.arange(S)
-        valid = s_idx[None, :] <= qpos[:, None]              # (T, S)
-        kc = self._gather(pool_k, bt_row[None])              # (L, 1, H, S, dh)
-        vc = self._gather(pool_v, bt_row[None])
-        for i in range(d.L):
-            name = f"layer{i}"
-            with jax.named_scope(name):
-                h2 = _ln(h, p[f"{name}_ln1_gamma"], p[f"{name}_ln1_beta"])
-                q, k, v = d._block_qkv(p, i, h2)
-                sh = lambda a: a.reshape(1, T, H, dh).transpose(0, 2, 1, 3)
-                qh, kh, vh = sh(q), sh(k), sh(v)             # (1, H, T, dh)
-                k_t = kh[0].transpose(1, 0, 2)                   # (T, H, dh)
-                v_t = vh[0].transpose(1, 0, 2)
-                kc = kc.at[i, 0, :, wpos].set(k_t)
-                vc = vc.at[i, 0, :, wpos].set(v_t)
-                pool_k = self._write_pages(pool_k, k_t, page_ids, i)
-                pool_v = self._write_pages(pool_v, v_t, page_ids, i)
-                scores = jnp.einsum("bhnd,bhsd->bhns", qh, kc[i]) \
-                    / jnp.sqrt(jnp.asarray(dh, h.dtype))
-                scores = jnp.where(valid[None, None], scores, NEG_INF)
-                att = jax.nn.softmax(scores, axis=-1)
-                ctx = jnp.einsum("bhns,bhsd->bhnd", att, vc[i])
-                ctx = ctx.transpose(0, 2, 1, 3).reshape(1, T, D)
-                proj = _fc(ctx, p[f"{name}_proj_weight"],
-                           p[f"{name}_proj_bias"])
-                h = h + proj
-                h2 = _ln(h, p[f"{name}_ln2_gamma"], p[f"{name}_ln2_beta"])
-                f = _fc(h2, p[f"{name}_ffn_in_weight"],
-                        p[f"{name}_ffn_in_bias"])
-                f = jax.nn.gelu(f)
-                f = _fc(f, p[f"{name}_ffn_out_weight"],
-                        p[f"{name}_ffn_out_bias"])
-                h = h + f
-        h = _ln(h, p["final_ln_gamma"], p["final_ln_beta"])
-        logits = _fc(h, p["lm_head_weight"], p["lm_head_bias"])
-        return (pool_k, pool_v), logits                      # (1, T, V)
-
-    def prefill(self, bucket):
-        if bucket not in self._prefill_cache:
-            import jax
-
-            from ..models.decode import _WeightProgram, _count_compiles
-
-            self._prefill_cache[bucket] = _WeightProgram(
-                self.dec, _count_compiles(self._forward_prefill,
-                                          "decode_prefill_paged"),
-                f"prefill_paged_b{bucket}", donate=_POOL_ARGS)
-        return self._prefill_cache[bucket]
-
-
-# the cache pytree among a declared program's arguments after the weights
+# the cache pytree among a program's arguments after the weights: it is
+# donated, each program's output is the cache from then on
 _CACHE_ARG = (0,)
-# the counters a declared program adds to (never donated: stats() reads
-# them from another thread): assignments on held experts, on absent
-# ones, distinct held experts hit
-_N_COUNTERS = 3
 
 
 class _CacheView:
-    """What ``decoder.forward`` sees of the cache: the per-slot state,
-    the pages, the positions of its tokens and which of them are real.
-    ``step`` tells the decode step's view from a prefill's."""
+    """What ``decoder.forward`` sees of the cache: the K/V pools, the
+    page rows and the per-slot state it declared, the positions of its
+    tokens and which of them are real.  ``step`` tells the decode step's
+    view from a prefill's, whose ``length`` counts its real tokens."""
+    length = None
 
     def __init__(self, programs, cache, positions, valid):
         self._pg = programs
+        self.kv = tuple(cache["kv"])
         self.pages = dict(cache["pages"])
         self._state = dict(cache["state"])
         self.positions, self.valid = positions, valid
@@ -446,18 +190,41 @@ class _CacheView:
         self.counts = self.counts + counts
 
     def cache(self):
-        return {"pages": self.pages, "state": self._state}
+        return {"kv": self.kv, "pages": self.pages, "state": self._state}
+
+    def embed(self, tok, table):
+        """The hidden state under a learned positional table ``(1,
+        max_len, D)``: the token rows ``tok`` (``(B, D)`` in the step,
+        ``(1, T, D)`` in a prefill) plus the table's at the view's
+        positions (a free row's and a pad token's clipped into it), as
+        ``(B, n, D)``: one position a slot, or one sequence."""
+        import jax.numpy as jnp
+
+        last = self._pg.max_blocks * self._pg.block - 1
+        rows = jnp.take(table[0], jnp.clip(self.positions, 0, last), axis=0)
+        return (tok + rows)[:, None] if self.step else tok + rows[None]
 
 
 class _StepView(_CacheView):
-    """One token a slot: state rows are the slots themselves; a page row
-    a slot is written at its cursor and every slot's table is gathered
-    back for attention."""
+    """One token a slot at its cursor (page ``bt[b, cursor // block]``,
+    offset ``cursor % block``): state rows are the slots themselves; a
+    K/V row or a page row a slot is written at its cursor and the slot
+    attends over ``[0, cursor]`` of its table.  Free rows ride along
+    with ``bt[b] = 0`` / ``cursor = 0``: their writes land in the
+    scratch page the allocator never hands out."""
     step = True
 
     def __init__(self, programs, cache, bt, cursor, occupied):
+        import jax.numpy as jnp
+
         super().__init__(programs, cache, cursor, occupied)
         self._bt, self._cursor = bt, cursor
+        # where each slot's row goes, as scalar pairs made once a
+        # program: a step's trace holds ``2 L B`` row writes
+        pages = jnp.take_along_axis(
+            bt, (cursor // programs.block)[:, None], axis=1)[:, 0]   # (B,)
+        offs = cursor % programs.block
+        self._at = [(pages[b], offs[b]) for b in range(bt.shape[0])]
 
     def state(self, name):
         return self._state[name]
@@ -465,24 +232,50 @@ class _StepView(_CacheView):
     def set_state(self, name, value):
         self._state[name] = value.astype(self._state[name].dtype)
 
+    def _write_rows(self, pool, new, layer):
+        """One K or V row a slot: ``new[b]`` ``(H, 1, dh)`` lands at
+        ``pool[page, layer, :, off]``, as one ``dynamic_update_slice`` a
+        slot (a scatter of rows, or a ``fori_loop`` over the slots, is
+        laid out otherwise by the TPU compiler: module docstring).  The
+        indices, never negative, skip the wrap-around ``lax`` would
+        stage for each."""
+        import jax
+
+        new = new[:, None]                           # (B, 1, H, 1, dh)
+        for b, (page, off) in enumerate(self._at):
+            pool = jax.lax.dynamic_update_slice(
+                pool, jax.lax.slice_in_dim(new, b, b + 1),
+                (page, layer, 0, off, 0), allow_negative_indices=False)
+        return pool
+
+    def attend(self, layer, q, k, v):
+        """``q, k, v`` ``(B, H, 1, dh)``: the new rows go into the K/V
+        pools, then the schedule's lowering (the Pallas kernel, or
+        gather) walks each slot's block table over the pools just
+        written.  Returns ``(B, H, 1, dh)``."""
+        from ..ops import paged_attention as _pa
+
+        pool_k, pool_v = self.kv = tuple(
+            self._write_rows(pool, new, layer)
+            for pool, new in zip(self.kv, (k, v)))
+        return _pa.paged_attention(
+            q, pool_k, pool_v, self._bt, self._cursor, layer,
+            block=self._pg.block, schedule=self._pg.schedule)
+
     def append(self, name, layer, rows):
         """``rows`` (B, W) land at each slot's cursor; returns the
         gathered ``(B, S, W)`` table and its ``(B, S)`` validity."""
         import jax
         import jax.numpy as jnp
 
-        pg, bt, cursor = self._pg, self._bt, self._cursor
+        pg, bt = self._pg, self._bt
         pool = self.pages[name]
-        pages = jnp.take_along_axis(
-            bt, (cursor // pg.block)[:, None], axis=1)[:, 0]
-        offs = cursor % pg.block
         W = rows.shape[-1]
         rows = rows.astype(pool.dtype)[None, :, None]        # (1, B, 1, W)
-        # one dynamic_update_slice a slot, as the K/V pool's row writes
-        # (a scatter of rows asks the TPU compiler for another layout)
-        for b in range(rows.shape[1]):
+        # one dynamic_update_slice a slot, as the K/V pools' row writes
+        for b, (page, off) in enumerate(self._at):
             pool = jax.lax.dynamic_update_slice(
-                pool, rows[:, b], (layer, pages[b], offs[b] * W),
+                pool, rows[:, b], (layer, page, off * W),
                 allow_negative_indices=False)
         self.pages[name] = pool
         # a page is one row of ``block * W`` numbers: gathering a slot's
@@ -490,21 +283,43 @@ class _StepView(_CacheView):
         S = pg.max_blocks * pg.block
         table = jnp.take(pool[layer], bt.reshape(-1), axis=0).reshape(
             bt.shape[0], S, W)
-        return table, jnp.arange(S)[None, :] <= cursor[:, None]
+        return table, jnp.arange(S)[None, :] <= self._cursor[:, None]
 
 
 class _PrefillView(_CacheView):
-    """One sequence from position 0 into slot ``slot``: the state starts
-    at zero and the state after token ``length - 1`` overwrites the
-    slot's row; page rows go in a page at a time."""
+    """One sequence into slot ``slot``, right-padded: its ``length``
+    real tokens stand at positions ``hist .. hist + length - 1`` behind
+    ``hist`` tokens of shared pages (0 for a decoder that reuses no
+    prefix).  ``hist`` is a whole number of pages (only full blocks are
+    shared), so the tail starts on a page boundary and goes into the
+    cache a PAGE at a time: every page that holds a real token is
+    written whole, the pages of pad tokens alone aim out of bounds and
+    are dropped.  The rows of the last, partial page beyond the prompt
+    therefore hold the pad tokens': finite values at positions ``>
+    cursor``, which the step masks to exact zero weight and which the
+    decode write at each position replaces before the mask reaches it;
+    only full blocks are promoted to the prefix index, so no shared
+    page ever holds one.  The state starts at zero and the state after
+    the last real token overwrites the slot's row.  ``slot``, ``hist``
+    and ``length`` ride as traced scalars, so the program count is one
+    per padded bucket length."""
     step = False
 
-    def __init__(self, programs, cache, bt_row, slot, tokens, length):
+    def __init__(self, programs, cache, bt_row, slot, tokens, hist, length):
         import jax.numpy as jnp
 
         j = jnp.arange(tokens.shape[0])
-        super().__init__(programs, cache, j, j < length)
+        super().__init__(programs, cache, hist + j, j < length)
         self._bt_row, self._slot, self.length = bt_row, slot, length
+        # the pool pages of the tail's pages; out of bounds (a dropped
+        # write) for a page without a real token
+        n = jnp.arange(-(-tokens.shape[0] // programs.block))
+        self._page_ids = jnp.where(
+            n * programs.block < length,
+            bt_row[jnp.clip(hist // programs.block + n, 0,
+                            programs.max_blocks - 1)],
+            programs.num_pages)
+        self._tables = None
 
     def state(self, name):
         import jax.numpy as jnp
@@ -521,61 +336,107 @@ class _PrefillView(_CacheView):
             (self._slot,) + (0,) * (s.ndim - 1),
             allow_negative_indices=False)
 
+    def _write_pages(self, pool, new, layer):
+        """Whole K or V pages: ``new`` ``(T, H, dh)`` holds the tail's
+        positions from its page boundary on, page ``n`` of it lands at
+        ``pool[page_ids[n], layer]``."""
+        import jax.numpy as jnp
+
+        block = self._pg.block
+        T, H, dh = new.shape
+        new = jnp.pad(new, ((0, -T % block), (0, 0), (0, 0)))
+        vals = new.reshape(-1, block, H, dh).transpose(0, 2, 1, 3)
+        return pool.at[self._page_ids, layer].set(vals, mode="drop")
+
+    def attend(self, layer, q, k, v):
+        """``q, k, v`` ``(1, H, T, dh)``: the real tokens' rows go into
+        the slot's gathered table (history and tail in the contiguous
+        layout, every layer's gathered once) for the prefill's own
+        attention, and the tail's pages into the K/V pools.  Returns
+        ``(1, H, T, dh)``."""
+        import jax.numpy as jnp
+
+        from ..ops import paged_attention as _pa
+
+        pg = self._pg
+        S = pg.max_blocks * pg.block
+        if self._tables is None:
+            self._tables = tuple(
+                _pa.gather_tables(pool, self._bt_row[None])
+                for pool in self.kv)                 # (L, 1, H, S, dh)
+            # pad tokens go out of bounds -> dropped writes
+            self._wpos = jnp.where(self.valid, self.positions, S)
+            self._seen = jnp.arange(S)[None, :] <= self.positions[:, None]
+        new = tuple(a[0].transpose(1, 0, 2) for a in (k, v))     # (T, H, dh)
+        kc, vc = self._tables = tuple(
+            table.at[layer, 0, :, self._wpos].set(rows)
+            for table, rows in zip(self._tables, new))
+        self.kv = tuple(self._write_pages(pool, rows, layer)
+                        for pool, rows in zip(self.kv, new))
+        return _pa.dense_attention(q, kc[layer], vc[layer],
+                                   self._seen[None, None])
+
     def append(self, name, layer, rows):
-        """``rows`` (T, W), right-padded: every page that holds a real
-        token is written whole (the rows beyond the prompt in the last
-        page are masked by the step until its own writes replace them),
-        pages of pad tokens alone aim out of bounds and are dropped."""
+        """``rows`` (T, W): the tail's page rows, written whole pages at
+        a time like the K/V pools'."""
         import jax.numpy as jnp
 
         pg = self._pg
         pool = self.pages[name]
         T, W = rows.shape
         rows = jnp.pad(rows.astype(pool.dtype), ((0, -T % pg.block), (0, 0)))
-        n = jnp.arange(rows.shape[0] // pg.block)
-        page_ids = jnp.where(
-            n * pg.block < self.length,
-            self._bt_row[jnp.clip(n, 0, pg.max_blocks - 1)], pg.num_pages)
-        self.pages[name] = pool.at[layer, page_ids].set(
+        self.pages[name] = pool.at[layer, self._page_ids].set(
             rows.reshape(-1, pg.block * W), mode="drop")
         return None
 
 
-class _DeclaredPrograms:
-    """The two jitted programs of a decoder that declares its cache
-    (``decoder.paged_layout()``): ``jit_decode_step_<family>`` and
-    ``jit_prefill_<family>_b<bucket>``.  Both call ``decoder.forward``
-    over a cache view and nothing else; the cache, one pytree
-    ``{"pages": {...}, "state": {...}}``, is donated to each call."""
+class _CachePrograms:
+    """The two jitted programs of a served decoder,
+    ``jit_decode_step_<family>`` and ``jit_prefill_<family>_b<bucket>``.
+    Both call ``decoder.forward`` over a cache view and nothing else;
+    the cache the decoder declared (``decoder.paged_layout()``), one
+    pytree ``{"kv": (pool_k, pool_v) or (), "pages": {...}, "state":
+    {...}}``, is donated to each call, and the counters it declared
+    (one int32 a name, never donated: ``stats()`` reads them from
+    another thread) go in and come out beside it."""
 
-    schedule = None
+    step_view, prefill_view = _StepView, _PrefillView
 
-    def __init__(self, decoder, block, max_blocks, num_pages, num_slots):
+    def __init__(self, decoder, layout, block, max_blocks, num_pages,
+                 num_slots, schedule=None):
         from ..models.decode import _WeightProgram, _count_compiles
 
-        self.dec = decoder
-        self.layout = decoder.paged_layout()
+        self.dec, self.layout = decoder, layout
         self.block, self.max_blocks = int(block), int(max_blocks)
         self.num_pages, self.num_slots = int(num_pages), int(num_slots)
+        # the K/V step's attention schedule (ops/paged_attention.py);
+        # None is gather
+        self.schedule = schedule
         self._WeightProgram, self._count = _WeightProgram, _count_compiles
         self._step_jit = _WeightProgram(
-            decoder, _count_compiles(self._forward_step,
+            decoder, _count_compiles(self._step_program,
                                      "decode_step_paged"),
             f"decode_step_{decoder.family}", donate=_CACHE_ARG)
         self._prefill_cache = {}
 
     def pool_structs(self):
         """Shapes of the cache pytree, as the 1-tuple ``PagedSlots``
-        keeps in ``pool``."""
+        keeps in ``pool``: what a program is lowered with when nothing
+        may take the cache's buffers."""
         import jax
 
+        kv = ()
+        if "kv_pages" in self.layout:
+            layers, heads, dh, dtype = self.layout["kv_pages"]
+            kv = (jax.ShapeDtypeStruct(
+                (self.num_pages, layers, heads, self.block, dh), dtype),) * 2
         pages = {n: jax.ShapeDtypeStruct(
             (layers, self.num_pages, self.block * width), dtype)
             for n, (layers, width, dtype) in self.layout["pages"].items()}
         state = {n: jax.ShapeDtypeStruct((self.num_slots,) + tuple(shape),
                                          dtype)
                  for n, (shape, dtype) in self.layout["state"].items()}
-        return ({"pages": pages, "state": state},)
+        return ({"kv": kv, "pages": pages, "state": state},)
 
     def init_pool(self):
         import jax
@@ -584,22 +445,23 @@ class _DeclaredPrograms:
         return jax.tree_util.tree_map(
             lambda s: jnp.zeros(s.shape, s.dtype), self.pool_structs())
 
-    def _forward_step(self, p, cache, counters, bt, tokens, cursor,
+    def _step_program(self, p, cache, counters, bt, tokens, cursor,
                       occupied):
-        view = _StepView(self, cache, bt, cursor, occupied)
+        view = self.step_view(self, cache, bt, cursor, occupied)
         logits = self.dec.forward(p, tokens, view)
         return (view.cache(),), (logits, counters + view.counts)
 
-    def _forward_prefill(self, p, cache, counters, bt_row, tokens, slot,
-                         t):
-        view = _PrefillView(self, cache, bt_row, slot, tokens[0], t)
+    def _prefill_program(self, p, cache, counters, bt_row, tokens, slot,
+                         hist, t):
+        view = self.prefill_view(self, cache, bt_row, slot, tokens[0],
+                                 hist, t)
         logits = self.dec.forward(p, tokens[0], view)
         return (view.cache(),), (logits, counters + view.counts)
 
     def prefill(self, bucket):
         if bucket not in self._prefill_cache:
             self._prefill_cache[bucket] = self._WeightProgram(
-                self.dec, self._count(self._forward_prefill,
+                self.dec, self._count(self._prefill_program,
                                       "decode_prefill_paged"),
                 f"prefill_{self.dec.family}_b{bucket}", donate=_CACHE_ARG)
         return self._prefill_cache[bucket]
@@ -650,29 +512,23 @@ class PagedSlots:
         self.prefix_on = (prefix_cache_on() if prefix_cache is None
                           else bool(prefix_cache))
         self.prefill_buckets = tuple(prefill_buckets or ())
-        # a decoder that declares its cache brings its own forward; the
-        # K/V pool, its kernel and its schedule are the GPT-2 block's
-        self.declared = hasattr(decoder, "paged_layout")
-        if self.declared:
-            self.kernel_mode, self.schedule = "none", None
-            self.programs = _DeclaredPrograms(
-                decoder, self.block, self.max_blocks, self.num_pages + 1,
-                self.num_slots)
-            # per-slot state cannot be picked up at a block boundary
-            self.prefix_on = self.prefix_on \
-                and self.programs.layout["prefix_reuse"]
-        else:
-            self.kernel_mode = (paged_kernel_mode() if kernel is None
-                                else str(kernel).strip().lower())
-            self.schedule = self._resolve_schedule()
-            self.programs = _PagedPrograms(
-                decoder, self.block, self.max_blocks, self.num_pages + 1,
-                schedule=self.schedule)
-        # device counters of a declared decoder's programs, and what
-        # stats() has read of them so far (host side, never wraps)
+        self.kernel_mode = (paged_kernel_mode() if kernel is None
+                            else str(kernel).strip().lower())
+        layout = decoder.paged_layout()
+        self.schedule = self._resolve_schedule(layout.get("kv_pages"))
+        self.programs = _CachePrograms(
+            decoder, layout, self.block, self.max_blocks,
+            self.num_pages + 1, self.num_slots, schedule=self.schedule)
+        # a decoder says whether a prefix of its cache can be picked up
+        # at a block boundary (per-slot state cannot)
+        self.prefix_on = self.prefix_on and layout["prefix_reuse"]
+        # the device counters the decoder's programs add to, by the
+        # names it declared, and what stats() has read of them so far
+        # (host side, never wraps)
+        self._counter_names = tuple(layout.get("counters", ()))
         self._counters = None
-        self._counted = np.zeros(_N_COUNTERS, np.int64)
-        self._counters_seen = np.zeros(_N_COUNTERS, np.int64)
+        self._counted = np.zeros(len(self._counter_names), np.int64)
+        self._counters_seen = np.zeros(len(self._counter_names), np.int64)
         self._reset_pool()
         # trace id of the admission currently allocating, so _alloc can
         # attribute its prefix evictions; None for step-time evictions
@@ -685,16 +541,15 @@ class PagedSlots:
 
     # ----------------------------------------------------------------- pool
     def _reset_pool(self):
-        """A zeroed pool (and, where the decoder declares one, a zeroed
-        state a slot) with every page free, no slot holding any and an
-        empty prefix index."""
-        self.pool = self.programs.init_pool()
-        if self.declared:
-            import jax.numpy as jnp
+        """A zeroed cache (pools, page rows, a state a slot: what the
+        decoder declared) with every page free, no slot holding any and
+        an empty prefix index."""
+        import jax.numpy as jnp
 
-            self._read_counters()       # keep what the lost pool counted
-            self._counters = jnp.zeros(_N_COUNTERS, jnp.int32)
-            self._counters_seen[:] = 0
+        self.pool = self.programs.init_pool()
+        self._read_counters()           # keep what the lost pool counted
+        self._counters = jnp.zeros(len(self._counter_names), jnp.int32)
+        self._counters_seen[:] = 0
         self.bt = np.zeros((self.num_slots, self.max_blocks), np.int32)
         self.cursor = np.zeros(self.num_slots, np.int32)
         self._free = list(range(self.num_pages, 0, -1))   # pop() -> page 1 last
@@ -713,56 +568,50 @@ class PagedSlots:
         requests that were live."""
         import jax
 
-        if self.declared:
-            args = (self._counters,) + args
         try:
-            self.pool, out = program(*self.pool, *args)
+            self.pool, (out, self._counters) = program(
+                *self.pool, self._counters, *args)
         except Exception:
             if any(a.is_deleted()
                    for a in jax.tree_util.tree_leaves(self.pool)):
                 self._reset_pool()
                 self._set_gauges()
             raise
-        if self.declared:
-            out, self._counters = out
         return out
 
     # ------------------------------------------------------------- schedule
-    def _resolve_schedule(self):
-        """The step-attention schedule for this pool's shape signature
-        — decided ONCE, here at bind time, never per tick (the search's
-        device syncs are the declared ``autotune.search.measure``
-        boundary).  ``None`` means the PR-15 gather step verbatim."""
+    def _resolve_schedule(self, kv_pages):
+        """The step-attention schedule for the shape signature of the
+        K/V pools the decoder declared (``kv_pages``) — decided ONCE, here at bind
+        time, never per tick (the search's device syncs are the declared
+        ``autotune.search.measure`` boundary).  ``None`` means the PR-15
+        gather step (and is all there is without K/V pages)."""
         import jax
 
         from .. import autotune as _autotune
         from ..ops import paged_attention as _pa
 
         mode = self.kernel_mode
-        if mode == "gather":
+        if mode == "gather" or kv_pages is None:
             return None
-        d = self.decoder
+        L, H, dh, dtype = kv_pages
         B, M, blk = self.num_slots, self.max_blocks, self.block
-        dtype = d._cache_dtype
         if mode in ("pallas", "interpret"):
-            if not _pa.supports(blk, d.dh, dtype):
+            if not _pa.supports(blk, dh, dtype):
                 return None         # shape gate even when forced
             return {"impl": "pallas", "interpret": mode == "interpret"}
-        if mode == "pagewalk":
-            return {"impl": "pagewalk", "chunk": 1}
         if mode != "auto":
             raise MXNetError(
                 f"unknown MXTPU_PAGED_KERNEL mode {mode!r} (want auto, "
-                "gather/0, pallas, interpret or pagewalk)")
+                "gather/0, pallas or interpret)")
         platform = jax.default_backend()
-        default = _pa.default_schedule(platform, blk, d.dh, dtype)
         sched = _autotune.ensure(
             "paged_attention",
-            _pa.keysig(B, d.H, M, blk, d.dh, dtype),
-            default,
-            _pa.candidate_schedules(platform, blk, d.dh, M, dtype),
-            lambda c: _pa.make_bench_fn(c, B=B, H=d.H, M=M, block=blk,
-                                        dh=d.dh, L=d.L, dtype=dtype))
+            _pa.keysig(B, H, M, blk, dh, dtype),
+            _pa.default_schedule(platform, blk, dh, dtype),
+            _pa.candidate_schedules(platform, blk, dh, dtype),
+            lambda c: _pa.make_bench_fn(c, B=B, H=H, M=M, block=blk,
+                                        dh=dh, L=L, dtype=dtype))
         return None if sched.get("impl") == "gather" else dict(sched)
 
     # --------------------------------------------------------- bookkeeping
@@ -770,8 +619,9 @@ class PagedSlots:
         _TM_PAGES.set(self.num_pages, state="total")
         _TM_PAGES.set(len(self._free), state="free")
         _TM_PAGES.set(len(self._prefix), state="prefix")
-        if self.declared:
+        if self.programs.layout["state"]:
             _TM_STATE_SLOTS.set(self._slots_in_use())
+        if self.programs.layout["pages"]:
             _TM_LATENT_PAGES.set(self.num_pages - len(self._free))
 
     def _slots_in_use(self):
@@ -782,9 +632,9 @@ class PagedSlots:
         side is int32 and may wrap (after ~2**31 assignments, hours of
         traffic): the host adds the difference modulo 2**32 since its
         last read, so a reader that comes by now and then never sees
-        the wrap.  The one device fetch of the counters; never called
-        from a tick."""
-        if self._counters is not None:
+        the wrap.  The one device fetch of the counters (none for a
+        decoder that declares no counter); never called from a tick."""
+        if self._counters is not None and self._counter_names:
             now = np.asarray(self._counters).astype(np.int64)
             self._counted += (now - self._counters_seen) % (1 << 32)
             self._counters_seen = now
@@ -792,25 +642,23 @@ class PagedSlots:
 
     def stats(self):
         """The ``/healthz`` ``paged`` payload."""
+        layout = self.programs.layout
         out = {"block": self.block,
                "pages_total": self.num_pages,
                "pages_free": len(self._free),
                "prefix_pages": len(self._prefix),
                "prefix_reuse": self.prefix_on,
-               "kernel": "none" if self.declared
-               else (self.schedule or {"impl": "gather"})["impl"]}
-        if self.declared:
-            held, absent, distinct = (int(n) for n in self._read_counters())
-            out.update(
-                family=self.decoder.family,
-                state_slots_in_use=self._slots_in_use(),
-                latent_pages_in_use=self.num_pages - len(self._free),
-                expert_assignments_held=held,
-                expert_assignments_absent=absent,
-                expert_distinct_hits=distinct)
-            for where, n in (("held", held), ("absent", absent),
-                             ("distinct_hit", distinct)):
-                _TM_EXPERT_PAIRS.set(n, where=where)
+               "family": self.decoder.family,
+               # the step's attention over K/V pages, where there are any
+               "kernel": (self.schedule or {"impl": "gather"})["impl"]
+               if "kv_pages" in layout else "none"}
+        if layout["state"]:
+            out["state_slots_in_use"] = self._slots_in_use()
+        if layout["pages"]:
+            out["latent_pages_in_use"] = self.num_pages - len(self._free)
+        for name, n in zip(self._counter_names, self._read_counters()):
+            out[name] = int(n)
+            _TM_COUNTED.set(int(n), name=name)
         return out
 
     def _alloc(self, n):
@@ -919,11 +767,13 @@ class PagedSlots:
         padded = np.zeros((1, bucket), np.int64)
         padded[0, :t] = tail
         # _snap: self.bt is mutated in place by later admits/steps while
-        # this dispatch may still be executing — never alias it
-        # the K/V programs prefill a tail behind ``hist`` shared tokens; a
-        # declared decoder shares none and is told its slot instead
-        args = (_snap(self.bt[slot]), jnp.asarray(padded),
-                jnp.int32(slot if self.declared else hist), jnp.int32(t))
+        # this dispatch may still be executing — never alias it.  The
+        # slot goes in as a host value: a program that does not read it
+        # (jit prunes unused arguments) then costs no transfer, where
+        # each explicit one before the launch costs 0.3-0.45 ms on the
+        # chip (PERF.md section 6, PR 29)
+        args = (_snap(self.bt[slot]), jnp.asarray(padded), np.int32(slot),
+                jnp.int32(hist), jnp.int32(t))
         logits = self._run(self.programs.prefill(bucket), *args)
         if bucket not in self._cost_prefill_done and _tm.perf.enabled():
             self._cost_prefill_done.add(bucket)
@@ -953,8 +803,7 @@ class PagedSlots:
                 time.perf_counter() - t_kv0, slot=slot,
                 pages_shared=n_shared, pages_owned=len(owned),
                 bucket=bucket)
-        # a declared decoder's prefill returns the last real token's row
-        return logits if self.declared else logits[0, t - 1]
+        return logits
 
     # ----------------------------------------------------------------- tick
     def step(self, tokens, occupied):
@@ -988,9 +837,10 @@ class PagedSlots:
                 self._slot_pages[b].append(pg)
         # _snap: bt/cursor are mutated in place right below and on the
         # next tick — aliasing them into the async dispatch races
-        args = (_snap(self.bt), _snap(tokens), _snap(self.cursor))
-        if self.declared:
-            args += (_snap(occupied, bool),)
+        # ``occupied`` as a host copy of its own (nothing mutates it): no
+        # transfer where the program does not read it (see admit)
+        args = (_snap(self.bt), _snap(tokens), _snap(self.cursor),
+                np.array(occupied, bool))
         logits = self._run(self.programs._step_jit, *args)
         if not self._cost_step_done and _tm.perf.enabled():
             self._cost_step_done = True
@@ -1011,11 +861,10 @@ class PagedSlots:
         it is handed the pool's shape, never its buffers."""
         from ..models.decode import _snap
 
-        args = (_snap(self.bt), _snap(np.zeros(self.num_slots, np.int64)),
-                _snap(self.cursor))
-        if self.declared:
-            args += (_snap(np.zeros(self.num_slots), bool),)
-        return self.programs._step_jit.lower(*self._lowering_args(), *args)
+        return self.programs._step_jit.lower(
+            *self._lowering_args(), _snap(self.bt),
+            _snap(np.zeros(self.num_slots, np.int64)), _snap(self.cursor),
+            _snap(np.zeros(self.num_slots), bool))
 
     def lower_prefill(self, bucket):
         """One prefill bucket's program, lowered the same way."""
@@ -1026,17 +875,15 @@ class PagedSlots:
         return self.programs.prefill(bucket).lower(
             *self._lowering_args(), _snap(self.bt[0]),
             jnp.asarray(np.zeros((1, bucket), np.int64)), jnp.int32(0),
-            jnp.int32(1))
+            jnp.int32(0), jnp.int32(1))
 
     def _lowering_args(self):
-        """What stands for the pool (and the counters) when a program
+        """What stands for the cache and the counters when a program
         is lowered without being run: shapes, never the buffers."""
         import jax
 
-        structs = self.programs.pool_structs()
-        if self.declared:
-            structs += (jax.ShapeDtypeStruct((_N_COUNTERS,), np.int32),)
-        return structs
+        return self.programs.pool_structs() + (jax.ShapeDtypeStruct(
+            (len(self._counter_names),), np.int32),)
 
     def exhausted(self, slot):
         return self.cursor[slot] >= self.decoder.max_len

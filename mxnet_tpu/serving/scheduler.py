@@ -250,7 +250,7 @@ class SlotScheduler:
     over a shared page pool with prompt-prefix reuse.  Default follows
     ``MXTPU_KV_BLOCK`` (0/unset = contiguous).  ``paged_kernel``
     overrides ``MXTPU_PAGED_KERNEL`` — the paged step's attention
-    lowering (gather / Pallas page-walk kernel / lax pagewalk; ISSUE
+    lowering (gather / Pallas page-walk kernel; ISSUE
     18), resolved once at construction through ``mxnet_tpu.autotune``.
     """
 

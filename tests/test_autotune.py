@@ -265,7 +265,7 @@ def test_refused_candidate_is_counted_and_logged_on_tpu(
         return lambda: 0.0
 
     cands = [{"impl": "gather"}, {"impl": "pallas"},
-             {"impl": "pagewalk", "chunk": 1}]
+             {"impl": "pallas", "interpret": True}]
     rejected = metrics.get("autotune_rejected_total")
     r0 = rejected.total()
     with caplog.at_level("WARNING", logger="mxnet_tpu.autotune"):
@@ -339,8 +339,8 @@ def _run_op(sched, args, layer, block):
 
 
 # the kernel joins its chunks with a running max / sum / accumulator, so
-# the sums over positions are reassociated against gather (as
-# pagewalk's are): f32 agrees to a few ulp of the output's scale
+# the sums over positions are reassociated against gather: f32 agrees
+# to a few ulp of the output's scale
 _F32_RTOL, _F32_ATOL = 2e-5, 2e-6
 
 _PARITY = {
@@ -454,17 +454,6 @@ def test_chunk_pages_follows_the_page_not_the_table(no_cache):
     assert pa.chunk_pages(64, 32, 256, "float32", 64) == 1
 
 
-def test_pagewalk_allclose_vs_gather(no_cache):
-    """The lax pagewalk reassociates the reductions (loop-carried
-    accumulation) — allclose, deliberately NOT bitwise, which is why
-    only the autotuner or an explicit mode ever installs it."""
-    args = _op_case()
-    ref = _run_op(None, args, 0, 8)
-    for chunk in (1, 2, 4):
-        out = _run_op({"impl": "pagewalk", "chunk": chunk}, args, 0, 8)
-        np.testing.assert_allclose(ref, out, rtol=2e-5, atol=2e-6)
-
-
 def test_shape_gate_falls_back_bit_identical(no_cache):
     """A shape the kernel cannot tile (block % 8 != 0, dh short of the
     128 lanes) takes the gather path by the gate even when the pallas
@@ -484,16 +473,12 @@ def test_shape_gate_falls_back_bit_identical(no_cache):
 
 
 def test_candidate_schedules_and_keysig(no_cache):
-    cands = pa.candidate_schedules("cpu", 8, 32, 4, np.float32)
-    assert {"impl": "gather"} in cands
-    assert all(c["impl"] != "pallas" for c in cands), \
-        "compiled-pallas candidates are TPU-only"
-    assert {"impl": "pagewalk", "chunk": 3} not in cands  # 3 !| M=4
-    tpu = pa.candidate_schedules("tpu", 8, 128, 4, np.float32)
-    assert [c for c in tpu if c["impl"] == "pallas"] == \
-        [{"impl": "pallas"}], "one kernel, no knob"
-    narrow = pa.candidate_schedules("tpu", 8, 32, 4, np.float32)
-    assert all(c["impl"] != "pallas" for c in narrow), \
+    assert pa.candidate_schedules("cpu", 8, 128, np.float32) == \
+        [{"impl": "gather"}], "compiled-pallas candidates are TPU-only"
+    assert pa.candidate_schedules("tpu", 8, 128, np.float32) == \
+        [{"impl": "gather"}, {"impl": "pallas"}], "one kernel, no knob"
+    assert pa.candidate_schedules("tpu", 8, 32, np.float32) == \
+        [{"impl": "gather"}], \
         "a head narrower than 128 lanes is gated off the kernel"
     assert pa.default_schedule("cpu", 8, 128, np.float32) == \
         {"impl": "gather"}
@@ -560,19 +545,12 @@ def test_paged_slots_interpret_kernel_end_to_end(wide_decoder, no_cache):
     assert np.array_equal(ref[0], outs[0]), "a prefill runs no kernel"
 
 
-def test_paged_slots_pagewalk_and_auto(decoder, no_cache):
-    """Pagewalk through the same life cycle stays allclose (its
-    documented tier); auto with the cache off resolves to gather on a
-    CPU host — bit-identical to MXTPU_PAGED_KERNEL=0."""
+def test_paged_slots_auto(decoder, no_cache):
+    """Auto with the cache off resolves to gather on a CPU host —
+    bit-identical to MXTPU_PAGED_KERNEL=0."""
     buckets = (8, 16, 32)
     ref = _drive(PagedSlots(decoder, 3, block=8, prefill_buckets=buckets,
                             kernel="gather"))
-    pw = PagedSlots(decoder, 3, block=8, prefill_buckets=buckets,
-                    kernel="pagewalk")
-    assert pw.stats()["kernel"] == "pagewalk"
-    for a, b in zip(ref, _drive(pw)):
-        scale = max(1.0, float(np.abs(a).max()))
-        assert np.abs(a - b).max() < 1e-3 * scale
     auto = PagedSlots(decoder, 3, block=8, prefill_buckets=buckets)
     assert auto.schedule is None and auto.stats()["kernel"] == "gather"
     for a, b in zip(ref, _drive(auto)):

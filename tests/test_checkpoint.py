@@ -387,10 +387,17 @@ _KILL_SCRIPT = textwrap.dedent("""
             # tell the parent we are mid-epoch and killable — but only
             # once a COMPLETE checkpoint exists (the background writer
             # races the dispatch loop; a kill before any publish would
-            # just test the fresh-start path)
-            if param.nbatch >= 7 and ck.latest(ckdir) is not None:
-                print("KILLME", flush=True)
+            # just test the fresh-start path).  Wait for that publish:
+            # on a loaded machine the 24 batches can be over before
+            # the writer has renamed its first file, and a victim that
+            # never says KILLME exits 0
+            if param.nbatch >= 7:
                 import time
+                deadline = time.monotonic() + 120
+                while ck.latest(ckdir) is None \
+                        and time.monotonic() < deadline:
+                    time.sleep(0.05)
+                print("KILLME", flush=True)
                 time.sleep(60)   # parent SIGKILLs us here
     t.fit(it, num_epoch=2, checkpoint=mgr,
           resume=(mode == "resume"), batch_end_callback=cb)

@@ -9,6 +9,7 @@ decode is still bitwise the contiguous one, a shared page is never
 written, and a call that dies with the pool's buffers leaves a backend
 that serves again.
 """
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -17,7 +18,8 @@ import mxnet_tpu as mx
 from mxnet_tpu import models
 from mxnet_tpu.models.decode import KVDecoder
 from mxnet_tpu.serving import SlotScheduler
-from mxnet_tpu.serving.paged_kv import PagedSlots, _PagedPrograms
+from mxnet_tpu.serving.paged_kv import (PagedSlots, _PrefillView,
+                                       _StepView)
 from mxnet_tpu.serving.scheduler import _ContiguousSlots
 from mxnet_tpu.telemetry import perf
 
@@ -48,19 +50,23 @@ def _paged(decoder, slots=3, **kw):
                       **kw)
 
 
-class _RowScatter(_PagedPrograms):
-    """The writes as they were: one scatter of rows a layer, in the step
-    and in the prefill (whose pad rows it writes too, where their page
-    is real; nothing live)."""
+class _RowScatterStep(_StepView):
+    """The step's write as it was: one scatter of rows a layer."""
 
-    def _write_rows(self, pool, new, layer, at):
-        pages, offs = (jnp.stack(x) for x in zip(*at))
+    def _write_rows(self, pool, new, layer):
+        pages, offs = (jnp.stack(x) for x in zip(*self._at))
         return pool.at[pages, layer, :, offs].set(new[:, :, 0])
 
-    def _write_pages(self, pool, new, page_ids, layer):
+
+class _RowScatterPrefill(_PrefillView):
+    """The prefill's write as it was: one scatter of rows a layer (its
+    pad rows too, where their page is real; nothing live)."""
+
+    def _write_pages(self, pool, new, layer):
         j = jnp.arange(new.shape[0])
-        return pool.at[page_ids[j // self.block], layer, :,
-                       j % self.block].set(new, mode="drop")
+        block = self._pg.block
+        return pool.at[self._page_ids[j // block], layer, :,
+                       j % block].set(new, mode="drop")
 
 
 def _live_rows(pg, slot):
@@ -69,22 +75,21 @@ def _live_rows(pg, slot):
     n = int(pg.cursor[slot])
     pages = pg.bt[slot, :(n + BLOCK - 1) // BLOCK]
     out = []
-    for side in pg.pool:
+    for side in pg.pool[0]["kv"]:
         rows = np.asarray(side)[pages]              # (n_pg, L, H, blk, dh)
         rows = rows.transpose(0, 3, 1, 2, 4).reshape(-1, L, H, D // H)
         out.append(rows[:n])
     return np.stack(out)
 
 
-@pytest.mark.parametrize("kernel", ["gather", "pagewalk"])
-def test_pool_holds_what_the_row_scatter_wrote(decoder, kernel):
+def test_pool_holds_what_the_row_scatter_wrote(decoder):
     """Admissions of every bucket, ticks across page boundaries, a slot
     released and taken again: every live position of every live page,
     and every logit on the way, is bitwise what the row scatter gave."""
-    new = _paged(decoder, kernel=kernel)
-    old = _paged(decoder, kernel=kernel)
-    old.programs = _RowScatter(decoder, BLOCK, old.max_blocks,
-                               old.num_pages + 1, schedule=old.schedule)
+    new = _paged(decoder)
+    old = _paged(decoder)
+    old.programs.step_view = _RowScatterStep
+    old.programs.prefill_view = _RowScatterPrefill
     rs = np.random.RandomState(3)
     tok = np.zeros(3, np.int64)
 
@@ -150,19 +155,19 @@ def test_shared_page_is_never_written(decoder):
     pg.admit(0, np.concatenate([shared, rs.randint(0, V, 5)]))
     page = pg.bt[0, 0]
     assert page in pg._prefix.values()
-    before = [np.asarray(side)[page].copy() for side in pg.pool]
+    before = [np.asarray(side)[page].copy() for side in pg.pool[0]["kv"]]
     pg.admit(1, np.concatenate([shared, rs.randint(0, V, 7)]))
     assert pg.bt[1, 0] == page and pg.bt[1, 1] != pg.bt[0, 1]
     occ = np.array([True, True, False])
     for _ in range(6):
         pg.step(np.array([1, 2, 0]), occ)
-    for side, was in zip(pg.pool, before):
+    for side, was in zip(pg.pool[0]["kv"], before):
         assert np.array_equal(np.asarray(side)[page], was)
 
 
-def _takes_the_pool_and_dies(pool_k, pool_v, *_args):
-    pool_k.delete()
-    pool_v.delete()
+def _takes_the_pool_and_dies(cache, *_args):
+    for side in cache["kv"]:
+        side.delete()
     raise RuntimeError("planted: died holding the pool")
 
 
@@ -195,7 +200,7 @@ def test_backend_starts_anew_after_losing_the_pool(decoder, where):
     st = pg.stats()
     assert st["pages_free"] == st["pages_total"] and st["prefix_pages"] == 0
     assert not pg._ref.any() and not pg.bt.any() and not pg.cursor.any()
-    assert not any(a.is_deleted() for a in pg.pool)
+    assert not any(a.is_deleted() for a in pg.pool[0]["kv"])
     with pytest.raises(mx.MXNetError, match="holds no pages"):
         pg.step(tok, occ)               # slot 0's request cannot go on
     for slot in range(3):               # what the scheduler does next
@@ -274,10 +279,132 @@ def test_lowering_takes_nothing(decoder):
         occ = np.array([True, False, False])
         pg.step(tok, occ)               # first dispatch: cost analysis
         assert "input_output_alias" in pg.lower_step().compile().as_text()
-        assert not any(a.is_deleted() for a in pg.pool)
+        assert not any(a.is_deleted() for a in pg.pool[0]["kv"])
         rows = _live_rows(pg, 0)
         pg.step(tok, occ)
         assert np.array_equal(_live_rows(pg, 0)[:, :13], rows)
     finally:
         if not was:
             perf.disable()
+
+
+# ------------------------------------------- a third decoder, defined here
+# What the seam is for: a decoder that is neither models/decode.py's nor
+# models/ling.py's declares K/V pages, brings one ``forward`` over the
+# view, and is served -- page writes, prefix index, the kernel -- with
+# no line of serving/paged_kv.py knowing it.
+class _ToyDecoder:
+    """A GPT-2-shaped block without biases: RMS norm, sinusoidal
+    positions (so it never asks the view to ``embed``), one fused q/k/v
+    projection.  128-wide heads and float32, so 8-row pages pass
+    ``ops.paged_attention.supports``."""
+    family, mesh = "toy", None
+    L, H, DH, V = 2, 2, 128, 23
+
+    def __init__(self, max_len, counters=()):
+        D = self.H * self.DH
+        rs = np.random.RandomState(5)
+        shapes = {"embed": (self.V, D), "head": (self.V, D)}
+        for i in range(self.L):
+            shapes.update({f"l{i}_qkv": (3 * D, D), f"l{i}_o": (D, D),
+                           f"l{i}_up": (2 * D, D), f"l{i}_down": (D, 2 * D)})
+        self.p = {k: jnp.asarray(rs.normal(0, 0.06, s), jnp.float32)
+                  for k, s in shapes.items()}
+        self.max_len, self.vocab, self.counters = max_len, self.V, counters
+
+    def paged_layout(self):
+        return {"kv_pages": (self.L, self.H, self.DH, jnp.float32),
+                "pages": {}, "state": {}, "counters": self.counters,
+                "prefix_reuse": True}
+
+    def forward(self, p, tokens, view):
+        """``tokens`` (N,) at ``view.positions`` -> (B, V) in the step,
+        (V,), the last real token's row, in a prefill."""
+        H, DH = self.H, self.DH
+        D = H * DH
+        norm = lambda x: x * jax.lax.rsqrt(
+            jnp.mean(x * x, -1, keepdims=True) + 1e-6)
+        ang = view.positions[:, None] * (
+            1e4 ** (-jnp.arange(0, D, 2) / D))[None, :]
+        h = p["embed"][tokens] + jnp.concatenate(
+            [jnp.sin(ang), jnp.cos(ang)], -1)
+        h = h[:, None] if view.step else h[None]             # (B, n, D)
+        B, n, _ = h.shape
+        heads = lambda a: a.reshape(B, n, H, DH).transpose(0, 2, 1, 3)
+        for i in range(self.L):
+            q, k, v = jnp.split(norm(h) @ p[f"l{i}_qkv"].T, 3, -1)
+            ctx = view.attend(i, heads(q), heads(k), heads(v))
+            h = h + ctx.transpose(0, 2, 1, 3).reshape(B, n, D) \
+                @ p[f"l{i}_o"].T
+            h = h + jax.nn.gelu(norm(h) @ p[f"l{i}_up"].T) \
+                @ p[f"l{i}_down"].T
+        if self.counters:       # real tokens seen, program calls
+            view.count(jnp.stack([jnp.sum(view.valid), 1]).astype(jnp.int32))
+        logits = norm(h) @ p["head"].T
+        return logits[:, 0] if view.step else logits[0, view.length - 1]
+
+
+class _DenseView:
+    """The toy's own reference: one whole sequence, no cache."""
+    step = False
+
+    def __init__(self, n):
+        self.positions, self.length = jnp.arange(n), n
+
+    def attend(self, layer, q, k, v):
+        n = q.shape[2]
+        scores = jnp.einsum("bhnd,bhsd->bhns", q, k) / np.sqrt(q.shape[-1])
+        causal = jnp.arange(n)[:, None] >= jnp.arange(n)[None, :]
+        att = jax.nn.softmax(jnp.where(causal, scores, -1e30), axis=-1)
+        return jnp.einsum("bhns,bhsd->bhnd", att, v)
+
+
+@pytest.mark.parametrize("kernel,impl", [("gather", "gather"),
+                                         ("interpret", "pallas")])
+def test_a_decoder_defined_here_is_served_over_kv_pages(kernel, impl):
+    """Two requests (one prompt ends inside a page, the other shares its
+    first page through the prefix index), then ``BLOCK + 2`` greedy
+    steps each across page boundaries: every logits row is the dense
+    forward's over the tokens so far, every token its choice."""
+    dec = _ToyDecoder(max_len=T)
+    pg = PagedSlots(dec, 3, block=BLOCK, prefill_buckets=BUCKETS,
+                    kernel=kernel, prefix_cache=True)
+    assert pg.stats()["kernel"] == impl and pg.stats()["family"] == "toy"
+
+    def dense(tokens):
+        return np.asarray(dec.forward(dec.p, jnp.asarray(tokens),
+                                      _DenseView(len(tokens))))
+
+    def same(got, tokens):
+        want = dense(tokens)
+        np.testing.assert_allclose(np.asarray(got), want, rtol=2e-4,
+                                   atol=2e-5)
+        assert int(np.argmax(got)) == int(want.argmax())
+        return tokens + [int(want.argmax())]
+
+    rs = np.random.RandomState(6)
+    first = rs.randint(0, dec.V, 11).tolist()
+    seqs = {0: same(pg.admit(0, np.array(first)), first)}
+    fork = first[:BLOCK] + rs.randint(0, dec.V, 4).tolist()
+    seqs[2] = same(pg.admit(2, np.array(fork)), fork)
+    assert pg.bt[2, 0] == pg.bt[0, 0], "the first page is shared"
+    occ = np.array([True, False, True])
+    for _ in range(BLOCK + 2):
+        tok = np.array([seqs[0][-1], 0, seqs[2][-1]])
+        logits = np.asarray(pg.step(tok, occ)[0])
+        for slot in (0, 2):
+            seqs[slot] = same(logits[slot], seqs[slot])
+    assert len(seqs[0]) == 11 + BLOCK + 3 <= T
+
+
+def test_a_decoder_sees_its_counters_under_its_own_names():
+    dec = _ToyDecoder(max_len=T, counters=("toy_real_tokens", "toy_calls"))
+    pg = _paged(dec)
+    assert pg.stats()["toy_real_tokens"] == pg.stats()["toy_calls"] == 0
+    pg.admit(1, np.arange(11) % dec.V)
+    occ = np.array([False, True, False])
+    for _ in range(3):
+        pg.step(np.array([0, 3, 0]), occ)
+    st = pg.stats()
+    assert (st["toy_real_tokens"], st["toy_calls"]) == (11 + 3, 4)
+    assert "expert_assignments_held" not in st

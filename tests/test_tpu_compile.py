@@ -19,11 +19,11 @@ The paged serving programs are compiled whole (abstract weights, a few
 layers) for what the kernels alone cannot show: which layout the
 compiler gives the page pool between them.  Any new program that takes
 the pool is added to ``test_paged_program_keeps_pool_layout``
-(docs/serving.md, "The pool's layout is the kernel's").  The programs of
-a decoder that declares its own cache (``models/ling.py``: latent pages
-and a recurrent state a slot) are compiled the same way at the
-benchmark's real sizes, where a copy of a state array would cost what
-the pool's copies cost before ISSUE 26:
+(docs/serving.md, "The pool's layout is the kernel's").  The same
+programs over a decoder that declares page rows of its own and a
+recurrent state a slot (``models/ling.py``) are compiled the same way
+at the benchmark's real sizes, where a copy of a state array would cost
+what the pool's copies cost before ISSUE 26:
 ``test_declared_program_copies_neither_state_nor_pages``.
 """
 import functools
@@ -39,8 +39,7 @@ from mxnet_tpu.models.decode import KVDecoder
 from mxnet_tpu.ops import flash_attention as fa
 from mxnet_tpu.ops import paged_attention as pa
 from mxnet_tpu.ops import residual_epilogue as repi
-from mxnet_tpu.serving.paged_kv import (_N_COUNTERS, _DeclaredPrograms,
-                                       _PagedPrograms)
+from mxnet_tpu.serving.paged_kv import _CachePrograms, _StepView
 
 
 @pytest.fixture(scope="module")
@@ -167,7 +166,7 @@ _POOL = _pool_shape(_LAYERS)
 _BUCKETS = (128, 256, 512, 1024)
 
 
-def _paged_programs(sds, cls=_PagedPrograms, layers=_LAYERS):
+def _paged_programs(sds, step_view=_StepView, layers=_LAYERS):
     """``serving/paged_kv.py``'s programs over a decoder that holds
     shapes for weights: nothing is allocated, everything lowers."""
     D, F, V = _HEADS * _DH, 512, 1024
@@ -189,24 +188,34 @@ def _paged_programs(sds, cls=_PagedPrograms, layers=_LAYERS):
     dec.L, dec.H, dec.dh, dec.d_model = layers, _HEADS, _DH, D
     dec.max_len, dec.mesh = _MAX_LEN, None
     dec._cache_dtype = jnp.dtype("bfloat16")
-    return cls(dec, _BLOCK, _MAX_LEN // _BLOCK, _PAGES,
-               schedule=pa.default_schedule("tpu", _BLOCK, _DH, "bfloat16"))
+    progs = _CachePrograms(
+        dec, dec.paged_layout(), _BLOCK, _MAX_LEN // _BLOCK, _PAGES, _SLOTS,
+        schedule=pa.default_schedule("tpu", _BLOCK, _DH, "bfloat16"))
+    progs.step_view = step_view
+    return progs
 
 
 def _compile_paged(progs, sds, which):
     """The step program (``which`` = "step") or one prefill bucket's,
     compiled with the pool handed over as ``PagedSlots`` does."""
-    pool = sds((_PAGES, progs.dec.L, _HEADS, _BLOCK, _DH), "bfloat16")
-    M = _MAX_LEN // _BLOCK
+    return _lower(progs, sds, which, _SLOTS, _MAX_LEN // _BLOCK)[0].compile()
+
+
+def _lower(progs, sds, which, B, M):
+    """The step program (``which`` = "step") or one prefill bucket's of
+    ``progs``, lowered over the shapes of its cache and counters."""
+    cache = jax.tree_util.tree_map(lambda s: sds(s.shape, s.dtype),
+                                   progs.pool_structs())
+    counters = sds((len(progs.layout.get("counters", ())),), "int32")
     if which == "step":
         lowered = progs._step_jit.lower(
-            pool, pool, sds((_SLOTS, M), "int32"),
-            sds((_SLOTS,), "int32"), sds((_SLOTS,), "int32"))
+            *cache, counters, sds((B, M), "int32"), sds((B,), "int32"),
+            sds((B,), "int32"), sds((B,), "bool"))
     else:
         lowered = progs.prefill(which).lower(
-            pool, pool, sds((M,), "int32"), sds((1, which), "int32"),
-            sds((), "int32"), sds((), "int32"))
-    return lowered.compile()
+            *cache, counters, sds((M,), "int32"), sds((1, which), "int32"),
+            sds((), "int32"), sds((), "int32"), sds((), "int32"))
+    return lowered, cache
 
 
 def _pool_copies(text, pool=_POOL):
@@ -267,9 +276,9 @@ def test_row_scatter_relayouts_the_pool(one_chip):
     dimension out of the tiled pair and copies the whole pool into that
     layout and back for every kernel operand: 2 + 2 L copies.  So the
     test above is known to see them."""
-    class RowScatter(_PagedPrograms):
-        def _write_rows(self, pool, new, layer, at):
-            pages, offs = (jnp.stack(x) for x in zip(*at))
+    class RowScatter(_StepView):
+        def _write_rows(self, pool, new, layer):
+            pages, offs = (jnp.stack(x) for x in zip(*self._at))
             return pool.at[pages, layer, :, offs].set(new[:, :, 0])
 
     text = _compile_paged(_paged_programs(one_chip, RowScatter), one_chip,
@@ -284,7 +293,7 @@ _LING_SLOTS, _LING_MAX_LEN = 128, 2304
 
 
 def _ling_programs(sds, donate=True):
-    """``_DeclaredPrograms`` over a ``LingDecoder`` that holds shapes for
+    """``_CachePrograms`` over a ``LingDecoder`` that holds shapes for
     weights, from the benchmark's configuration file."""
     import json
 
@@ -309,28 +318,18 @@ def _ling_programs(sds, donate=True):
     dec.max_len, dec.vocab = _LING_MAX_LEN, config["vocab_size"]
     dec._cache_dtype = jnp.dtype("bfloat16")
     M = _LING_MAX_LEN // _BLOCK
-    progs = _DeclaredPrograms(dec, _BLOCK, M, _LING_SLOTS * M + 1,
-                              _LING_SLOTS)
+    progs = _CachePrograms(dec, dec.paged_layout(), _BLOCK, M,
+                           _LING_SLOTS * M + 1, _LING_SLOTS)
     if not donate:
         from mxnet_tpu.models.decode import _WeightProgram
         progs._step_jit = _WeightProgram(
-            dec, progs._forward_step, "decode_step_ling_kept")
+            dec, progs._step_program, "decode_step_ling_kept")
     return progs
 
 
 def _compile_ling(progs, sds, which):
-    cache = jax.tree_util.tree_map(lambda s: sds(s.shape, s.dtype),
-                                   progs.pool_structs())
-    counters = sds((_N_COUNTERS,), "int32")
-    B, M = _LING_SLOTS, _LING_MAX_LEN // _BLOCK
-    if which == "step":
-        lowered = progs._step_jit.lower(
-            *cache, counters, sds((B, M), "int32"), sds((B,), "int32"),
-            sds((B,), "int32"), sds((B,), "bool"))
-    else:
-        lowered = progs.prefill(which).lower(
-            *cache, counters, sds((M,), "int32"), sds((1, which), "int32"),
-            sds((), "int32"), sds((), "int32"))
+    lowered, cache = _lower(progs, sds, which, _LING_SLOTS,
+                            _LING_MAX_LEN // _BLOCK)
     return lowered.compile(), cache
 
 
