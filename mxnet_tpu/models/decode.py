@@ -448,8 +448,8 @@ class KVDecoder:
     # CALLER tracks as host int arrays — no step reads device state, so
     # the scheduler's bookkeeping costs zero syncs, exactly like the
     # shared-pos API's host counter.  serving/paged_kv.py runs the same
-    # forward over its views of a shared page pool — bitwise equal to
-    # this path on aligned prompts, test-pinned.
+    # forward over its views of a shared page pool — equal to this path
+    # to the last bits of a sum taken in another order, test-pinned.
 
     def init_slot_state(self, num_slots):
         """Empty slot-pool cache ``(k_cache, v_cache)`` for ``num_slots``

@@ -44,7 +44,7 @@ from ..base import mxu_precision
 
 __all__ = [
     "supports", "keysig", "default_schedule", "candidate_schedules",
-    "chunk_pages", "paged_attention", "gather_tables", "dense_attention",
+    "chunk_pages", "paged_attention", "layer_table", "dense_attention",
     "make_bench_fn",
 ]
 
@@ -96,15 +96,13 @@ def candidate_schedules(platform: str, block: int, dh: int, dtype) -> list:
 
 
 # ---------------------------------------------------------------- gather
-def gather_tables(pool, bt):
-    """``(P, L, H, blk, dh)[bt (B, M)] -> (L, B, H, M*blk, dh)``: every
-    layer's table in the contiguous layout (a paged prefill attends
-    over it)."""
+def layer_table(pool, bt, layer):
+    """``(P, L, H, blk, dh)[bt (B, M), layer] -> (B, H, M*blk, dh)``: one
+    layer's table of each slot in the contiguous layout."""
     B, M = bt.shape
-    _P, L, H, blk, dh = pool.shape
-    t = pool[bt]                                 # (B, M, L, H, blk, dh)
-    t = t.transpose(2, 0, 3, 1, 4, 5)            # (L, B, H, M, blk, dh)
-    return t.reshape(L, B, H, M * blk, dh)
+    _P, _L, H, blk, dh = pool.shape
+    return pool[bt, layer].transpose(0, 2, 1, 3, 4).reshape(
+        B, H, M * blk, dh)
 
 
 def dense_attention(q, k, v, mask):
@@ -121,15 +119,10 @@ def dense_attention(q, k, v, mask):
 
 
 def _gather_attention(q, pool_k, pool_v, bt, cursor, layer):
-    B, M = bt.shape
-    H, blk, dh = pool_k.shape[2:]
-
-    def table(pool):                             # one layer's, (B, H, S, dh)
-        return pool[bt, layer].transpose(0, 2, 1, 3, 4) \
-            .reshape(B, H, M * blk, dh)
-
-    valid = jnp.arange(M * blk)[None, :] <= cursor[:, None]
-    return dense_attention(q, table(pool_k), table(pool_v),
+    S = bt.shape[1] * pool_k.shape[3]
+    valid = jnp.arange(S)[None, :] <= cursor[:, None]
+    return dense_attention(q, layer_table(pool_k, bt, layer),
+                           layer_table(pool_v, bt, layer),
                            valid[:, None, None, :])
 
 

@@ -41,13 +41,17 @@ declare:
 - ``kv_pages``: ``(layers, heads, head_dim, dtype)`` — the pair of
   ``(P, layers, heads, block, head_dim)`` K/V pools
   (``models/decode.py:KVDecoder``, the GPT-2 block).  ``view.attend``
-  writes a layer's new rows and attends over the slot's table: in the
-  step by the schedule's lowering (``ops/paged_attention.py``: the
-  Pallas kernel, or gather), in a prefill over the gathered table,
-  which reconstructs exactly the contiguous layout (absolute
-  positions, ``start=0``) with masked-out entries contributing exact
-  zeros — paged and contiguous decode are BITWISE equal on aligned
-  prompts (tests pin it);
+  writes a layer's new rows and attends over what the slot has seen:
+  in the step by the schedule's lowering (``ops/paged_attention.py``:
+  the Pallas kernel, or gather) over the slot's table; in a prefill
+  over this layer's pages of the history and the tail's own K/V as
+  they come from the projections, side by side in one softmax, and no
+  table is scattered into (``_PrefillView.attend``).  It is the
+  contiguous decoder's softmax over the same keys (absolute positions,
+  masked-out entries at exact zero weight) with the tail's keys
+  standing behind the table and not inside it: logits agree with it to
+  the last bits, not bitwise (tests state the tolerance and the gap
+  measured);
 - ``pages``: name -> ``(layers, row width, dtype)`` — page rows of the
   decoder's own, one ``(layers, P, block * width)`` array each, a page
   one row, so that gathering a table is a lookup of whole rows
@@ -299,10 +303,15 @@ class _PrefillView(_CacheView):
     cursor``, which the step masks to exact zero weight and which the
     decode write at each position replaces before the mask reaches it;
     only full blocks are promoted to the prefix index, so no shared
-    page ever holds one.  The state starts at zero and the state after
-    the last real token overwrites the slot's row.  ``slot``, ``hist``
-    and ``length`` ride as traced scalars, so the program count is one
-    per padded bucket length."""
+    page ever holds one.  The prefill never reads back what it wrote:
+    a K/V query attends over the tail's own rows and, of the pool, one
+    layer's pages of the history (:meth:`attend`); the all-layer table
+    a prefill scattered its rows into until ISSUE 30 was copied whole
+    twice a layer by the TPU compiler, as the pool had been.
+    The state starts at zero and the state after the last real token
+    overwrites the slot's row.  ``slot``, ``hist`` and ``length`` ride
+    as traced scalars, so the program count is one per padded bucket
+    length."""
     step = False
 
     def __init__(self, programs, cache, bt_row, slot, tokens, hist, length):
@@ -319,7 +328,7 @@ class _PrefillView(_CacheView):
             bt_row[jnp.clip(hist // programs.block + n, 0,
                             programs.max_blocks - 1)],
             programs.num_pages)
-        self._tables = None
+        self._hist = hist
 
     def state(self, name):
         import jax.numpy as jnp
@@ -349,32 +358,28 @@ class _PrefillView(_CacheView):
         return pool.at[self._page_ids, layer].set(vals, mode="drop")
 
     def attend(self, layer, q, k, v):
-        """``q, k, v`` ``(1, H, T, dh)``: the real tokens' rows go into
-        the slot's gathered table (history and tail in the contiguous
-        layout, every layer's gathered once) for the prefill's own
-        attention, and the tail's pages into the K/V pools.  Returns
-        ``(1, H, T, dh)``."""
+        """``q, k, v`` ``(1, H, T, dh)``: the tail's pages go into the
+        K/V pools, and the query attends over this layer's pages of the
+        slot, gathered from the pool and masked to the history
+        (positions ``< hist``), and behind them the tail's own ``k, v``
+        as they come from the projections (a causal mask is all a real
+        query needs: pad keys stand behind it): the two blocks side by
+        side on the key axis, one softmax.  Returns ``(1, H, T, dh)``."""
         import jax.numpy as jnp
 
         from ..ops import paged_attention as _pa
 
-        pg = self._pg
-        S = pg.max_blocks * pg.block
-        if self._tables is None:
-            self._tables = tuple(
-                _pa.gather_tables(pool, self._bt_row[None])
-                for pool in self.kv)                 # (L, 1, H, S, dh)
-            # pad tokens go out of bounds -> dropped writes
-            self._wpos = jnp.where(self.valid, self.positions, S)
-            self._seen = jnp.arange(S)[None, :] <= self.positions[:, None]
-        new = tuple(a[0].transpose(1, 0, 2) for a in (k, v))     # (T, H, dh)
-        kc, vc = self._tables = tuple(
-            table.at[layer, 0, :, self._wpos].set(rows)
-            for table, rows in zip(self._tables, new))
-        self.kv = tuple(self._write_pages(pool, rows, layer)
-                        for pool, rows in zip(self.kv, new))
-        return _pa.dense_attention(q, kc[layer], vc[layer],
-                                   self._seen[None, None])
+        self.kv = tuple(
+            self._write_pages(pool, a[0].transpose(1, 0, 2), layer)
+            for pool, a in zip(self.kv, (k, v)))
+        hk, hv = (_pa.layer_table(pool, self._bt_row[None], layer)
+                  for pool in self.kv)               # (1, H, S, dh)
+        S, T = hk.shape[2], q.shape[2]
+        seen = jnp.concatenate([
+            jnp.broadcast_to(jnp.arange(S) < self._hist, (T, S)),
+            jnp.arange(T)[None, :] <= jnp.arange(T)[:, None]], axis=1)
+        return _pa.dense_attention(q, jnp.concatenate([hk, k], axis=2),
+                                   jnp.concatenate([hv, v], axis=2), seen)
 
     def append(self, name, layer, rows):
         """``rows`` (T, W): the tail's page rows, written whole pages at

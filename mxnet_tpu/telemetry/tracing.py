@@ -134,13 +134,19 @@ def sample_rate() -> float:
         return 1.0
 
 
+# a busy engine writes ~270 spans a second (three a tick, three an
+# admission, at 26 ms a tick): the default holds a minute of them, so
+# that a reader of a 10 s window finds its first spans still there
+_SPAN_RING = 16384
+
+
 def span_ring_size() -> int:
     """``MXTPU_SPAN_RING`` — bounded span-buffer capacity (default
-    2048 spans; the oldest are overwritten)."""
+    16384 spans; the oldest are overwritten)."""
     try:
-        return max(int(os.environ.get("MXTPU_SPAN_RING", "2048")), 16)
+        return max(int(os.environ.get("MXTPU_SPAN_RING", _SPAN_RING)), 16)
     except ValueError:
-        return 2048
+        return _SPAN_RING
 
 
 def slo_ttft_ms() -> float:

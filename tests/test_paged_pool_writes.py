@@ -5,10 +5,19 @@ the prefill programs whole pages, and both take the pool's buffers over
 (``donate``).  What the chip's compiler makes of that is
 ``tests/test_tpu_compile.py``'s; here, on the CPU: the values and where
 they land are what the row scatter this replaced put there, paged
-decode is still bitwise the contiguous one, a shared page is never
-written, and a call that dies with the pool's buffers leaves a backend
-that serves again.
+decode is still the contiguous one, a shared page is never written,
+and a call that dies with the pool's buffers leaves a backend that
+serves again.
+
+A prefill attends over one layer's history pages and its tail's own
+K/V beside them (ISSUE 30), where the contiguous decoder attends over
+one table of ``max_len`` positions: the same softmax over the same
+keys, summed in another order, so logits agree to the last bits and not
+bitwise.  ``_TOL`` states what they are held to, with the
+widest gap measured here beside it.
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -28,12 +37,23 @@ BLOCK = 8
 BUCKETS = (8, 12, 16, 32)       # 12: a bucket that ends inside a page
 
 
-@pytest.fixture(scope="module")
-def decoder():
+# paged against contiguous, as a share of the largest magnitude compared
+# (logits, K/V rows): float32 read at most 1.9e-7 over 144 admissions
+# and their steps, about one ulp; bfloat16 read 0 and is given two ulps
+_TOL = {"float32": 2e-6, "bfloat16": 2e-2}
+
+
+def _close(want, got, dtype="float32"):
+    want, got = (np.asarray(a, np.float32) for a in (want, got))
+    return np.abs(want - got).max() <= _TOL[dtype] * np.abs(want).max()
+
+
+def _decoder(dtype, max_len=T):
     net = models.transformer.transformer_lm(
-        num_layers=L, num_heads=H, d_model=D, seq_len=T, vocab_size=V)
+        num_layers=L, num_heads=H, d_model=D, seq_len=max_len,
+        vocab_size=V)
     ex = net.simple_bind(ctx=mx.cpu(), grad_req="null",
-                         data=(1, T), softmax_label=(1, T))
+                         data=(1, max_len), softmax_label=(1, max_len))
     rs = np.random.RandomState(0)
     params = {}
     for name, arr in ex.arg_dict.items():
@@ -41,7 +61,13 @@ def decoder():
             continue
         arr[:] = rs.normal(0, 0.08, arr.shape).astype(np.float32)
         params[name] = arr
-    return KVDecoder(params, num_layers=L, num_heads=H, max_len=T)
+    return KVDecoder(params, num_layers=L, num_heads=H, max_len=max_len,
+                     dtype=dtype)
+
+
+@pytest.fixture(scope="module")
+def decoder():
+    return _decoder(jnp.float32)
 
 
 def _paged(decoder, slots=3, **kw):
@@ -127,23 +153,104 @@ def test_pool_holds_what_the_row_scatter_wrote(decoder):
 
 @pytest.mark.parametrize("plen", [8, 12, 16])
 def test_paged_vs_contiguous_bitwise_by_prompt_end(decoder, plen):
-    """Paged decode is bitwise the contiguous one on prompts that end
-    with a page (8, 16) and on one that ends inside a page (12, whose
-    bucket is no whole number of pages either): the rows the page-wise
-    prefill left beyond the prompt weigh exactly nothing."""
+    """Paged decode is the contiguous one on prompts that end with a
+    page (8, 16) and on one that ends inside a page (12, whose bucket
+    is no whole number of pages either): the rows the page-wise prefill
+    left beyond the prompt weigh exactly nothing.  Bitwise while the
+    prefill attended over one gathered ``max_len`` table (until ISSUE
+    30); now to ``_TOL``."""
     cont = _ContiguousSlots(decoder, 2, BUCKETS)
     pg = _paged(decoder, slots=2)
     prompt = np.random.RandomState(plen).randint(0, V, plen)
     lc = np.asarray(cont.admit(0, prompt), np.float32)
     lp = np.asarray(pg.admit(0, prompt), np.float32)
-    assert np.array_equal(lc, lp)
+    assert _close(lc, lp)
     tok = np.array([int(lc.argmax()), 0])
     occ = np.array([True, False])
     for _ in range(T - plen - 1):
         lc = np.asarray(cont.step(tok, occ)[0], np.float32)
         lp = np.asarray(pg.step(tok, occ)[0], np.float32)
-        assert np.array_equal(lc[0], lp[0])
+        assert _close(lc[0], lp[0])
         tok = np.array([int(lc[0].argmax()), 0])
+
+
+# ------------------------------------ a prefill behind a history (ISSUE 30)
+@pytest.fixture(scope="module")
+def backend():
+    """``backend(dtype, buckets, max_len)``: one decoder a dtype and
+    length and one paged backend a bucket list, shared by the cases
+    below (a program set is seconds of compile) and started anew for
+    each: every page free, an empty prefix index."""
+    made = {}
+
+    def get(dtype, buckets, max_len=T):
+        if (dtype, buckets, max_len) not in made:
+            if (dtype, max_len) not in made:
+                made[dtype, max_len] = _decoder(jnp.dtype(dtype), max_len)
+            made[dtype, buckets, max_len] = PagedSlots(
+                made[dtype, max_len], 3, block=BLOCK,
+                prefill_buckets=buckets, kernel="gather", prefix_cache=True)
+        made[dtype, buckets, max_len]._reset_pool()
+        return made[dtype, buckets, max_len]
+
+    return get
+
+
+def _admission_is_the_contiguous_one(pg, hist_pages, tail, dtype):
+    """Slot 1 leaves ``hist_pages`` shared pages behind; slot 0's
+    prompt finds them and prefills ``tail`` tokens: against the
+    contiguous decoder on the whole prompt, the first token's logits,
+    every live row of the pool, and five decode steps."""
+    hist = hist_pages * BLOCK
+    cont = _ContiguousSlots(pg.decoder, 2, (hist + tail,))
+    rs = np.random.RandomState(100 * hist_pages + tail)
+    shared = rs.randint(0, V, hist)
+    if hist:
+        pg.admit(1, np.concatenate([shared, rs.randint(0, V, 3)]))
+    prompt = np.concatenate([shared, rs.randint(0, V, tail)])
+    close = functools.partial(_close, dtype=dtype)
+
+    def window(n):          # (2, n, L, H, dh) of the contiguous cache
+        return np.stack([np.asarray(side, np.float32)[:, 0, :, :n]
+                         .transpose(2, 0, 1, 3) for side in cont.cache])
+
+    lc, lp = cont.admit(0, prompt), pg.admit(0, prompt)
+    assert list(pg.bt[0, :hist_pages]) == list(pg.bt[1, :hist_pages])
+    assert hist_pages == 0 or pg.bt[0, hist_pages] != pg.bt[1, hist_pages]
+    assert close(lc, lp)
+    assert close(window(hist + tail), _live_rows(pg, 0))
+    tok = np.array([int(np.asarray(lc).argmax()), 0, 0])
+    occ = np.array([True, False, False])
+    for _ in range(5):
+        lc, lp = cont.step(tok[:2], occ[:2])[0], pg.step(tok, occ)[0]
+        assert close(lc[0], lp[0])
+        tok[0] = int(np.asarray(lc[0]).argmax())
+    assert close(window(hist + tail + 5), _live_rows(pg, 0))
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("buckets", [BUCKETS, (T,)],
+                         ids=["bucket_fits", "bucket_overshoots"])
+@pytest.mark.parametrize("tail", [8, 5], ids=["ends_a_page", "inside_a_page"])
+@pytest.mark.parametrize("hist_pages", [0, 1, 2])
+def test_admission_behind_a_history_is_the_contiguous_one(
+        backend, hist_pages, tail, buckets, dtype):
+    """An admission that finds ``hist_pages`` pages of its prompt in
+    the prefix index (none, one, several) and prefills a tail that ends
+    with a page or inside one, in a bucket that fits or in ``max_len``'s
+    (which, behind a history, reaches past ``max_len - hist``: those
+    pad tokens' positions are clipped and their pages dropped)."""
+    _admission_is_the_contiguous_one(backend(dtype, buckets), hist_pages,
+                                     tail, dtype)
+
+
+@pytest.mark.parametrize("hist_pages,dtype", [
+    (16, "float32"), (17, "float32"), (17, "bfloat16"), (33, "float32")])
+def test_admission_behind_a_long_history(backend, hist_pages, dtype):
+    """Histories of 128 keys and more at ``max_len`` 288: the shared
+    pages outnumber the tail's by 16, 17 and 33 to one."""
+    _admission_is_the_contiguous_one(
+        backend(dtype, (16, 288), max_len=288), hist_pages, 5, dtype)
 
 
 def test_shared_page_is_never_written(decoder):

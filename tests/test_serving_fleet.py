@@ -1,6 +1,6 @@
 """Serving-fleet tests (ISSUE 15): the replica router (least-loaded
 balancing, idempotent retries, draining rolling upgrades, SIGKILL'd
-replica survival), the paged KV cache (bitwise parity vs contiguous,
+replica survival), the paged KV cache (last-bit parity vs contiguous,
 prefix reuse with fork isolation, pool accounting), and the graceful
 SIGTERM drain of tools/serve.py.
 """
@@ -495,10 +495,21 @@ def test_serve_sigterm_drains_then_exits(decoder):
 # ---------------------------------------------------------------------------
 # paged KV cache
 # ---------------------------------------------------------------------------
+# paged against contiguous logits, float32, absolute: a paged prefill's
+# keys are its history's pages and, beside them, its tail's own rows,
+# where the contiguous one holds both in one max_len table (ISSUE 30):
+# the same softmax summed in another order, so they agree to the last
+# bits and no longer bitwise; measured <= 3.0e-8 on logits of scale 0.16
+# (tests/test_paged_pool_writes.py holds bfloat16 and prefixes to it too)
+_PAGED_TOL = 1e-6
+
+
 def test_paged_vs_contiguous_bitwise(decoder):
-    """On a block-aligned prompt the paged gather reconstructs exactly
-    the contiguous layout: prefill logits and every step's logits are
-    BITWISE equal between the two backends."""
+    """On a block-aligned prompt the paged programs compute the
+    contiguous decoder's softmax over the same keys: prefill logits and
+    every step's logits agree to ``_PAGED_TOL`` between the two
+    backends (bitwise while the prefill attended over a gathered
+    ``max_len`` table), and the greedy tokens are the same."""
     buckets = (8, 16, 32)
     cont = _ContiguousSlots(decoder, 2, buckets)
     paged = PagedSlots(decoder, 2, block=8, prefill_buckets=buckets)
@@ -506,7 +517,8 @@ def test_paged_vs_contiguous_bitwise(decoder):
     prompt = rs.randint(0, V, 8).astype(np.int64)   # == bucket: start 0
     lc = np.asarray(cont.admit(0, prompt), np.float32)
     lp = np.asarray(paged.admit(0, prompt), np.float32)
-    assert np.array_equal(lc, lp), "prefill logits diverged bitwise"
+    assert np.abs(lc - lp).max() <= _PAGED_TOL, "prefill logits diverged"
+    assert lc.argmax() == lp.argmax()
     tok = np.array([int(lc.argmax()), 0])
     occ = np.array([True, False])
     for _ in range(6):
@@ -514,7 +526,9 @@ def test_paged_vs_contiguous_bitwise(decoder):
         slp, _m = paged.step(tok, occ)
         slc = np.asarray(slc, np.float32)
         slp = np.asarray(slp, np.float32)
-        assert np.array_equal(slc[0], slp[0]), "step logits diverged"
+        assert np.abs(slc[0] - slp[0]).max() <= _PAGED_TOL, \
+            "step logits diverged"
+        assert slc[0].argmax() == slp[0].argmax()
         tok = np.array([int(slc[0].argmax()), 0])
 
 
@@ -572,7 +586,7 @@ def test_prefix_reuse_and_fork_isolation(decoder, metrics):
     h0 = hits.total()
     la_c = np.asarray(cont.admit(0, fa), np.float32)
     la_p = np.asarray(pg.admit(0, fa), np.float32)
-    assert np.array_equal(la_c, la_p)      # aligned: bitwise regime
+    assert np.abs(la_c - la_p).max() <= _PAGED_TOL     # no prefix yet
     assert hits.total() - h0 == 0          # nothing cached yet
     # mutate fork A: 6 decode steps writing K/V past the shared block
     occ = np.array([True, False, False])
@@ -582,7 +596,7 @@ def test_prefix_reuse_and_fork_isolation(decoder, metrics):
         lp, _ = pg.step(tok, occ)
         lc = np.asarray(lc, np.float32)
         lp = np.asarray(lp, np.float32)
-        assert np.array_equal(lc[0], lp[0])
+        assert np.abs(lc[0] - lp[0]).max() <= _PAGED_TOL
         tok = np.array([int(lc[0].argmax()), 0, 0])
     # fork B admits: the paged side prefills ONLY its tail behind the
     # reused shared page; corruption would blow past tol by orders of
@@ -714,8 +728,9 @@ def test_prefix_chain_pinned_against_own_eviction(decoder, metrics):
 def test_paged_composes_with_int8(lm_params):
     """quantize='int8' weights decode through the paged programs too —
     the _DequantView dequantize-in-compute is backend-agnostic.  Parity
-    is pinned in the bitwise regime (block-aligned prompt, paged vs
-    contiguous scheduler over the SAME int8 decoder): comparing two
+    is pinned where the two agree to the last bits (block-aligned
+    prompt, paged vs contiguous scheduler over the SAME int8 decoder:
+    the same greedy tokens): comparing two
     structurally different programs on near-tie int8 logits would pin
     floating-point rounding, not the quantize/paging contract."""
     dec8 = KVDecoder(lm_params, num_layers=L, num_heads=H, max_len=T,

@@ -19,7 +19,10 @@ The paged serving programs are compiled whole (abstract weights, a few
 layers) for what the kernels alone cannot show: which layout the
 compiler gives the page pool between them.  Any new program that takes
 the pool is added to ``test_paged_program_keeps_pool_layout``
-(docs/serving.md, "The pool's layout is the kernel's").  The same
+(docs/serving.md, "The pool's layout is the kernel's").  What a prefill
+attends over is held the same way: no array of every layer's table, no
+copy above one layer's (``test_paged_prefill_builds_no_table``, with
+the gathered table it replaced as its control).  The same
 programs over a decoder that declares page rows of its own and a
 recurrent state a slot (``models/ling.py``) are compiled the same way
 at the benchmark's real sizes, where a copy of a state array would cost
@@ -39,7 +42,8 @@ from mxnet_tpu.models.decode import KVDecoder
 from mxnet_tpu.ops import flash_attention as fa
 from mxnet_tpu.ops import paged_attention as pa
 from mxnet_tpu.ops import residual_epilogue as repi
-from mxnet_tpu.serving.paged_kv import _CachePrograms, _StepView
+from mxnet_tpu.serving.paged_kv import (_CachePrograms, _PrefillView,
+                                        _StepView)
 
 
 @pytest.fixture(scope="module")
@@ -166,7 +170,8 @@ _POOL = _pool_shape(_LAYERS)
 _BUCKETS = (128, 256, 512, 1024)
 
 
-def _paged_programs(sds, step_view=_StepView, layers=_LAYERS):
+def _paged_programs(sds, step_view=_StepView, layers=_LAYERS,
+                    prefill_view=_PrefillView):
     """``serving/paged_kv.py``'s programs over a decoder that holds
     shapes for weights: nothing is allocated, everything lowers."""
     D, F, V = _HEADS * _DH, 512, 1024
@@ -191,7 +196,7 @@ def _paged_programs(sds, step_view=_StepView, layers=_LAYERS):
     progs = _CachePrograms(
         dec, dec.paged_layout(), _BLOCK, _MAX_LEN // _BLOCK, _PAGES, _SLOTS,
         schedule=pa.default_schedule("tpu", _BLOCK, _DH, "bfloat16"))
-    progs.step_view = step_view
+    progs.step_view, progs.prefill_view = step_view, prefill_view
     return progs
 
 
@@ -218,20 +223,31 @@ def _lower(progs, sds, which, B, M):
     return lowered, cache
 
 
-def _pool_copies(text, pool=_POOL):
-    """Instructions of the optimized HLO that copy the whole pool: a
-    ``copy`` (or its asynchronous start) or a copy fusion whose result
-    has the pool's shape."""
-    found = []
+def _instructions(text):
+    """``(name, result type, opcode)`` of each instruction of the
+    optimized HLO."""
     for line in text.splitlines():
         m = re.match(r"\s*(?:ROOT )?%(\S+) = (.*?) ([\w-]+)\(", line)
-        if m is None or pool not in m.group(2):
-            continue
-        name, op = m.group(1), m.group(3)
-        if op in ("copy", "copy-start") or (op == "fusion"
-                                            and "copy" in name):
-            found.append(name)
-    return found
+        if m is not None:
+            yield m.groups()
+
+
+def _is_copy(name, op):
+    """A ``copy``, its asynchronous start, or a copy fusion."""
+    return op in ("copy", "copy-start") or (op == "fusion"
+                                            and "copy" in name)
+
+
+def _copies_of(text, shapes):
+    """Instructions of the optimized HLO that copy an array of one of
+    ``shapes`` (``f32[128,32,128,128]``)."""
+    return [name for name, result, op in _instructions(text)
+            if _is_copy(name, op) and any(s in result for s in shapes)]
+
+
+def _pool_copies(text, pool=_POOL):
+    """Instructions of the optimized HLO that copy the whole pool."""
+    return _copies_of(text, [pool])
 
 
 @pytest.mark.parametrize("which", ("step",) + _BUCKETS)
@@ -286,6 +302,99 @@ def test_row_scatter_relayouts_the_pool(one_chip):
     assert len(_pool_copies(text)) == 2 + 2 * _LAYERS
 
 
+# ------------------------------------- what a paged prefill attends over
+# one layer's table of one slot, (H, max_len, dh) bf16: 4 MB.  Until
+# ISSUE 30 a prefill gathered every layer's for the slot, scattered each
+# layer's new rows into that array and read the layer back out of it:
+# the scatter wants one layout and the read another, so the compiler
+# copied the whole array over and back in every layer (100 MB each way
+# at serve_batch's 24 layers, 39% of the device's busy time)
+_LAYER_TABLE_BYTES = 2 * _HEADS * _MAX_LEN * _DH
+_ITEMSIZE = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s32": 4,
+             "u32": 4, "f32": 4}
+
+
+class GatheredTable(_PrefillView):
+    """The prefill's attention as it was: the real tokens' rows go into
+    the slot's gathered table, every layer's gathered once."""
+    _tables = None
+
+    def attend(self, layer, q, k, v):
+        S = self._pg.max_blocks * self._pg.block
+        if self._tables is None:
+            def gathered(pool):                  # (L, 1, H, S, dh)
+                t = pool[self._bt_row[None]].transpose(2, 0, 3, 1, 4, 5)
+                return t.reshape(t.shape[:3] + (S, t.shape[-1]))
+            self._tables = tuple(gathered(pool) for pool in self.kv)
+            self._wpos = jnp.where(self.valid, self.positions, S)
+            self._seen = jnp.arange(S)[None, :] <= self.positions[:, None]
+        new = tuple(a[0].transpose(1, 0, 2) for a in (k, v))
+        kc, vc = self._tables = tuple(
+            table.at[layer, 0, :, self._wpos].set(rows)
+            for table, rows in zip(self._tables, new))
+        self.kv = tuple(self._write_pages(pool, rows, layer)
+                        for pool, rows in zip(self.kv, new))
+        return pa.dense_attention(q, kc[layer], vc[layer],
+                                  self._seen[None, None])
+
+
+def _tables_and_copies(text, layers):
+    """Of the optimized HLO: the instructions whose result is an array
+    of every layer's table (``bf16[L,1,H,S,dh]`` / ``bf16[L,H,S,dh]``),
+    and the copies (``copy``, its asynchronous start, a copy fusion) of
+    an array larger than one layer's table."""
+    table = re.compile(r"bf16\[%d,(1,)?%d,%d,%d\]"
+                       % (layers, _HEADS, _MAX_LEN, _DH))
+    tables, copies = [], []
+    for name, result, op in _instructions(text):
+        m = re.match(r"(\w+)\[([\d,]*)\]", result)
+        if m is None:                        # a tuple, a token
+            continue
+        if table.match(result):
+            tables.append(name)
+        nbytes = functools.reduce(
+            lambda a, b: a * int(b), filter(None, m.group(2).split(",")),
+            _ITEMSIZE.get(m.group(1), 0))
+        if nbytes > _LAYER_TABLE_BYTES and _is_copy(name, op):
+            copies.append(name)
+    return tables, copies
+
+
+@pytest.mark.parametrize("layers,bucket",
+                         [(_LAYERS, b) for b in _BUCKETS] + [(24, 256)])
+def test_paged_prefill_builds_no_table(one_chip, layers, bucket):
+    """A prefill attends over one layer's pages of the slot and the
+    tail's own K/V beside them: in no bucket's program is there an
+    array of every layer's table, nor a copy of anything above one
+    layer's; no control flow (a branch or a loop a layer loads slower:
+    PERF.md, PR 30), temporaries no larger than the gathered table's
+    program had (15.6 MB at 3 layers in bucket 1024, 337 MB at 24 in
+    bucket 256), the pools in place as ever.  Once at serve_batch's 24
+    layers."""
+    compiled = _compile_paged(_paged_programs(one_chip, layers=layers),
+                              one_chip, bucket)
+    text = compiled.as_text()
+    assert _tables_and_copies(text, layers) == ([], [])
+    assert not re.search(r" (while|conditional)\(", text)
+    assert _pool_copies(text, _pool_shape(layers)) == []
+    mem = compiled.memory_analysis()
+    pool_bytes = 2 * _PAGES * layers * _HEADS * _BLOCK * _DH
+    assert mem.alias_size_in_bytes == 2 * pool_bytes
+    assert mem.temp_size_in_bytes < (15_610_368 if layers == _LAYERS
+                                     else 64 << 20)
+
+
+@pytest.mark.parametrize("bucket", [128, 1024])
+def test_gathered_table_is_copied_twice_a_layer(one_chip, bucket):
+    """The control: the gathered table that a prefill used to attend
+    over, compiled the same way, is there and is copied whole at least
+    twice a layer.  So the test above is known to see both."""
+    progs = _paged_programs(one_chip, prefill_view=GatheredTable)
+    tables, copies = _tables_and_copies(
+        _compile_paged(progs, one_chip, bucket).as_text(), _LAYERS)
+    assert tables and len(copies) >= 2 * _LAYERS
+
+
 # ------------------------------- declared cache: state beside the pages
 # serve_batch_ling as the benchmark runs it: the configuration's own
 # widths and layers, 128 slots of 2304 positions
@@ -331,22 +440,6 @@ def _compile_ling(progs, sds, which):
     lowered, cache = _lower(progs, sds, which, _LING_SLOTS,
                             _LING_MAX_LEN // _BLOCK)
     return lowered.compile(), cache
-
-
-def _copies_of(text, shapes):
-    """Instructions of the optimized HLO that copy an array of one of
-    ``shapes`` (``f32[128,32,128,128]``): a ``copy``, its asynchronous
-    start, or a copy fusion."""
-    found = []
-    for line in text.splitlines():
-        m = re.match(r"\s*(?:ROOT )?%(\S+) = (.*?) ([\w-]+)\(", line)
-        if m is None or not any(s in m.group(2) for s in shapes):
-            continue
-        name, op = m.group(1), m.group(3)
-        if op in ("copy", "copy-start") or (op == "fusion"
-                                            and "copy" in name):
-            found.append(name)
-    return found
 
 
 def _shape_text(s):
