@@ -56,8 +56,8 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
-from ..base import mxu_precision
 from ..parallel.moe import moe_serve
+from .blocks import lin as _lin, rms_norm, swiglu as _swiglu
 
 __all__ = ["LingConfig", "LingDecoder", "kda_recurrent_step", "kda_chunked",
            "mla_absorbed", "mla_expanded"]
@@ -130,24 +130,9 @@ class LingConfig:
 
 
 # ------------------------------------------------------------- pieces
-def rms_norm(x, w, eps):
-    x32 = x.astype(jnp.float32)
-    y = x32 * jax.lax.rsqrt(jnp.mean(jnp.square(x32), -1, keepdims=True)
-                            + eps)
-    return (y * w.astype(jnp.float32)).astype(x.dtype)
-
-
-def _lin(x, w):
-    return jnp.dot(x, w.T, precision=mxu_precision(x, w))
-
-
 def _l2(x):
     return x * jax.lax.rsqrt(jnp.sum(jnp.square(x), -1, keepdims=True)
                              + 1e-6)
-
-
-def _swiglu(x, gate, up, down):
-    return _lin(jax.nn.silu(_lin(x, gate)) * _lin(x, up), down)
 
 
 def _rope(x, pos, theta):

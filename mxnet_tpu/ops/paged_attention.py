@@ -8,11 +8,16 @@ it.  This module computes the same decode attention straight off the
 page pool, two lowerings behind one schedule-driven entry:
 
 - **pallas** — the TPU kernel: one program a slot (grid ``(B,)``), the
-  block table and the cursors ride as scalar prefetch.  The pool is
+  block table and the limits ride as scalar prefetch.  A K/V head
+  serves ``R`` query rows (``q`` ``(B, Hkv, R, dh)``): one for the
+  GPT-2 block, whose every query head has K/V of its own; (query
+  heads a K/V head) x (tokens of the slot's block) for a grouped-query
+  decoder that decodes a block a slot (``models/sdar.py``), all of
+  which see the same keys ``[0, limit]``.  The pool is
   ``(P, L, H, block, dh)`` row-major, so ``pool[pg, layer]`` is one
   contiguous ``(H, block, dh)`` run: a page comes in for ALL heads in
   one DMA.  The program walks its slot's live pages (those the cursor
-  has reached) a chunk at a time: every copy of a chunk is started
+  has reached: ``limit``) a chunk at a time: every copy of a chunk is started
   before any is waited for, the next chunk's copies — after a slot's
   last chunk, the next slot's first — fly while this chunk's scores,
   softmax and weighted sum are computed (two VMEM buffers), and a
@@ -118,9 +123,9 @@ def dense_attention(q, k, v, mask):
     return jnp.einsum("bhns,bhsd->bhnd", att, v)
 
 
-def _gather_attention(q, pool_k, pool_v, bt, cursor, layer):
+def _gather_attention(q, pool_k, pool_v, bt, limit, layer):
     S = bt.shape[1] * pool_k.shape[3]
-    valid = jnp.arange(S)[None, :] <= cursor[:, None]
+    valid = jnp.arange(S)[None, :] <= limit[:, None]
     return dense_attention(q, layer_table(pool_k, bt, layer),
                            layer_table(pool_v, bt, layer),
                            valid[:, None, None, :])
@@ -142,9 +147,9 @@ def chunk_pages(H: int, block: int, dh: int, dtype, M: int) -> int:
     return int(max(1, min(M, _MAX_CHUNK_PAGES, _VMEM_BUDGET // (4 * page))))
 
 
-def _pallas_attention(q, pool_k, pool_v, bt, cursor, layer, block,
+def _pallas_attention(q, pool_k, pool_v, bt, limit, layer, block,
                       interpret):
-    B, H, _n, dh = q.shape
+    B, H, R, dh = q.shape       # H: K/V heads; R query rows each
     M = bt.shape[1]
     C = chunk_pages(H, block, dh, q.dtype, M)
     Cb = C * block
@@ -155,7 +160,7 @@ def _pallas_attention(q, pool_k, pool_v, bt, cursor, layer, block,
         cur = cur_ref[b]
 
         def live_pages(slot):
-            # pages holding [0, cursor]: at least one, so every program
+            # pages holding [0, limit]: at least one, so every program
             # runs a chunk and the chain of prefetches never breaks
             return jnp.clip(cur_ref[slot] // block + 1, 1, M)
 
@@ -211,7 +216,7 @@ def _pallas_attention(q, pool_k, pool_v, bt, cursor, layer, block,
             start(0, 0, 0)
 
         first = first_ref[0]
-        qv = q_ref[0]                                    # (H, 1, dh)
+        qv = q_ref[0]                                    # (H, R, dh)
         # Mosaic only takes a matmul that accumulates in 32 bits, and a
         # bf16 one only at single-pass precision (the package default is
         # fp32 passes): both products and the softmax between them run
@@ -249,22 +254,22 @@ def _pallas_attention(q, pool_k, pool_v, bt, cursor, layer, block,
 
         _m, l, acc = jax.lax.fori_loop(
             0, n_chunks, body,
-            (jnp.full((H, 1, 1), NEG_INF, jnp.float32),
-             jnp.zeros((H, 1, 1), jnp.float32),
-             jnp.zeros((H, 1, dh), jnp.float32)))
+            (jnp.full((H, R, 1), NEG_INF, jnp.float32),
+             jnp.zeros((H, R, 1), jnp.float32),
+             jnp.zeros((H, R, dh), jnp.float32)))
         o_ref[0] = (acc / l).astype(o_ref.dtype)
         first_ref[0] = (first + n_chunks) % 2    # the buffer after my last
 
     qmap = lambda b, *_: (b, 0, 0, 0)
     gs = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,                   # bt, cursor
+        num_scalar_prefetch=2,                   # bt, limit
         grid=(B,),
         in_specs=[
-            pl.BlockSpec((1, H, 1, dh), qmap),
+            pl.BlockSpec((1, H, R, dh), qmap),
             pl.BlockSpec(memory_space=pl.ANY),   # pool_k stays in HBM
             pl.BlockSpec(memory_space=pl.ANY),   # pool_v stays in HBM
         ],
-        out_specs=pl.BlockSpec((1, H, 1, dh), qmap),
+        out_specs=pl.BlockSpec((1, H, R, dh), qmap),
         scratch_shapes=[
             pltpu.VMEM((2, H, Cb, dh), q.dtype),
             pltpu.VMEM((2, H, Cb, dh), q.dtype),
@@ -273,25 +278,28 @@ def _pallas_attention(q, pool_k, pool_v, bt, cursor, layer, block,
         ])
     return pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct((B, H, 1, dh), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, H, R, dh), q.dtype),
         grid_spec=gs,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),  # in order: see above
         interpret=interpret,
         name="paged_attn",
-    )(bt.astype(jnp.int32), cursor.astype(jnp.int32), q, pool_k, pool_v)
+    )(bt.astype(jnp.int32), limit.astype(jnp.int32), q, pool_k, pool_v)
 
 
 # ------------------------------------------------------------------ entry
-def paged_attention(q, pool_k, pool_v, bt, cursor, layer, *, block,
+def paged_attention(q, pool_k, pool_v, bt, limit, layer, *, block,
                     schedule=None, interpret=False):
     """Decode attention for one layer straight off the page pool.
 
-    ``q``: ``(B, H, 1, dh)``; ``pool_k``/``pool_v``: ``(P, L, H, block,
-    dh)``; ``bt``: ``(B, M)`` page ids; ``cursor``: ``(B,)`` absolute
-    positions (attend over ``[0, cursor[b]]``).  Returns ``(B, H, 1,
-    dh)``.  ``schedule`` picks the lowering (``None`` = gather); shapes
-    the Pallas gate rejects fall back to gather even when forced —
+    ``q``: ``(B, H, R, dh)``, ``R`` query rows a K/V head (1 for a
+    decoder of one token a slot whose query heads each have K/V of
+    their own); ``pool_k``/``pool_v``: ``(P, L, H, block, dh)``;
+    ``bt``: ``(B, M)`` page ids; ``limit``: ``(B,)`` absolute positions
+    (every row of slot ``b`` attends over ``[0, limit[b]]``: its
+    cursor, or the last position of the block it decodes).  Returns
+    ``(B, H, R, dh)``.  ``schedule`` picks the lowering (``None`` =
+    gather); shapes the Pallas gate rejects fall back to gather even when forced —
     ragged shapes never crash, they just take the reference path."""
     sched = schedule or {"impl": "gather"}
     impl = sched.get("impl", "gather")
@@ -303,8 +311,8 @@ def paged_attention(q, pool_k, pool_v, bt, cursor, layer, *, block,
         # says so
         interp = bool(interpret or sched.get("interpret", False))
         return _pallas_attention(
-            q, pool_k, pool_v, bt, cursor, layer, block, interp)
-    return _gather_attention(q, pool_k, pool_v, bt, cursor, layer)
+            q, pool_k, pool_v, bt, limit, layer, block, interp)
+    return _gather_attention(q, pool_k, pool_v, bt, limit, layer)
 
 
 # ------------------------------------------------------------- benchmark

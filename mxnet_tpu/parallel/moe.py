@@ -30,7 +30,8 @@ Two expert layers live here, and they differ in what they may lose:
 - :func:`moe_serve` -- the SERVING layer (``models/ling.py``): it is
   told which experts it holds (``expert_offset`` and the leading axis
   of the expert weights), routes over ALL of the router's experts in
-  float32 (:func:`route_group_limited`), and computes its own experts'
+  float32 (``route``: :func:`route_group_limited` over sigmoid scores,
+  or :func:`route_softmax_topk`), and computes its own experts'
   part of the result by a sort-and-segment grouped matmul
   (``lax.ragged_dot``) sized for the worst case.  It NEVER drops a
   token; what the experts held elsewhere would add is simply not in
@@ -48,7 +49,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from ..base import mxu_precision
 
 __all__ = ["init_moe_params", "moe_ffn", "route_group_limited",
-           "moe_serve"]
+           "route_softmax_topk", "moe_serve"]
 
 
 def init_moe_params(rng, d_model, d_hidden, n_experts, scale=0.02):
@@ -165,9 +166,18 @@ def route_group_limited(scores, bias, *, top_k, n_group, topk_group, scale):
     return idx.astype(jnp.int32), w
 
 
+def route_softmax_topk(logits, *, top_k):
+    """Qwen3-MoE style choice over router ``logits`` (N, E) float32:
+    softmax over ALL experts, the ``top_k`` most probable, their
+    probabilities normalised to sum 1 (``norm_topk_prob``).  Returns
+    ``(idx (N, top_k) int32, weights (N, top_k) float32)``."""
+    w, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+    return idx.astype(jnp.int32), w / jnp.sum(w, -1, keepdims=True)
+
+
 def moe_serve(x, router_w, router_b, w_gate, w_up, w_down, *,
-              expert_offset, top_k, n_group, topk_group, scale,
-              valid=None):
+              expert_offset, top_k, n_group=None, topk_group=None,
+              scale=None, valid=None, route=None):
     """This chip's share of a routed expert layer, dropless.
 
     ``x`` (N, D) tokens; ``router_w`` (E, D) and ``router_b`` (E,) over
@@ -179,8 +189,13 @@ def moe_serve(x, router_w, router_b, w_gate, w_up, w_down, *,
     token-expert pairs are sorted by expert (pairs on absent experts
     last), and one ``lax.ragged_dot`` a matrix runs every held expert
     over exactly its rows; the buffers hold all ``N * top_k`` pairs, so
-    nothing is ever dropped.  Named scopes ``moe.route`` and
-    ``moe.experts`` carry the two parts in a device trace.
+    nothing is ever dropped.  ``route`` maps the router's float32
+    logits (N, E) to ``(idx, weights)`` of ``top_k`` experts a token
+    (:func:`route_softmax_topk`); without it the choice is
+    :func:`route_group_limited` over their sigmoid with ``router_b``,
+    ``n_group``, ``topk_group`` and ``scale``.  Named scopes
+    ``moe.route`` and ``moe.experts`` carry the two parts in a device
+    trace.
 
     Returns ``(y (N, D), counts (3,) int32)``: ``y`` is the routed part
     of the held experts alone; ``counts`` = pairs that fell on held
@@ -189,12 +204,16 @@ def moe_serve(x, router_w, router_b, w_gate, w_up, w_down, *,
     N, D = x.shape
     held = w_gate.shape[0]
     with jax.named_scope("moe.route"):
-        scores = jax.nn.sigmoid(jnp.dot(
+        logits = jnp.dot(
             x.astype(jnp.float32), router_w.astype(jnp.float32).T,
-            precision=jax.lax.Precision.HIGHEST))
-        idx, wts = route_group_limited(
-            scores, router_b.astype(jnp.float32), top_k=top_k,
-            n_group=n_group, topk_group=topk_group, scale=scale)
+            precision=jax.lax.Precision.HIGHEST)
+        if route is None:
+            idx, wts = route_group_limited(
+                jax.nn.sigmoid(logits), router_b.astype(jnp.float32),
+                top_k=top_k, n_group=n_group, topk_group=topk_group,
+                scale=scale)
+        else:
+            idx, wts = route(logits)
         local = idx - expert_offset
         here = (local >= 0) & (local < held)
         # absent pairs carry the id ``held``: they sort last and belong

@@ -73,6 +73,21 @@ declare:
 Still one block table a slot, whatever is declared, and the whole cache
 is donated through every program.
 
+A decoder may decode A BLOCK OF ``n`` TOKENS A SLOT in one step
+(``decoder.block_length``; 1 where it says nothing, and then every
+program is what it was): the step takes ``(B, n)`` tokens at positions
+``cursor .. cursor + n - 1``, writes the block's ``n`` K/V rows a slot
+and layer as one ``dynamic_update_slice`` and lets every query of the
+block attend over ``[0, cursor + n)``, its own block included
+(``models/sdar.py``: a block under denoising).  A cursor then stands
+on a block boundary always -- an admission prefills the prompt's whole
+blocks, its remainder rides in the first decoded block -- and ``n``
+divides the page, so a block never straddles two pages.  Such a step
+moves no cursor by itself: the rows it wrote are provisional, the next
+step of the same block overwrites them, and only a slot the caller
+names in ``commit`` advances, by ``n`` (:meth:`PagedSlots.step`).  A
+prefill's tail mask is block-causal with the same ``n``.
+
 The K/V pools' layout is the kernel's.  Each is one ``(P, L, H, block,
 dh)`` array that the Mosaic kernel reads row-major (``{4,3,2,1,0}``).
 A program that writes it in a way the TPU compiler would rather lay out
@@ -210,18 +225,30 @@ class _CacheView:
 
 
 class _StepView(_CacheView):
-    """One token a slot at its cursor (page ``bt[b, cursor // block]``,
-    offset ``cursor % block``): state rows are the slots themselves; a
-    K/V row or a page row a slot is written at its cursor and the slot
-    attends over ``[0, cursor]`` of its table.  Free rows ride along
-    with ``bt[b] = 0`` / ``cursor = 0``: their writes land in the
-    scratch page the allocator never hands out."""
+    """``n`` tokens a slot from its cursor on (``n`` = the decoder's
+    ``block_length``, 1 for most; page ``bt[b, cursor // block]``,
+    offset ``cursor % block``): state rows are the slots themselves;
+    ``n`` K/V rows or a page row a slot are written at its cursor and
+    the slot attends over ``[0, cursor + n)`` of its table.  With ``n``
+    > 1 ``positions`` and ``valid`` are flat, ``(B n,)``, a slot's block
+    side by side.  Free rows ride along with ``bt[b] = 0`` / ``cursor =
+    0``: their writes land in the scratch page the allocator never
+    hands out."""
     step = True
 
     def __init__(self, programs, cache, bt, cursor, occupied):
         import jax.numpy as jnp
 
-        super().__init__(programs, cache, cursor, occupied)
+        n = programs.block_n
+        if n == 1:
+            super().__init__(programs, cache, cursor, occupied)
+            self._limit = cursor
+        else:
+            super().__init__(
+                programs, cache,
+                (cursor[:, None] + jnp.arange(n)).reshape(-1),
+                jnp.repeat(occupied, n))
+            self._limit = cursor + (n - 1)
         self._bt, self._cursor = bt, cursor
         # where each slot's row goes, as scalar pairs made once a
         # program: a step's trace holds ``2 L B`` row writes
@@ -237,15 +264,15 @@ class _StepView(_CacheView):
         self._state[name] = value.astype(self._state[name].dtype)
 
     def _write_rows(self, pool, new, layer):
-        """One K or V row a slot: ``new[b]`` ``(H, 1, dh)`` lands at
-        ``pool[page, layer, :, off]``, as one ``dynamic_update_slice`` a
-        slot (a scatter of rows, or a ``fori_loop`` over the slots, is
+        """The K or V rows of a slot: ``new[b]`` ``(H, n, dh)`` lands at
+        ``pool[page, layer, :, off:off + n]``, as one
+        ``dynamic_update_slice`` a slot (a scatter of rows, or a ``fori_loop`` over the slots, is
         laid out otherwise by the TPU compiler: module docstring).  The
         indices, never negative, skip the wrap-around ``lax`` would
         stage for each."""
         import jax
 
-        new = new[:, None]                           # (B, 1, H, 1, dh)
+        new = new[:, None]                           # (B, 1, H, n, dh)
         for b, (page, off) in enumerate(self._at):
             pool = jax.lax.dynamic_update_slice(
                 pool, jax.lax.slice_in_dim(new, b, b + 1),
@@ -253,18 +280,23 @@ class _StepView(_CacheView):
         return pool
 
     def attend(self, layer, q, k, v):
-        """``q, k, v`` ``(B, H, 1, dh)``: the new rows go into the K/V
-        pools, then the schedule's lowering (the Pallas kernel, or
+        """``k, v`` ``(B, H, n, dh)``, ``q`` ``(B, H, R, dh)`` (``R`` =
+        ``n`` x the query heads a K/V head): the new rows go into the
+        K/V pools, then the schedule's lowering (the Pallas kernel, or
         gather) walks each slot's block table over the pools just
-        written.  Returns ``(B, H, 1, dh)``."""
+        written, up to the block's last position, in the named scope
+        ``attn.pages``.  Returns ``(B, H, R, dh)``."""
+        import jax
+
         from ..ops import paged_attention as _pa
 
         pool_k, pool_v = self.kv = tuple(
             self._write_rows(pool, new, layer)
             for pool, new in zip(self.kv, (k, v)))
-        return _pa.paged_attention(
-            q, pool_k, pool_v, self._bt, self._cursor, layer,
-            block=self._pg.block, schedule=self._pg.schedule)
+        with jax.named_scope("attn.pages"):
+            return _pa.paged_attention(
+                q, pool_k, pool_v, self._bt, self._limit, layer,
+                block=self._pg.block, schedule=self._pg.schedule)
 
     def append(self, name, layer, rows):
         """``rows`` (B, W) land at each slot's cursor; returns the
@@ -358,13 +390,17 @@ class _PrefillView(_CacheView):
         return pool.at[self._page_ids, layer].set(vals, mode="drop")
 
     def attend(self, layer, q, k, v):
-        """``q, k, v`` ``(1, H, T, dh)``: the tail's pages go into the
-        K/V pools, and the query attends over this layer's pages of the
-        slot, gathered from the pool and masked to the history
-        (positions ``< hist``), and behind them the tail's own ``k, v``
-        as they come from the projections (a causal mask is all a real
-        query needs: pad keys stand behind it): the two blocks side by
-        side on the key axis, one softmax.  Returns ``(1, H, T, dh)``."""
+        """``k, v`` ``(1, H, T, dh)``, ``q`` ``(1, H, G T, dh)`` (``G``
+        query heads a K/V head, a head's ``T`` rows side by side; 1 for
+        most): the tail's pages go into the K/V pools, and the query
+        attends over this layer's pages of the slot, gathered from the
+        pool and masked to the history (positions ``< hist``), and
+        behind them the tail's own ``k, v`` as they come from the
+        projections (a causal mask is all a real query needs: pad keys
+        stand behind it; block-causal for a decoder of ``n`` tokens a
+        step, key ``j`` seen by query ``i`` iff ``j // n <= i // n``):
+        the two blocks side by side on the key axis, one softmax.
+        Returns ``q``'s shape."""
         import jax.numpy as jnp
 
         from ..ops import paged_attention as _pa
@@ -374,10 +410,16 @@ class _PrefillView(_CacheView):
             for pool, a in zip(self.kv, (k, v)))
         hk, hv = (_pa.layer_table(pool, self._bt_row[None], layer)
                   for pool in self.kv)               # (1, H, S, dh)
-        S, T = hk.shape[2], q.shape[2]
-        seen = jnp.concatenate([
-            jnp.broadcast_to(jnp.arange(S) < self._hist, (T, S)),
-            jnp.arange(T)[None, :] <= jnp.arange(T)[:, None]], axis=1)
+        S, T = hk.shape[2], k.shape[2]
+        history = jnp.broadcast_to(jnp.arange(S) < self._hist, (T, S))
+        if self._pg.block_n > 1:        # block-causal
+            at = jnp.arange(T) // self._pg.block_n
+            tail = at[None, :] <= at[:, None]
+        else:
+            tail = jnp.arange(T)[None, :] <= jnp.arange(T)[:, None]
+        seen = jnp.concatenate([history, tail], axis=1)
+        if q.shape[2] != T:
+            seen = jnp.tile(seen, (q.shape[2] // T, 1))
         return _pa.dense_attention(q, jnp.concatenate([hk, k], axis=2),
                                    jnp.concatenate([hv, v], axis=2), seen)
 
@@ -412,6 +454,8 @@ class _CachePrograms:
         from ..models.decode import _WeightProgram, _count_compiles
 
         self.dec, self.layout = decoder, layout
+        # tokens a slot a step (a block decoder's block; else 1)
+        self.block_n = int(getattr(decoder, "block_length", 1))
         self.block, self.max_blocks = int(block), int(max_blocks)
         self.num_pages, self.num_slots = int(num_pages), int(num_slots)
         # the K/V step's attention schedule (ops/paged_attention.py);
@@ -524,6 +568,13 @@ class PagedSlots:
         self.programs = _CachePrograms(
             decoder, layout, self.block, self.max_blocks,
             self.num_pages + 1, self.num_slots, schedule=self.schedule)
+        # tokens a slot a step: a block decoder's block, which a page
+        # holds whole (a block never straddles two pages)
+        self.block_n = self.programs.block_n
+        if self.block % self.block_n:
+            raise MXNetError(
+                f"KV block {self.block} must be a multiple of the "
+                f"decoder's block_length {self.block_n}")
         # a decoder says whether a prefix of its cache can be picked up
         # at a block boundary (per-slot state cannot)
         self.prefix_on = self.prefix_on and layout["prefix_reuse"]
@@ -716,8 +767,12 @@ class PagedSlots:
     # ------------------------------------------------------------ admission
     def admit(self, slot, prompt, trace=None):
         """Prefix lookup + page allocation + ONE bucketed tail prefill
-        writing straight into the pool; returns the next-token logits
-        row of the last prompt token.  ``trace``: the admitting
+        writing straight into the pool; returns what the decoder's
+        forward gives for a prefill (the next-token logits row of the
+        last prompt token).  For a block decoder only the prompt's
+        whole blocks are prefilled (the remainder is the caller's to
+        put into the first decoded block) and a prompt shorter than
+        one block runs no program and returns None.  ``trace``: the admitting
         request's trace id — kv_admit/kv_prefix_hit spans land under
         it, and prefix pages evicted to make room are attributed to it
         (ISSUE 16)."""
@@ -727,7 +782,8 @@ class PagedSlots:
 
         t_kv0 = time.perf_counter()
         prompt = np.asarray(prompt, np.int64)
-        p_len = int(prompt.size)
+        p_len = int(prompt.size) // self.block_n * self.block_n
+        prompt = prompt[:p_len]
         blk = self.block
         n_full = p_len // blk
         hashes = self._block_hashes(prompt, n_full) if self.prefix_on \
@@ -755,7 +811,10 @@ class PagedSlots:
             self._ref[pg] += 1
         self._trace_ctx = trace
         try:
-            owned = self._alloc((p_len + blk - 1) // blk - n_shared)
+            # at least one page: step() takes a slot that holds none
+            # as never admitted (a prompt shorter than a block)
+            owned = self._alloc(
+                max((p_len + blk - 1) // blk - n_shared, not p_len))
         except PoolExhausted:
             for pg in shared:
                 self._ref[pg] -= 1
@@ -768,6 +827,10 @@ class PagedSlots:
         self._slot_pages[slot] = list(row)
         if n_shared:
             _TM_PREFIX_HITS.inc(n_shared)
+        if not p_len:
+            self.cursor[slot] = 0
+            self._set_gauges()
+            return None
         bucket = next(b for b in self.prefill_buckets if b >= t)
         padded = np.zeros((1, bucket), np.int64)
         padded[0, :t] = tail
@@ -811,12 +874,16 @@ class PagedSlots:
         return logits
 
     # ----------------------------------------------------------------- tick
-    def step(self, tokens, occupied):
+    def step(self, tokens, occupied, commit=None):
         """One jitted step over the pool (the paged allocator tick —
         declared in analysis/config.py:ENTRY_POINTS).  Rows crossing a
         block boundary get their next page here; a row the pool cannot
         feed is reported in ``starved`` for the scheduler to deliver
-        truncated (its garbage write lands in the scratch page)."""
+        truncated (its garbage write lands in the scratch page).
+        ``tokens`` ``(B,)``, or ``(B, n)`` for a block decoder; the
+        cursors of the slots in ``commit`` (every occupied one where
+        None) advance by ``n``: what the others wrote stays
+        provisional, to be overwritten by their next step."""
         from ..models.decode import _snap
 
         starved = []
@@ -852,9 +919,9 @@ class PagedSlots:
             _tm.perf.attach_cost_analysis(
                 "decode_step_paged", self.programs._step_jit,
                 *self._lowering_args(), *args)
-        adv = occupied.copy()
+        adv = (occupied if commit is None else commit).copy()
         adv[starved] = False
-        self.cursor[adv] += 1
+        self.cursor[adv] += self.block_n
         if starved:
             self._set_gauges()
         return logits, starved
@@ -866,9 +933,10 @@ class PagedSlots:
         it is handed the pool's shape, never its buffers."""
         from ..models.decode import _snap
 
+        shape = (self.num_slots,) + (self.block_n,) * (self.block_n > 1)
         return self.programs._step_jit.lower(
             *self._lowering_args(), _snap(self.bt),
-            _snap(np.zeros(self.num_slots, np.int64)), _snap(self.cursor),
+            _snap(np.zeros(shape, np.int64)), _snap(self.cursor),
             _snap(np.zeros(self.num_slots), bool))
 
     def lower_prefill(self, bucket):

@@ -21,6 +21,26 @@ fetch that sampling needs anyway.  Per-request sampling params
 (temperature / top_k / seed) are host-side, so heterogeneous requests
 co-batch freely.
 
+A *block decoder* (``decoder.block_length`` = n > 1: ``models/sdar.py``,
+generation by diffusion over blocks) does not yield one token a slot a
+tick.  A slot holds a block under denoising -- ``n`` ids, some of them
+the mask id -- and a tick is a *forward* that hands the backend ``(B,
+n)`` ids and gets back, per row, the most probable token and its
+probability (reduced on the device; no logits come to the host).  Per
+slot a forward is either a DENOISE forward, which fixes the ``k``
+masked positions of highest probability by the request's schedule
+(``denoising_steps``: ``k = n // steps``, one more in the first ``n %
+steps`` forwards) and so yields 1..n tokens, or, once the block is
+whole and the request goes on, the COMMIT forward, which yields none:
+it writes the block's final K/V and moves the slot's cursor by ``n``
+(``PagedSlots.step(..., commit)``).  Slots in either phase share one
+forward.  A block's tokens are delivered, and stamped, when the block
+is whole; a request ends only there (its last block needs no commit),
+``eos`` is looked for there, and ``ttft`` is the first block's.  An
+admission prefills the prompt's whole blocks and samples nothing; the
+prompt's remainder rides, fixed, in the first block.  Greedy only: a
+temperature is refused at ``submit``.
+
 Backpressure is explicit: the admission queue is bounded
 (``MXTPU_SERVE_QUEUE``); a full queue raises
 :class:`AdmissionQueueFull`, which the HTTP layer maps to 429.
@@ -107,7 +127,8 @@ class Request:
 
     def __init__(self, prompt, max_new_tokens=16, temperature=0.0,
                  top_k=None, eos_id=None, deadline_ms=None, seed=0,
-                 arrival=None, trace=None, parent=None, sampled=False):
+                 arrival=None, trace=None, parent=None, sampled=False,
+                 denoising_steps=None):
         prompt = np.asarray(prompt)
         if prompt.ndim != 1 or prompt.size == 0:
             raise MXNetError(
@@ -132,6 +153,9 @@ class Request:
         seed = int(seed)
         if not 0 <= seed < 2 ** 32:
             raise MXNetError(f"seed must be in [0, 2**32), got {seed}")
+        if denoising_steps is not None and int(denoising_steps) < 1:
+            raise MXNetError(
+                f"denoising_steps must be >= 1, got {denoising_steps}")
         self.id = next(Request._ids)
         self.prompt = prompt.astype(np.int64)
         self.max_new_tokens = int(max_new_tokens)
@@ -156,6 +180,12 @@ class Request:
         # one time.monotonic() stamp per token, on arrival's clock: the
         # reply's token_ms and the terminal span's gaps_ms come from here
         self.token_times = []
+        # a block decoder's: forwards a block of this request takes to
+        # unmask (None: the decoder's default), and per token the
+        # ordinal of the forward of its block that fixed it
+        self.denoising_steps = (None if denoising_steps is None
+                                else int(denoising_steps))
+        self.unmask_step = None
         self.outcome = None   # ok | timeout | error | shutdown
         self.error = None
         self.ttft = None
@@ -291,6 +321,11 @@ class SlotScheduler:
         blk = kv_block if kv_block is not None else _paged_kv.kv_block()
         if paged is None:
             paged = blk > 0
+        # a block decoder's n (module docstring); 1: a token a tick
+        self._block_n = n = int(getattr(decoder, "block_length", 1))
+        if n > 1 and not paged:
+            raise MXNetError(
+                "a block decoder is served paged: give kv_block")
         if paged:
             self.backend = _paged_kv.PagedSlots(
                 decoder, self.num_slots, block=(blk or None),
@@ -300,6 +335,14 @@ class SlotScheduler:
         else:
             self.backend = _ContiguousSlots(
                 decoder, self.num_slots, self.prefill_buckets)
+        # the block each slot holds: its ids, which of them are fixed
+        # (all: it is whole and its next forward is its commit), the
+        # forward that fixed each (-1: the prompt's), and the ordinal
+        # of its next denoise forward
+        self._blk_ids = np.zeros((self.num_slots, n), np.int64)
+        self._blk_fixed = np.zeros((self.num_slots, n), bool)
+        self._blk_at = np.full((self.num_slots, n), -1, np.int64)
+        self._blk_step = np.zeros(self.num_slots, np.int64)
         self.slots = [None] * self.num_slots
         self._next_tok = np.zeros(self.num_slots, np.int64)
         self._slot_used = [False] * self.num_slots
@@ -309,9 +352,13 @@ class SlotScheduler:
         self._draining = False
         self._idle_wait = float(idle_wait)
         # rolled-up engine stats (bench + /healthz): mean slot occupancy
-        # = slot_ticks / ticks
+        # = slot_ticks / ticks.  A tick is a forward, a slot-tick an
+        # occupied slot in one; the last three move only under a block
+        # decoder: slot-forwards that were commits, positions unmasked,
+        # blocks whose cursor moved
         self.stats = {"ticks": 0, "slot_ticks": 0, "admitted": 0,
-                      "completed": 0}
+                      "completed": 0, "commit_forwards": 0,
+                      "tokens_unmasked": 0, "blocks_committed": 0}
         self._thread = threading.Thread(
             target=self._run, daemon=True,
             name="mxtpu-serve-engine-%d" % id(self))
@@ -325,6 +372,23 @@ class SlotScheduler:
         (prompt longer than the largest prefill bucket)."""
         kwargs.setdefault("deadline_ms", self.default_deadline_ms or None)
         req = Request(prompt, **kwargs)
+        if self._block_n > 1:
+            # the head hands over a top-1 token, not a distribution
+            if req.temperature > 0:
+                _TM_REQS.inc(outcome="rejected")
+                raise MXNetError(
+                    "this decoder's head returns its most probable token "
+                    "alone: temperature must be 0, got "
+                    f"{req.temperature}")
+            if req.denoising_steps is None:
+                req.denoising_steps = int(self.decoder.denoising_steps)
+            req.denoising_steps = min(req.denoising_steps, self._block_n)
+            req.unmask_step = []
+        elif req.denoising_steps is not None:
+            _TM_REQS.inc(outcome="rejected")
+            raise MXNetError(
+                "denoising_steps is a block decoder's; this decoder "
+                "yields one token a forward")
         vocab = getattr(self.decoder, "vocab", None)
         if req.top_k is not None and vocab and req.top_k > vocab:
             _TM_REQS.inc(outcome="rejected")
@@ -504,7 +568,10 @@ class SlotScheduler:
     def _admit_one(self, free, req):
         """One request's whole admission — prefill, first sample, cache
         write — as the span ``engine.admit``; it fails only this
-        request: the slot stays free and the engine moves on."""
+        request: the slot stays free and the engine moves on.  A block
+        decoder's admission samples nothing and fetches nothing: its
+        ``engine.prefill`` times the dispatch alone, and the first
+        forward waits for the prefill."""
         from .. import faults as _faults
 
         req.queue_wait = time.monotonic() - req.arrival
@@ -530,28 +597,31 @@ class SlotScheduler:
                     logits = self.backend.admit(
                         free, req.prompt,
                         trace=(req.trace if traced else None))
-                    logits = np.asarray(logits, np.float32)
-                first = self._sample(req, logits)
+                    if self._block_n == 1:
+                        logits = np.asarray(logits, np.float32)
+                if self._block_n == 1:
+                    first = self._sample(req, logits)
             except Exception as exc:  # noqa: BLE001
                 self.backend.release(free)
                 req.error = exc
                 self._terminal(req, "error")
             else:
                 admitted = True
-                self._next_tok[free] = first
                 if self._slot_used[free]:
                     _TM_REUSE.inc()
                 self._slot_used[free] = True
                 self.slots[free] = req
-                req.tokens.append(first)
-                now = time.monotonic()
-                req.token_times.append(now)
-                req.ttft = now - req.arrival
-                _TM_TTFT.observe(req.ttft)
-                _TM_TOKENS.inc()
                 self.stats["admitted"] += 1
                 _TM_OCCUPANCY.set(self.occupied)
-                self._maybe_finish(free, now)
+                if self._block_n > 1:
+                    # the prompt's remainder, fixed, opens the first block
+                    n = self._block_n
+                    self._new_block(
+                        free, req.prompt[req.prompt.size // n * n:])
+                else:
+                    self._next_tok[free] = first
+                    self._deliver(req, [first], time.monotonic())
+                    self._maybe_finish(free, req.token_times[-1])
         if traced and admitted and adm.t1 is not None:
             # the per-request records of a router-sampled request, from
             # the phases' own stamps (traced implies they were live)
@@ -578,8 +648,16 @@ class SlotScheduler:
             time.sleep(_tm.health._fault_slow_s())
         occupied = [i for i, r in enumerate(self.slots) if r is not None]
         n = self.stats["ticks"]
+        occ_mask = np.array([r is not None for r in self.slots])
+        block = self._block_n > 1
+        phases = {}
+        if block:
+            # slots whose block is whole commit it in this forward
+            commit = self._blk_fixed.all(axis=1) & occ_mask
+            phases = {"commit": int(commit.sum()),
+                      "denoise": len(occupied) - int(commit.sum())}
         with _tracing.phase("engine.tick", "engine", tick=n,
-                            occupied=len(occupied)) as tick:
+                            occupied=len(occupied), **phases) as tick:
             # each stamp is read once: from the phase when someone is
             # looking, else here (the tick histogram needs it always)
             t0 = tick.t0 or time.perf_counter()
@@ -592,12 +670,17 @@ class SlotScheduler:
             if _tracing.trace_on() and n % _tracing.TICK_EVERY == 0:
                 tick_reqs = [(i, self.slots[i]) for i in occupied
                              if self.slots[i].sampled]
-            occ_mask = np.array([r is not None for r in self.slots])
             with _tracing.phase("engine.step", "engine", tick=n) as step:
-                logits, starved = self.backend.step(self._next_tok,
-                                                    occ_mask)
-                # the ONE host sync/tick
-                logits = np.asarray(logits, np.float32)
+                # the ONE host sync/tick: the logits, or a block
+                # decoder's (token, probability) a row
+                if block:
+                    out, starved = self.backend.step(
+                        self._blk_ids, occ_mask, commit)
+                    toks, probs = (np.asarray(a) for a in out)
+                else:
+                    logits, starved = self.backend.step(self._next_tok,
+                                                        occ_mask)
+                    logits = np.asarray(logits, np.float32)
             t_fetch = step.t1 or time.perf_counter()
             with _tracing.phase("engine.sample", "engine",
                                 tick=n) as sample:
@@ -611,11 +694,13 @@ class SlotScheduler:
                         self._finish_slot(i, "ok")
                         continue
                     req = self.slots[i]
+                    if block:
+                        self._advance_block(i, toks[i], probs[i],
+                                            bool(commit[i]), now)
+                        continue
                     nxt = self._sample(req, logits[i])
-                    req.tokens.append(nxt)
-                    req.token_times.append(now)
                     self._next_tok[i] = nxt
-                    _TM_TOKENS.inc()
+                    self._deliver(req, [nxt], now)
                     self._maybe_finish(i, now)
                 self.stats["ticks"] += 1
                 self.stats["slot_ticks"] += len(occupied)
@@ -638,6 +723,75 @@ class SlotScheduler:
                     "decode_tick", "replica", req.trace, tick_dur,
                     parent=req.parent, slot=i, tick=n,
                     tokens=len(req.tokens), request=req.id)
+
+    @staticmethod
+    def _deliver(req, tokens, now):
+        """``tokens`` leave for the request, stamped ``now``; the first
+        ever is its TTFT."""
+        if not req.tokens:
+            req.ttft = now - req.arrival
+            _TM_TTFT.observe(req.ttft)
+        req.tokens.extend(tokens)
+        req.token_times.extend([now] * len(tokens))
+        _TM_TOKENS.inc(len(tokens))
+
+    def _new_block(self, slot, fixed=()):
+        """A fresh block for ``slot``: ``fixed`` ids (the prompt's
+        remainder) in front, the mask id behind."""
+        k = len(fixed)
+        self._blk_ids[slot, :k] = fixed
+        self._blk_ids[slot, k:] = self.decoder.mask_id
+        self._blk_fixed[slot] = np.arange(self._block_n) < k
+        self._blk_at[slot] = -1
+        self._blk_step[slot] = 0
+
+    def _advance_block(self, slot, toks, probs, committed, now):
+        """What one forward did to the block of ``slot``: ``toks``,
+        ``probs`` ``(n,)`` the most probable token and its probability
+        a position.  A commit forward moved the cursor (the backend's
+        doing): a fresh block.  A denoise forward fixes the request's
+        ``k`` most probable masked positions (ties to the lower
+        position); a block without a mask left is whole: its tokens are
+        delivered, and its next forward, if the request goes on, is its
+        commit."""
+        req = self.slots[slot]
+        if req.deadline is not None and now > req.deadline:
+            self._finish_slot(slot, "timeout")
+            return
+        if committed:
+            self.stats["commit_forwards"] += 1
+            self.stats["blocks_committed"] += 1
+            if self.backend.exhausted(slot):
+                # cache window exhausted: deliver what fits
+                self._finish_slot(slot, "ok")
+            else:
+                self._new_block(slot)
+            return
+        n, steps = self._block_n, req.denoising_steps
+        s = int(self._blk_step[slot])
+        masked = np.flatnonzero(~self._blk_fixed[slot])
+        k = n // steps + (1 if s < n % steps else 0)
+        now_fixed = masked[np.argsort(-probs[masked], kind="stable")[:k]]
+        self._blk_ids[slot, now_fixed] = toks[now_fixed]
+        self._blk_fixed[slot, now_fixed] = True
+        self._blk_at[slot, now_fixed] = s
+        self._blk_step[slot] = s + 1
+        self.stats["tokens_unmasked"] += len(now_fixed)
+        if len(now_fixed) < len(masked):
+            return
+        # whole: what is the request's of it (not the prompt's
+        # remainder, not beyond its budget, nothing behind an eos)
+        mine = self._blk_at[slot] >= 0
+        new = [int(t) for t in self._blk_ids[slot, mine]][
+            :req.max_new_tokens - len(req.tokens)]
+        ended = req.eos_id is not None and req.eos_id in new
+        if ended:
+            new = new[:new.index(req.eos_id) + 1]
+        req.unmask_step.extend(
+            int(a) for a in self._blk_at[slot, mine][:len(new)])
+        self._deliver(req, new, now)
+        if ended or len(req.tokens) >= req.max_new_tokens:
+            self._finish_slot(slot, "ok")
 
     def _maybe_finish(self, slot, now):
         req = self.slots[slot]

@@ -14,7 +14,9 @@ Endpoints:
     200: ``{"tokens": [...], "n_tokens": .., "outcome": "ok",
     "ttft_ms": .., "queue_wait_ms": .., "token_ms": [..]}``
     (``token_ms``: each token's time from receipt; ``token_ms[0] ==
-    ttft_ms``).  429 when the bounded
+    ttft_ms``).  A block decoder (docs/serving.md) also takes
+    ``"denoising_steps"`` and replies ``"unmask_step"`` (per token, the
+    forward of its block that fixed it).  429 when the bounded
     admission queue is full (body carries ``Retry-After`` guidance),
     504 when the deadline expires (partial ``tokens`` included), 400 on
     malformed input, 500 on an engine error.  A ``traceparent`` request
@@ -63,7 +65,7 @@ from .scheduler import (AdmissionQueueFull, SchedulerDraining,
 __all__ = ["start_server", "serve_decoder"]
 
 _GENERATE_FIELDS = {"prompt", "max_tokens", "temperature", "top_k",
-                    "eos_id", "deadline_ms", "seed"}
+                    "eos_id", "deadline_ms", "seed", "denoising_steps"}
 
 
 def _number(body, name, integral=False, lo=None, hi=None):
@@ -108,7 +110,8 @@ def _parse_generate(body):
             ("top_k", "top_k", True, 1, None),
             ("eos_id", "eos_id", True, 0, None),
             ("deadline_ms", "deadline_ms", True, 0, None),
-            ("seed", "seed", True, 0, 2 ** 32 - 1)):
+            ("seed", "seed", True, 0, 2 ** 32 - 1),
+            ("denoising_steps", "denoising_steps", True, 1, None)):
         v = _number(body, name, integral=integral, lo=lo, hi=hi)
         if v is not None:
             kwargs[dst] = v
@@ -132,6 +135,10 @@ def _request_json(req):
         "token_ms": [round((t - req.arrival) * 1000.0, 3)
                      for t in req.token_times],
     }
+    if req.unmask_step is not None:
+        # a block decoder's: per token, the ordinal of the forward of
+        # its block that fixed it
+        out["unmask_step"] = list(req.unmask_step)
     if req.trace is not None:
         out["trace"] = req.trace
     return out

@@ -108,12 +108,13 @@ def test_flash_bwd_compiles(one_chip, tile):
 
 
 # ------------------------------------------------------------------ paged
-def _compile_paged_kernel(sds, B, H, dh, block, M, dtype, L=2):
+def _compile_paged_kernel(sds, B, H, dh, block, M, dtype, L=2, R=1):
+    """``H`` K/V heads, ``R`` query rows each."""
     pool = sds((B * M + 1, L, H, block, dh), dtype)
     return _compile(
         lambda q, pk, pv, bt, cur: pa._pallas_attention(
             q, pk, pv, bt, cur, 1, block, False),
-        sds((B, H, 1, dh), dtype), pool, pool,
+        sds((B, H, R, dh), dtype), pool, pool,
         sds((B, M), "int32"), sds((B,), "int32"))
 
 
@@ -143,6 +144,14 @@ def test_paged_attention_compiles_other_callers(one_chip, B, H, block, M,
     """One algorithm, its chunk read from the shapes: the other
     callers' sizes compile by the same path."""
     _compile_paged_kernel(one_chip, B, H, 128, block, M, dtype)
+
+
+def test_paged_attention_compiles_for_a_block_of_grouped_queries(one_chip):
+    """serve_block_sdar's width: 64 slots, 4 K/V heads of 128 that each
+    serve 8 query heads x a block of 4 tokens = 32 rows, 16-token
+    pages, 152 pages a slot, bfloat16."""
+    _compile_paged_kernel(one_chip, 64, 4, 128, 16, 152, "bfloat16", L=7,
+                          R=32)
 
 
 def test_paged_gate_rejects_what_mosaic_rejects(one_chip):
@@ -485,6 +494,101 @@ def test_a_cache_that_is_not_donated_is_not_written_in_place(one_chip):
     compiled, _ = _compile_ling(_ling_programs(one_chip, donate=False),
                                 one_chip, "step")
     assert compiled.memory_analysis().alias_size_in_bytes == 0
+
+
+# ------------------------------------------- a block decoder's programs
+# serve_block_sdar: benchmark/configs/sdar-30b-a3b-chat-l7.json at its
+# published widths and 7 layers, 64 slots of 2432 positions
+_SDAR_SLOTS, _SDAR_MAX_LEN = 64, 2432
+_SDAR_PAGES = _SDAR_SLOTS * _SDAR_MAX_LEN // _BLOCK + 1
+_SDAR_POOL = "bf16[%d,7,4,%d,128]" % (_SDAR_PAGES, _BLOCK)
+
+
+def _sdar_programs(sds, step_view=_StepView):
+    """``_CachePrograms`` over an ``SdarDecoder`` that holds shapes for
+    weights, from the benchmark's configuration file."""
+    import json
+    import sys
+
+    from mxnet_tpu.models.sdar import SdarConfig, SdarDecoder
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmark.families import sdar as fam
+
+    with open(os.path.join(root, "benchmark", "configs",
+                           "sdar-30b-a3b-chat-l7.json")) as f:
+        config = json.load(f)
+    config.pop("rehearse")
+    dec = SdarDecoder.__new__(SdarDecoder)
+    dec.cfg = c = SdarConfig.from_dict(config)
+    dec.p = {k: sds(tuple(v["shape"]), "bfloat16")
+             for k, v in fam.param_specs(config).items()}
+    dec.max_len, dec.vocab = _SDAR_MAX_LEN, config["vocab_size"]
+    dec.block_length, dec.mask_id = c.block_length, c.mask_id
+    dec._cache_dtype = jnp.dtype("bfloat16")
+    progs = _CachePrograms(
+        dec, dec.paged_layout(), _BLOCK, _SDAR_MAX_LEN // _BLOCK,
+        _SDAR_PAGES, _SDAR_SLOTS,
+        schedule=pa.default_schedule("tpu", _BLOCK, c.head_dim, "bfloat16"))
+    progs.step_view = step_view
+    return progs
+
+
+def _lower_sdar(progs, sds, which):
+    B, M, n = _SDAR_SLOTS, _SDAR_MAX_LEN // _BLOCK, progs.block_n
+    cache = jax.tree_util.tree_map(lambda s: sds(s.shape, s.dtype),
+                                   progs.pool_structs())
+    counters = sds((3,), "int32")
+    if which == "step":
+        return progs._step_jit.lower(
+            *cache, counters, sds((B, M), "int32"), sds((B, n), "int32"),
+            sds((B,), "int32"), sds((B,), "bool"))
+    return progs.prefill(which).lower(
+        *cache, counters, sds((M,), "int32"), sds((1, which), "int32"),
+        sds((), "int32"), sds((), "int32"), sds((), "int32"))
+
+
+@pytest.mark.parametrize("which", ("step", 256, 2048))
+def test_block_decoder_program_keeps_pool_layout(one_chip, which):
+    """The SDAR step (a block of 4 tokens a slot, written as one
+    ``dynamic_update_slice`` a slot and layer) and its prefills at the
+    benchmark's sizes: no instruction copies a K/V pool (1.12 GB each),
+    both are written in place in the donated buffers in the kernel's
+    row-major layout, the step holds one paged-attention kernel a layer
+    (beside the TPU compiler's own grouped-matmul calls, four a layer),
+    and the program fits the chip beside its 12.2 GB of arguments."""
+    compiled = _lower_sdar(_sdar_programs(one_chip), one_chip,
+                           which).compile()
+    text = compiled.as_text()
+    assert _pool_copies(text, _SDAR_POOL) == []
+    layouts = set(re.findall(re.escape(_SDAR_POOL) + r"\{([\d,]+)", text))
+    assert layouts == {"4,3,2,1,0"}, layouts
+    kernels = len(re.findall(
+        r'custom-call\(.*custom_call_target="tpu_custom_call".*paged_attn',
+        text))
+    assert kernels == (7 if which == "step" else 0)
+    mem = compiled.memory_analysis()
+    pool_bytes = 2 * _SDAR_PAGES * 7 * 4 * _BLOCK * 128
+    assert mem.alias_size_in_bytes == 2 * pool_bytes
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.5e9
+
+
+def test_block_row_scatter_relayouts_the_pool(one_chip):
+    """The control: the block's rows written by a scatter and not by
+    one ``dynamic_update_slice`` a slot make the compiler copy the
+    whole pool, so the test above is known to see such copies."""
+    class RowScatter(_StepView):
+        def _write_rows(self, pool, new, layer):
+            pages, offs = (jnp.stack(x) for x in zip(*self._at))
+            rows = offs[:, None] + jnp.arange(new.shape[2])      # (B, n)
+            return pool.at[pages[:, None], layer, :, rows].set(
+                new.transpose(0, 2, 1, 3))
+
+    text = _lower_sdar(_sdar_programs(one_chip, RowScatter), one_chip,
+                       "step").compile().as_text()
+    assert len(_pool_copies(text, _SDAR_POOL)) >= 2
 
 
 # ------------------------------------------------------ residual epilogue
