@@ -314,7 +314,8 @@ class LingDecoder:
                 # what moe_block counts (parallel/moe.py:moe_serve)
                 "counters": ("expert_assignments_held",
                              "expert_assignments_absent",
-                             "expert_distinct_hits"),
+                             "expert_distinct_hits",
+                             "expert_kernel_calls"),
                 # a hit would also need the state at that block boundary
                 "prefix_reuse": not state}
 

@@ -138,7 +138,8 @@ class SdarDecoder:
                 # what the expert layers count (parallel/moe.py:moe_serve)
                 "counters": ("expert_assignments_held",
                              "expert_assignments_absent",
-                             "expert_distinct_hits"),
+                             "expert_distinct_hits",
+                             "expert_kernel_calls"),
                 # a page is whole blocks, and a block's K/V depend on
                 # nothing behind it
                 "prefix_reuse": True}

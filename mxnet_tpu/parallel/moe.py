@@ -33,10 +33,11 @@ Two expert layers live here, and they differ in what they may lose:
   float32 (``route``: :func:`route_group_limited` over sigmoid scores,
   or :func:`route_softmax_topk`), and computes its own experts'
   part of the result by a sort-and-segment grouped matmul
-  (``lax.ragged_dot``) sized for the worst case.  It NEVER drops a
-  token; what the experts held elsewhere would add is simply not in
-  its result.  On one chip it runs without the exchange that would sum
-  the shares.
+  (``ops/grouped_matmul.py``: a Pallas kernel for bf16 on a TPU,
+  ``lax.ragged_dot`` otherwise) sized for the worst case.  It NEVER
+  drops a token; what the experts held elsewhere would add is simply
+  not in its result.  On one chip it runs without the exchange that
+  would sum the shares.
 """
 from __future__ import annotations
 
@@ -46,7 +47,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..base import mxu_precision
+from ..ops import grouped_matmul as gmm
 
 __all__ = ["init_moe_params", "moe_ffn", "route_group_limited",
            "route_softmax_topk", "moe_serve"]
@@ -187,20 +188,22 @@ def moe_serve(x, router_w, router_b, w_gate, w_up, w_down, *,
     expert of a token can lie closer than bfloat16 resolves, and a
     flipped choice moves the result by a whole expert's part.  The
     token-expert pairs are sorted by expert (pairs on absent experts
-    last), and one ``lax.ragged_dot`` a matrix runs every held expert
-    over exactly its rows; the buffers hold all ``N * top_k`` pairs, so
-    nothing is ever dropped.  ``route`` maps the router's float32
-    logits (N, E) to ``(idx, weights)`` of ``top_k`` experts a token
-    (:func:`route_softmax_topk`); without it the choice is
-    :func:`route_group_limited` over their sigmoid with ``router_b``,
-    ``n_group``, ``topk_group`` and ``scale``.  Named scopes
-    ``moe.route`` and ``moe.experts`` carry the two parts in a device
-    trace.
+    last), and a grouped matmul (``ops/grouped_matmul.py``) runs every
+    held expert over exactly its rows; the buffers hold all ``N *
+    top_k`` pairs, so nothing is ever dropped.  ``route`` maps the
+    router's float32 logits (N, E) to ``(idx, weights)`` of ``top_k``
+    experts a token (:func:`route_softmax_topk`); without it the choice
+    is :func:`route_group_limited` over their sigmoid with
+    ``router_b``, ``n_group``, ``topk_group`` and ``scale``.  Named
+    scopes ``moe.route`` and ``moe.experts`` carry the two parts in a
+    device trace.
 
-    Returns ``(y (N, D), counts (3,) int32)``: ``y`` is the routed part
+    Returns ``(y (N, D), counts (4,) int32)``: ``y`` is the routed part
     of the held experts alone; ``counts`` = pairs that fell on held
     experts, pairs that fell on absent ones, distinct held experts hit
-    -- over the rows ``valid`` (N,) marks (all rows when None)."""
+    -- over the rows ``valid`` (N,) marks (all rows when None) -- and 1
+    if this call's products took the kernel, 0 for ``lax.ragged_dot``
+    (a constant of the traced program)."""
     N, D = x.shape
     held = w_gate.shape[0]
     with jax.named_scope("moe.route"):
@@ -229,12 +232,14 @@ def moe_serve(x, router_w, router_b, w_gate, w_up, w_down, *,
         order = jnp.argsort(flat, stable=True)
         sizes = jnp.zeros(held + 1, jnp.int32).at[flat].add(1)[:held]
         xs = jnp.take(x, order // top_k, axis=0)                 # (N k, D)
-        # one MXU pass for low-precision operands whatever the package's
-        # default says (the TPU's grouped matmul refuses bf16 operands
-        # at "float32" precision); float32 operands keep the default
-        grouped = partial(jax.lax.ragged_dot, group_sizes=sizes,
-                          precision=mxu_precision(xs, w_gate))
-        h = jax.nn.silu(grouped(xs, w_gate)) * grouped(xs, w_up)
+        # the kernel for bf16 on a TPU, ``lax.ragged_dot`` otherwise:
+        # decided here, at trace time, from shape, dtype and platform
+        schedule = gmm.default_schedule(
+            jax.default_backend(), N * top_k, D, w_gate.shape[2],
+            jnp.result_type(xs, w_gate, w_up, w_down), n_rhs=2)
+        grouped = partial(gmm.grouped_matmul, group_sizes=sizes,
+                          schedule=schedule)
+        h = grouped(xs, (w_gate, w_up))        # silu(xs gate) * (xs up)
         ys = grouped(h, w_down)                                  # (N k, D)
         # rows past the last group belong to absent experts: whatever
         # the grouped matmul left there is not part of the result
@@ -243,4 +248,5 @@ def moe_serve(x, router_w, router_b, w_gate, w_up, w_down, *,
                        ys.astype(jnp.float32) * w_sorted[:, None], 0.0)
         back = jnp.argsort(order)
         y = jnp.sum(jnp.take(ys, back, axis=0).reshape(N, top_k, D), 1)
-    return y.astype(x.dtype), counts
+    took = jnp.asarray([schedule["impl"] == "pallas"], jnp.int32)
+    return y.astype(x.dtype), jnp.concatenate([counts, took])

@@ -269,6 +269,35 @@ def test_four_shares_add_up_to_the_uncut_layer():
     assert np.max(np.abs(np.asarray(total - want))) < 2e-5 * scale
 
 
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_moe_serve_with_the_kernel_forced_matches_the_fallback(
+        monkeypatch, dtype):
+    """One chip's share as ``serve_batch_ling`` has it -- experts 8..15
+    of 32 held, so most pairs are absent and sort behind the last group,
+    and some held experts get no row -- through the Pallas grouped
+    matmul (interpreted) and through ``lax.ragged_dot``: the same rows,
+    the same three counters, and the fourth says which ran."""
+    E, held, lo, N, D, F, k = 32, 8, 8, 24, 128, 256, 4
+    rng = np.random.default_rng(5)
+    mk = lambda *s: jnp.asarray(
+        0.2 * rng.standard_normal(s).astype(np.float32), jnp.dtype(dtype))
+    args = (mk(N, D) * 5, mk(E, D), mk(E), mk(held, D, F), mk(held, D, F),
+            mk(held, F, D))
+    kw = dict(expert_offset=lo, top_k=k, n_group=4, topk_group=2,
+              scale=2.5, valid=jnp.arange(N) < 20)
+    want, counts = moe.moe_serve(*args, **kw)
+    assert int(counts[3]) == 0 and 0 < int(counts[1]) and int(counts[0]) > 0
+    monkeypatch.setattr(
+        moe.gmm, "default_schedule",
+        lambda *a, **k: {"impl": "pallas", "interpret": True})
+    got, forced = moe.moe_serve(*args, **kw)
+    assert list(np.asarray(forced)) == list(np.asarray(counts[:3])) + [1]
+    tol = 1e-5 if dtype == "float32" else 2e-2
+    scale = float(jnp.max(jnp.abs(want.astype(jnp.float32))))
+    assert np.max(np.abs(np.asarray(got, np.float32)
+                         - np.asarray(want, np.float32))) < tol * scale
+
+
 # --------------------------------------------------------- (e) the router
 def brute_force_choice(scores, bias, n_group, topk_group, top_k):
     E = scores.shape[0]

@@ -344,6 +344,35 @@ def test_softmax_top_k_routing_against_a_brute_force_pick():
     assert list(np.asarray(counts)[:2]) == [160, 0]
 
 
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_moe_serve_with_the_kernel_forced_matches_the_fallback(
+        monkeypatch, dtype):
+    """The SDAR expert layer -- every expert held, softmax top-k, no
+    absent pair -- through the Pallas grouped matmul (interpreted) and
+    through ``lax.ragged_dot``: the same rows and counters, and the
+    fourth counter says which ran."""
+    import functools
+    E, N, D, F, k = 16, 48, 128, 256, 4
+    rng = np.random.default_rng(6)
+    mk = lambda *s: jnp.asarray(
+        0.2 * rng.standard_normal(s).astype(np.float32), jnp.dtype(dtype))
+    args = (mk(N, D) * 5, mk(E, D), None, mk(E, D, F), mk(E, D, F),
+            mk(E, F, D))
+    kw = dict(expert_offset=0, top_k=k,
+              route=functools.partial(moe.route_softmax_topk, top_k=k))
+    want, counts = moe.moe_serve(*args, **kw)
+    assert list(np.asarray(counts))[:2] + [int(counts[3])] == [N * k, 0, 0]
+    monkeypatch.setattr(
+        moe.gmm, "default_schedule",
+        lambda *a, **k: {"impl": "pallas", "interpret": True})
+    got, forced = moe.moe_serve(*args, **kw)
+    assert list(np.asarray(forced)) == list(np.asarray(counts[:3])) + [1]
+    tol = 1e-5 if dtype == "float32" else 2e-2
+    scale = float(jnp.max(jnp.abs(want.astype(jnp.float32))))
+    assert np.max(np.abs(np.asarray(got, np.float32)
+                         - np.asarray(want, np.float32))) < tol * scale
+
+
 @pytest.mark.parametrize("dtype,block", [("float32", 8), ("float32", 16),
                                          ("bfloat16", 16)])
 def test_kernel_matches_gather_for_a_block_of_grouped_queries(dtype, block):

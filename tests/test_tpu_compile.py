@@ -40,6 +40,7 @@ from jax.sharding import SingleDeviceSharding
 
 from mxnet_tpu.models.decode import KVDecoder
 from mxnet_tpu.ops import flash_attention as fa
+from mxnet_tpu.ops import grouped_matmul as gmm
 from mxnet_tpu.ops import paged_attention as pa
 from mxnet_tpu.ops import residual_epilogue as repi
 from mxnet_tpu.serving.paged_kv import (_CachePrograms, _PrefillView,
@@ -540,7 +541,7 @@ def _lower_sdar(progs, sds, which):
     B, M, n = _SDAR_SLOTS, _SDAR_MAX_LEN // _BLOCK, progs.block_n
     cache = jax.tree_util.tree_map(lambda s: sds(s.shape, s.dtype),
                                    progs.pool_structs())
-    counters = sds((3,), "int32")
+    counters = sds((len(progs.layout["counters"]),), "int32")
     if which == "step":
         return progs._step_jit.lower(
             *cache, counters, sds((B, M), "int32"), sds((B, n), "int32"),
@@ -589,6 +590,72 @@ def test_block_row_scatter_relayouts_the_pool(one_chip):
     text = _lower_sdar(_sdar_programs(one_chip, RowScatter), one_chip,
                        "step").compile().as_text()
     assert len(_pool_copies(text, _SDAR_POOL)) >= 2
+
+
+# ------------------------------------------ the experts' grouped matmul
+# (rows, k, n, stacks): the step's two calls in serve_block_sdar and in
+# serve_batch_ling, and the largest prefill buffer (bucket 2048, 8 pairs
+# a token)
+_GMM = [(2048, 2048, 768, 2), (2048, 768, 2048, 1),
+        (1024, 2560, 768, 2), (1024, 768, 2560, 1),
+        (16384, 2560, 768, 2), (16384, 768, 2560, 1)]
+
+
+@pytest.mark.parametrize("rows,k,n,stacks", _GMM)
+def test_grouped_matmul_compiles(one_chip, rows, k, n, stacks):
+    """128 experts' matrices of 3.1-3.9 MB, two of each stack in VMEM:
+    more than the 16 MiB a kernel gets unasked."""
+    assert gmm.supports(rows, k, n, "bfloat16", stacks)
+    text = _compile(
+        lambda x, sizes, *w: gmm.grouped_matmul(
+            x, w, sizes, schedule={"impl": "pallas"}),
+        one_chip((rows, k), "bfloat16"), one_chip((128,), "int32"),
+        *[one_chip((128, k, n), "bfloat16")] * stacks)
+    assert "grouped_matmul" in text and "ragged-dot" not in text
+
+
+_STACKS = ["bf16[128,%d,%d]" % s for s in
+           ((2048, 768), (768, 2048), (2560, 768), (768, 2560))]
+
+
+@pytest.mark.parametrize("lowering", ["kernel", "ragged"])
+@pytest.mark.parametrize("family", ["sdar", "ling"])
+def test_step_holds_the_grouped_matmul_kernel(one_chip, monkeypatch, family,
+                                              lowering):
+    """The SDAR and Ling step programs at the benchmark's sizes with
+    ``moe_serve`` told it lowers for a TPU (under a described topology
+    ``jax.default_backend()`` says cpu): two calls of this repo's kernel
+    a MoE layer (gate and up together, down), none of the compiler's
+    ``ragged-dot`` kernels, and no copy of a 403 / 503 MB weight stack
+    (a layout the kernel and the program disagreed on would cost one).
+    The control is the same program as it lowers off the TPU: the
+    compiler's kernels and none of ours, so each check can fail."""
+    if lowering == "kernel":
+        on_cpu = gmm.default_schedule
+        monkeypatch.setattr(gmm, "default_schedule",
+                            lambda platform, *a, **k: on_cpu("tpu", *a, **k))
+    if family == "sdar":
+        compiled = _lower_sdar(_sdar_programs(one_chip), one_chip,
+                               "step").compile()
+        moe_layers, others = 7, 7               # a paged_attn a layer
+    else:
+        compiled, _ = _compile_ling(_ling_programs(one_chip), one_chip,
+                                    "step")
+        moe_layers, others = 6, 0
+    text = compiled.as_text()
+    ours = len(re.findall(
+        r'custom-call\(.*custom_call_target="tpu_custom_call".*'
+        r'grouped_matmul', text))
+    mosaic = text.count('custom_call_target="tpu_custom_call"')
+    assert _copies_of(text, _STACKS) == []
+    if lowering == "kernel":
+        assert ours == 2 * moe_layers and mosaic == ours + others
+        assert "ragged-dot" not in text
+    else:
+        assert ours == 0 and mosaic == 4 * moe_layers + others
+        assert "ragged-dot" in text
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.5e9
 
 
 # ------------------------------------------------------ residual epilogue
