@@ -56,16 +56,14 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
-from ..parallel.moe import moe_serve
-from .blocks import lin as _lin, rms_norm, swiglu as _swiglu
+from .blocks import lin as _lin, moe_block, rms_norm, swiglu as _swiglu
+from .mla import mla_absorbed, mla_expanded, rope as _rope, rope_freq
 
 __all__ = ["LingConfig", "LingDecoder", "kda_recurrent_step", "kda_chunked",
            "mla_absorbed", "mla_expanded"]
 
 HIGHEST = jax.lax.Precision.HIGHEST
-NEG_INF = -1e30
 KDA_CHUNK = 16      # |g| <= 5 a token: exp(+-40) stays well inside float32
-MLA_QUERY_BLOCK = 512
 
 
 @dataclasses.dataclass(frozen=True)
@@ -133,19 +131,6 @@ class LingConfig:
 def _l2(x):
     return x * jax.lax.rsqrt(jnp.sum(jnp.square(x), -1, keepdims=True)
                              + 1e-6)
-
-
-def _rope(x, pos, theta):
-    """Rotary positions on the last axis of ``x`` (N, ..., d), float32;
-    interleaved pairs, ``pos`` (N,) absolute positions."""
-    d = x.shape[-1]
-    freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    ang = pos.astype(jnp.float32)[:, None] * freq
-    shape = (x.shape[0],) + (1,) * (x.ndim - 2) + (d // 2,)
-    cos, sin = jnp.cos(ang).reshape(shape), jnp.sin(ang).reshape(shape)
-    a, b = x[..., 0::2], x[..., 1::2]
-    return jnp.stack([a * cos - b * sin, a * sin + b * cos],
-                     -1).reshape(x.shape)
 
 
 # ---------------------------------------------------------------- KDA
@@ -217,51 +202,6 @@ def kda_chunked(q, k, v, g, beta, S0, chunk=KDA_CHUNK):
 
     S, O = jax.lax.scan(body, S0, (w_v, w_k, q_in, B, k_end, g_end))
     return O.transpose(0, 2, 1, 3).reshape(T, H, dv), S
-
-
-# ---------------------------------------------------------------- MLA
-def mla_absorbed(q_nope, q_rope, table, valid, w_kvb, c):
-    """Decode form: ``q_nope`` (B, H, 128), ``q_rope`` (B, H, 64) turned
-    already; ``table`` (B, S, 576) the rows ``[latent | rotary key]`` of
-    each slot and ``valid`` (B, S) which of them exist.  ``W_kvb`` is
-    absorbed into the query and into the output, so attention runs over
-    the latent rows themselves.  Returns (B, H * 128)."""
-    H = c.heads
-    wb = w_kvb.reshape(H, c.nope + c.v_dim, c.kv_rank)
-    lat, k_rope = table[..., :c.kv_rank], table[..., c.kv_rank:]
-    q_lat = jnp.einsum("bhd,hdr->bhr", q_nope, wb[:, :c.nope])
-    s = (jnp.einsum("bhr,bsr->bhs", q_lat, lat,
-                    preferred_element_type=jnp.float32)
-         + jnp.einsum("bhd,bsd->bhs", q_rope, k_rope,
-                      preferred_element_type=jnp.float32)) \
-        / jnp.sqrt(jnp.float32(c.nope + c.rope))
-    p = jax.nn.softmax(jnp.where(valid[:, None, :], s, NEG_INF), axis=-1)
-    o_lat = jnp.einsum("bhs,bsr->bhr", p.astype(lat.dtype), lat)
-    o = jnp.einsum("bhr,hdr->bhd", o_lat, wb[:, c.nope:])
-    return o.reshape(o.shape[0], H * c.v_dim)
-
-
-def mla_expanded(q_nope, q_rope, rows, w_kvb, c, block=MLA_QUERY_BLOCK):
-    """Prefill form over one sequence from position 0: ``q_nope`` (T, H,
-    128), ``q_rope`` (T, H, 64), ``rows`` (T, 576).  Keys and values are
-    expanded from the latent rows; causal, a block of queries at a time
-    over the keys up to its end.  Returns (T, H * 128)."""
-    T, H = q_nope.shape[0], c.heads
-    lat, k_rope = rows[:, :c.kv_rank], rows[:, c.kv_rank:]
-    kv = _lin(lat, w_kvb).reshape(T, H, c.nope + c.v_dim)
-    k_nope, v = kv[..., :c.nope], kv[..., c.nope:]
-    out = []
-    for lo in range(0, T, block):
-        hi = min(T, lo + block)
-        s = (jnp.einsum("thd,shd->hts", q_nope[lo:hi], k_nope[:hi],
-                        preferred_element_type=jnp.float32)
-             + jnp.einsum("thd,sd->hts", q_rope[lo:hi], k_rope[:hi],
-                          preferred_element_type=jnp.float32)) \
-            / jnp.sqrt(jnp.float32(c.nope + c.rope))
-        causal = jnp.arange(lo, hi)[:, None] >= jnp.arange(hi)[None, :]
-        p = jax.nn.softmax(jnp.where(causal[None], s, NEG_INF), axis=-1)
-        out.append(jnp.einsum("hts,shd->thd", p.astype(v.dtype), v[:hi]))
-    return jnp.concatenate(out).reshape(T, H * c.v_dim)
 
 
 # ------------------------------------------------------------ decoder
@@ -377,11 +317,12 @@ class LingDecoder:
         q = _lin(x, p[pre + "q_weight"]).reshape(N, H, c.nope + c.rope)
         q_nope = q[..., :c.nope]
         q_rope = _rope(q[..., c.nope:].astype(jnp.float32), view.positions,
-                       c.rope_theta).astype(x.dtype)
+                       rope_freq(c.rope_theta, c.rope)).astype(x.dtype)
         kva = _lin(x, p[pre + "kva_weight"])
         lat = rms_norm(kva[:, :c.kv_rank], p[pre + "kv_norm_weight"], c.eps)
-        k_rope = _rope(kva[:, c.kv_rank:].astype(jnp.float32),
-                       view.positions, c.rope_theta).astype(x.dtype)
+        k_rope = _rope(
+            kva[:, c.kv_rank:].astype(jnp.float32), view.positions,
+            rope_freq(c.rope_theta, c.rope)).astype(x.dtype)
         rows = jnp.concatenate([lat, k_rope], -1)              # (N, 576)
         written = view.append("latent", page_layer, rows)
         if view.step:
@@ -391,22 +332,6 @@ class LingDecoder:
         else:
             o = mla_expanded(q_nope, q_rope, rows, p[pre + "kvb_weight"], c)
         return _lin(o, p[pre + "o_weight"])
-
-    def moe_block(self, p, i, x, view):
-        c = self.cfg
-        pre = f"layer{i}_"
-        y, counts = moe_serve(
-            x, p[pre + "router_weight"], p[pre + "router_bias"],
-            p[pre + "experts_gate_weight"], p[pre + "experts_up_weight"],
-            p[pre + "experts_down_weight"], expert_offset=c.expert_offset,
-            top_k=c.top_k, n_group=c.n_group, topk_group=c.topk_group,
-            scale=c.scale, valid=view.valid)
-        view.count(counts)
-        with jax.named_scope("moe.shared"):
-            shared = _swiglu(x, p[pre + "shared_gate_weight"],
-                             p[pre + "shared_up_weight"],
-                             p[pre + "shared_down_weight"])
-        return y + shared
 
     # ------------------------------------------------------ the forward
     def forward(self, p, tokens, view):
@@ -433,7 +358,7 @@ class LingDecoder:
                             p[f"layer{i}_mlp_up_weight"],
                             p[f"layer{i}_mlp_down_weight"])
                 else:
-                    h = h + self.moe_block(p, i, x, view)
+                    h = h + moe_block(p, f"layer{i}_", x, view, c)
         if not view.step:
             h = jax.lax.dynamic_slice_in_dim(h, view.length - 1, 1)
         with jax.named_scope("head"):

@@ -26,7 +26,7 @@ HOST-side bookkeeping (``PagedSlots.step`` is a declared
 ``analysis/config.py:ENTRY_POINTS`` steady-state loop — lint proves it
 never touches the device); the device work stays the serving invariant:
 one jitted step over all slots per tick, one bucketed prefill per
-admission, zero traces on a warm server
+admission (or per chunk of a long one), zero traces on a warm server
 (``executor_compile_total{kind=decode_step_paged|decode_prefill_paged}``).
 
 What a page holds, and what lives beside the pages, is the decoder's
@@ -55,8 +55,11 @@ declare:
 - ``pages``: name -> ``(layers, row width, dtype)`` — page rows of the
   decoder's own, one ``(layers, P, block * width)`` array each, a page
   one row, so that gathering a table is a lookup of whole rows
-  (``models/ling.py``: one latent row a token and MLA layer;
-  ``view.append``);
+  (``models/ling.py``, ``models/kimi.py``: one latent row a token and
+  MLA layer).  ``view.append`` writes a layer's new rows and hands back
+  what the queries attend over beside them: in the step every slot's
+  gathered table, in a prefill this layer's rows of the slot and
+  ``hist``, how many of them stand before the tail;
 - ``state``: name -> ``(shape, dtype)`` — a FIXED-SIZE STATE PER SLOT
   beside the pages, one ``(num_slots, *shape)`` array each (a
   linear-attention layer's recurrent state; ``view.state`` /
@@ -65,10 +68,22 @@ declare:
   predecessor;
 - ``counters``: names of int32 device counters the forward adds to
   (``view.count``), reported by ``stats()`` under those names;
-- ``prefix_reuse``: whether a cached prefix can be picked up at a block
-  boundary.  It would also need the state there, which nothing
-  snapshots: a decoder with state says ``False`` and no page of it is
-  ever shared (``stats()["prefix_reuse"]``).
+- ``prefix_reuse``: whether a prefill's tail may stand behind rows the
+  slot's pages already hold -- a cached prefix picked up at a block
+  boundary, or the earlier chunks of the same prompt.  It would also
+  need the state there, which nothing snapshots: a decoder with state
+  says ``False``, no page of it is ever shared
+  (``stats()["prefix_reuse"]``) and its prompts are one program each.
+  This flag is all that is asked: no family and no kind of page is
+  named here.
+
+An admission is :meth:`PagedSlots.begin_admit` (prefix lookup, page
+allocation) and one or more :meth:`PagedSlots.admit_chunk` (a prefill
+program each): a tail that fits the largest bucket is one program, a
+longer one goes in chunks of that bucket's whole pages, each behind
+``hist`` = the cached prefix and the chunks before it, so that whoever
+drives the backend may run steps between two chunks
+(``serving/scheduler.py`` does; :meth:`PagedSlots.admit` does not).
 
 Still one block table a slot, whatever is declared, and the whole cache
 is donated through every program.
@@ -425,16 +440,24 @@ class _PrefillView(_CacheView):
 
     def append(self, name, layer, rows):
         """``rows`` (T, W): the tail's page rows, written whole pages at
-        a time like the K/V pools'."""
+        a time like the K/V pools'.  Returns what stands before the
+        tail: ``(table (S, W), hist)``, this layer's rows of the slot
+        gathered from the pool (whole pages, as the step gathers them)
+        and how many of them, from position 0 on, are the history; the
+        rest of the table is not the caller's to read.  ``None`` for a
+        layout that can have no history (``prefix_reuse`` False)."""
         import jax.numpy as jnp
 
         pg = self._pg
         pool = self.pages[name]
         T, W = rows.shape
         rows = jnp.pad(rows.astype(pool.dtype), ((0, -T % pg.block), (0, 0)))
-        self.pages[name] = pool.at[layer, self._page_ids].set(
+        self.pages[name] = pool = pool.at[layer, self._page_ids].set(
             rows.reshape(-1, pg.block * W), mode="drop")
-        return None
+        if not pg.layout["prefix_reuse"]:
+            return None
+        table = jnp.take(pool[layer], self._bt_row, axis=0)
+        return table.reshape(pg.max_blocks * pg.block, W), self._hist
 
 
 class _CachePrograms:
@@ -516,6 +539,21 @@ class _CachePrograms:
         return self._prefill_cache[bucket]
 
 
+class _Admission:
+    """A prompt on its way into a slot: what
+    :meth:`PagedSlots.begin_admit` decided (``row``: the slot's pages,
+    shared then owned, ``bt_row`` the same as a block-table row;
+    ``hist`` tokens of it a cached prefix; ``tail`` the tokens to
+    prefill; ``chunked``: it takes more than one program) and how many
+    of the tail's tokens the chunks so far have put in (``done``)."""
+    __slots__ = ("slot", "trace", "t0", "p_len", "hashes", "n_shared",
+                 "hist", "tail", "done", "row", "bt_row", "chunked")
+
+    @property
+    def pending(self):
+        return self.done < self.tail.size
+
+
 class PagedSlots:
     """Paged scheduler backend: the device pool + pure-host page
     bookkeeping (block tables, refcounts, prefix index).
@@ -578,6 +616,15 @@ class PagedSlots:
         # a decoder says whether a prefix of its cache can be picked up
         # at a block boundary (per-slot state cannot)
         self.prefix_on = self.prefix_on and layout["prefix_reuse"]
+        # the same flag says a prefill's tail may stand behind rows the
+        # slot's pages already hold: a tail over the largest bucket then
+        # goes in chunks of that bucket's whole pages (0: it cannot)
+        self._bucket_max = max(self.prefill_buckets, default=0)
+        self._chunk = self._bucket_max // self.block * self.block \
+            if layout["prefix_reuse"] else 0
+        # host-side counts since start, beside the decoder's own
+        self._host_counts = {"prefill_chunks": 0, "prompt_tokens": 0,
+                             "prefix_tokens_hit": 0}
         # the device counters the decoder's programs add to, by the
         # names it declared, and what stats() has read of them so far
         # (host side, never wraps)
@@ -712,6 +759,9 @@ class PagedSlots:
             out["state_slots_in_use"] = self._slots_in_use()
         if layout["pages"]:
             out["latent_pages_in_use"] = self.num_pages - len(self._free)
+        # prefill programs run, tokens of admitted prompts, and how many
+        # of those came from shared pages
+        out.update(self._host_counts)
         for name, n in zip(self._counter_names, self._read_counters()):
             out[name] = int(n)
             _TM_COUNTED.set(int(n), name=name)
@@ -762,46 +812,55 @@ class PagedSlots:
 
     @property
     def max_prompt(self):
-        return self.decoder.max_len
+        """The longest prompt an admission takes: the cache window where
+        the tail may go in chunks, else the largest prefill bucket."""
+        return self.decoder.max_len if self._chunk else self._bucket_max
 
     # ------------------------------------------------------------ admission
-    def admit(self, slot, prompt, trace=None):
-        """Prefix lookup + page allocation + ONE bucketed tail prefill
-        writing straight into the pool; returns what the decoder's
-        forward gives for a prefill (the next-token logits row of the
-        last prompt token).  For a block decoder only the prompt's
-        whole blocks are prefilled (the remainder is the caller's to
-        put into the first decoded block) and a prompt shorter than
-        one block runs no program and returns None.  ``trace``: the admitting
-        request's trace id — kv_admit/kv_prefix_hit spans land under
-        it, and prefix pages evicted to make room are attributed to it
-        (ISSUE 16)."""
-        import jax.numpy as jnp
-
-        from ..models.decode import _snap
-
-        t_kv0 = time.perf_counter()
+    def begin_admit(self, slot, prompt, trace=None):
+        """Prefix lookup + page allocation for ``prompt`` into ``slot``;
+        no program runs.  Returns the :class:`_Admission` that
+        :meth:`admit_chunk` carries forward.  The slot's block table and
+        cursor stay free (zero) until the last chunk is in: a step that
+        runs between two chunks writes the free row's garbage into the
+        scratch page, not into this prompt's first page.  For a block
+        decoder only the prompt's whole blocks are prefilled (the
+        remainder is the caller's to put into the first decoded block).
+        ``trace``: the admitting request's trace id -- kv_admit /
+        kv_prefix_hit spans land under it, and prefix pages evicted to
+        make room are attributed to it (ISSUE 16)."""
+        adm = _Admission()
+        adm.slot, adm.trace, adm.t0 = slot, trace, time.perf_counter()
         prompt = np.asarray(prompt, np.int64)
         p_len = int(prompt.size) // self.block_n * self.block_n
         prompt = prompt[:p_len]
         blk = self.block
-        n_full = p_len // blk
-        hashes = self._block_hashes(prompt, n_full) if self.prefix_on \
-            else []
+        adm.p_len = p_len
+        # chain hashes of the prompt's full blocks (none: no index)
+        adm.hashes = self._block_hashes(prompt, p_len // blk) \
+            if self.prefix_on else []
         shared = []
         # reuse the longest cached chain, capped so >=1 tail token is
         # always prefilled (its logits seed the first sampled token) and
         # the cursor page stays fork-private
         for i in range((p_len - 1) // blk):
-            pg = self._prefix.get(hashes[i]) if i < len(hashes) else None
+            pg = self._prefix.get(adm.hashes[i]) \
+                if i < len(adm.hashes) else None
             if pg is None:
                 break
             shared.append(pg)
-            self._prefix.move_to_end(hashes[i])
-        n_shared = len(shared)
-        hist = n_shared * blk
-        tail = prompt[hist:]
-        t = int(tail.size)
+            self._prefix.move_to_end(adm.hashes[i])
+        adm.n_shared = n_shared = len(shared)
+        adm.hist = n_shared * blk
+        adm.tail = prompt[adm.hist:]
+        adm.done = 0
+        adm.chunked = adm.tail.size > self._bucket_max
+        if adm.chunked and not self._chunk:
+            raise MXNetError(
+                f"a tail of {adm.tail.size} tokens exceeds the largest "
+                f"prefill bucket {self._bucket_max}, and this decoder's "
+                "prefill takes no history (per-slot state): it cannot go "
+                "in chunks")
         # pin the matched chain BEFORE allocating: _alloc evicts ref==1
         # prefix pages, which would otherwise include this request's own
         # shared chain under pool pressure — the evicted page would come
@@ -821,27 +880,49 @@ class PagedSlots:
             raise
         finally:
             self._trace_ctx = None
-        row = shared + owned
-        self.bt[slot, :len(row)] = row
-        self.bt[slot, len(row):] = 0
-        self._slot_pages[slot] = list(row)
+        adm.row = shared + owned
+        adm.bt_row = np.zeros(self.max_blocks, np.int32)
+        adm.bt_row[:len(adm.row)] = adm.row
+        self._slot_pages[slot] = list(adm.row)
+        self._host_counts["prompt_tokens"] += p_len
+        self._host_counts["prefix_tokens_hit"] += adm.hist
         if n_shared:
             _TM_PREFIX_HITS.inc(n_shared)
         if not p_len:
-            self.cursor[slot] = 0
-            self._set_gauges()
-            return None
-        bucket = next(b for b in self.prefill_buckets if b >= t)
+            self._finish_admit(adm, None)
+        return adm
+
+    def next_chunk(self, adm):
+        """``(tokens, bucket)`` of the program :meth:`admit_chunk` runs
+        next."""
+        t = int(adm.tail.size) - adm.done
+        if t > self._bucket_max:
+            t = self._chunk
+        return t, next(b for b in self.prefill_buckets if b >= t)
+
+    def admit_chunk(self, adm):
+        """The next prefill program of ``adm``: up to the largest
+        bucket's worth of the tail (whole pages of it, so that the next
+        chunk starts on a page boundary), in the smallest bucket that
+        holds it, behind ``hist`` = the shared prefix and the chunks
+        that went before.  Returns what the decoder's forward gives for
+        a prefill (the next-token logits row of the chunk's last
+        token), without waiting for it; after the last chunk
+        (``adm.pending`` false) the slot is admitted."""
+        import jax.numpy as jnp
+
+        from ..models.decode import _snap
+
+        t, bucket = self.next_chunk(adm)
         padded = np.zeros((1, bucket), np.int64)
-        padded[0, :t] = tail
-        # _snap: self.bt is mutated in place by later admits/steps while
-        # this dispatch may still be executing — never alias it.  The
+        padded[0, :t] = adm.tail[adm.done:adm.done + t]
+        # _snap: never alias a host array into an async dispatch.  The
         # slot goes in as a host value: a program that does not read it
         # (jit prunes unused arguments) then costs no transfer, where
         # each explicit one before the launch costs 0.3-0.45 ms on the
         # chip (PERF.md section 6, PR 29)
-        args = (_snap(self.bt[slot]), jnp.asarray(padded), np.int32(slot),
-                jnp.int32(hist), jnp.int32(t))
+        args = (_snap(adm.bt_row), jnp.asarray(padded), np.int32(adm.slot),
+                jnp.int32(adm.hist + adm.done), jnp.int32(t))
         logits = self._run(self.programs.prefill(bucket), *args)
         if bucket not in self._cost_prefill_done and _tm.perf.enabled():
             self._cost_prefill_done.add(bucket)
@@ -849,28 +930,50 @@ class PagedSlots:
                 f"decode_prefill_paged[b{bucket}]",
                 self.programs.prefill(bucket),
                 *self._lowering_args(), *args)
-        self.cursor[slot] = p_len
+        adm.done += t
+        self._host_counts["prefill_chunks"] += 1
+        if not adm.pending:
+            self._finish_admit(adm, bucket)
+        return logits
+
+    def _finish_admit(self, adm, bucket):
+        """The prompt is in: the slot gets its block table and cursor,
+        the prompt's full blocks go to the prefix index."""
+        slot, row = adm.slot, adm.row
+        self.bt[slot] = adm.bt_row
+        self.cursor[slot] = adm.p_len
         # promote this prompt's full blocks: they are never written
         # again (writes happen at cursor >= p_len), so they are safe to
         # share with every later identical prefix
-        if self.prefix_on:
-            for i in range(n_full):
-                if hashes[i] not in self._prefix:
-                    pg = row[i]
-                    self._prefix[hashes[i]] = pg
-                    self._page_hash[pg] = hashes[i]
-                    self._ref[pg] += 1
+        for pg, digest in zip(row, adm.hashes):
+            if digest not in self._prefix:
+                self._prefix[digest] = pg
+                self._page_hash[pg] = digest
+                self._ref[pg] += 1
         self._set_gauges()
-        if trace is not None and _tracing.trace_on():
-            if n_shared:
+        if bucket is not None and adm.trace is not None \
+                and _tracing.trace_on():
+            if adm.n_shared:
                 _tracing.record_span(
-                    "kv_prefix_hit", "replica", trace, 0.0,
-                    blocks=n_shared, tokens=hist)
+                    "kv_prefix_hit", "replica", adm.trace, 0.0,
+                    blocks=adm.n_shared, tokens=adm.hist)
             _tracing.record_span(
-                "kv_admit", "replica", trace,
-                time.perf_counter() - t_kv0, slot=slot,
-                pages_shared=n_shared, pages_owned=len(owned),
+                "kv_admit", "replica", adm.trace,
+                time.perf_counter() - adm.t0, slot=slot,
+                pages_shared=adm.n_shared,
+                pages_owned=len(row) - adm.n_shared,
                 bucket=bucket)
+
+    def admit(self, slot, prompt, trace=None):
+        """A whole admission at once: :meth:`begin_admit`, then every
+        chunk back to back (one program where the tail fits the largest
+        bucket).  Returns the last chunk's logits; None for a block
+        decoder's prompt shorter than one block, which runs no
+        program."""
+        adm = self.begin_admit(slot, prompt, trace)
+        logits = None
+        while adm.pending:
+            logits = self.admit_chunk(adm)
         return logits
 
     # ----------------------------------------------------------------- tick

@@ -203,6 +203,16 @@ class Request:
         return self._event.is_set()
 
 
+class _WholePrompt:
+    """The contiguous pool's record of an admission (the paged pool's
+    is :class:`~mxnet_tpu.serving.paged_kv._Admission`): one program,
+    ``pending`` until it has run, never in chunks."""
+    chunked = False
+
+    def __init__(self, slot, prompt):
+        self.slot, self.prompt, self.pending = slot, prompt, True
+
+
 class _ContiguousSlots:
     """The PR-6 contiguous slot pool behind the backend interface the
     scheduler drives: one ``(L, slots, H, max_len, dh)`` cache pair,
@@ -223,12 +233,23 @@ class _ContiguousSlots:
     def stats(self):
         return None
 
-    def admit(self, slot, prompt, trace=None):
+    @property
+    def max_prompt(self):
+        return self.prefill_buckets[-1]
+
+    def begin_admit(self, slot, prompt, trace=None):
+        """The first half of an admission, as the paged pool has it:
+        here nothing is looked up or allocated, and the one program of
+        :meth:`admit_chunk` is the whole of it.  ``trace`` is accepted
+        for parity with the paged pool (which records kv_admit/kv_evict
+        spans); the contiguous pool has no per-admit KV events to
+        attribute."""
+        return _WholePrompt(slot, np.asarray(prompt))
+
+    def admit_chunk(self, adm):
         """Bucketed left-padded prefill + one traced-slot cache write;
-        returns the next-token logits row of the last prompt token.
-        ``trace`` is accepted for backend-interface parity with the
-        paged pool (which records kv_admit/kv_evict spans); the
-        contiguous pool has no per-admit KV events to attribute."""
+        returns the next-token logits row of the last prompt token."""
+        slot, prompt = adm.slot, adm.prompt
         plen = int(prompt.size)
         bucket = next(b for b in self.prefill_buckets if b >= plen)
         padded = np.zeros((1, bucket), np.int64)
@@ -237,7 +258,12 @@ class _ContiguousSlots:
         self.cache = self.decoder.adopt_row(self.cache, row, slot)
         self.start[slot] = bucket - plen
         self.cursor[slot] = bucket
+        adm.pending = False
         return logits[0, -1]
+
+    def admit(self, slot, prompt, trace=None):
+        """A whole admission at once."""
+        return self.admit_chunk(self.begin_admit(slot, prompt, trace))
 
     def step(self, tokens, occupied):
         """ONE jitted decode step over the whole pool; advances the
@@ -347,6 +373,9 @@ class SlotScheduler:
         self._next_tok = np.zeros(self.num_slots, np.int64)
         self._slot_used = [False] * self.num_slots
         self._queue = deque()
+        # (slot, request, the backend's admission) of a prompt that goes
+        # in chunks and is not whole yet; the engine thread's alone
+        self._admitting = None
         self._cond = threading.Condition()
         self._stop = False
         self._draining = False
@@ -369,7 +398,7 @@ class SlotScheduler:
         """Enqueue a generation request; returns the :class:`Request`.
         Raises :class:`AdmissionQueueFull` when the bounded queue is full
         and :class:`MXNetError` for requests that can never be served
-        (prompt longer than the largest prefill bucket)."""
+        (prompt longer than ``backend.max_prompt``)."""
         kwargs.setdefault("deadline_ms", self.default_deadline_ms or None)
         req = Request(prompt, **kwargs)
         if self._block_n > 1:
@@ -394,11 +423,13 @@ class SlotScheduler:
             _TM_REQS.inc(outcome="rejected")
             raise MXNetError(
                 f"top_k {req.top_k} exceeds the vocab size {vocab}")
-        if req.prompt.size > self.prefill_buckets[-1]:
+        if req.prompt.size > self.backend.max_prompt:
             _TM_REQS.inc(outcome="rejected")
             raise MXNetError(
-                f"prompt length {req.prompt.size} exceeds the largest "
-                f"prefill bucket {self.prefill_buckets[-1]}")
+                f"prompt length {req.prompt.size} exceeds what an "
+                f"admission takes, {self.backend.max_prompt} (the largest "
+                "prefill bucket, or the cache window where a prompt may "
+                "go in chunks)")
         with self._cond:
             if self._stop:
                 raise MXNetError("scheduler is shut down")
@@ -452,6 +483,7 @@ class SlotScheduler:
         work left — safe to restart."""
         with self._cond:
             return (self._draining and not self._queue
+                    and self._admitting is None
                     and all(r is None for r in self.slots))
 
     @property
@@ -499,6 +531,8 @@ class SlotScheduler:
         for req in self.slots:
             if req is not None:
                 self._terminal(req, "shutdown")
+        if self._admitting is not None:
+            self._terminal(self._admitting[1], "shutdown")
         # race-ok: reached only after _thread.join() proved the engine
         # thread dead (is_alive() returns above otherwise) — the join is
         # the happens-before edge static analysis can't see
@@ -535,6 +569,7 @@ class SlotScheduler:
     def _nothing_to_do(self):
         """Nothing queued, no slot busy, not stopping (under _cond)."""
         return (not self._stop and not self._queue
+                and self._admitting is None
                 and all(r is None for r in self.slots))
 
     def _expire_queued(self, now):
@@ -552,8 +587,21 @@ class SlotScheduler:
     def _admit(self, now):
         """Move queued requests into free slots: bucketed prefill + one
         traced-slot cache write each; the first token is sampled straight
-        from the prefill logits (that fetch IS the TTFT)."""
-        while True:
+        from the prefill logits (that fetch IS the TTFT).  A prompt that
+        goes in chunks (its tail is longer than the largest bucket; a
+        paged backend whose decoder's prefill takes history) gets ONE
+        chunk a call: the occupied slots tick between two chunks, its
+        own slot is not occupied until the last, and no other request
+        is admitted meanwhile."""
+        if self._admitting is not None:
+            free, req, adm = self._admitting
+            if req.deadline is not None and now > req.deadline:
+                self._admitting = None
+                self.backend.release(free)
+                self._terminal(req, "timeout")
+            else:
+                self._admit_one(free, req, adm)
+        while self._admitting is None:
             free = next((i for i, r in enumerate(self.slots) if r is None),
                         None)
             if free is None:
@@ -565,48 +613,76 @@ class SlotScheduler:
                 _TM_QUEUE.set(len(self._queue))
             self._admit_one(free, req)
 
-    def _admit_one(self, free, req):
-        """One request's whole admission — prefill, first sample, cache
-        write — as the span ``engine.admit``; it fails only this
-        request: the slot stays free and the engine moves on.  A block
-        decoder's admission samples nothing and fetches nothing: its
+    def _prefill_phase(self, req, adm, bucket):
+        """The span around one prefill program and the fetch of its
+        logits: ``engine.prefill`` for an admission of one program,
+        ``engine.prefill_chunk``, with the chunk's place in the prompt,
+        for each of several."""
+        if not adm.chunked:
+            return _tracing.phase("engine.prefill", "engine",
+                                  request=req.id, bucket=bucket)
+        tokens, chunk_bucket = self.backend.next_chunk(adm)
+        return _tracing.phase(
+            "engine.prefill_chunk", "engine", request=req.id,
+            hist=adm.hist + adm.done, tokens=tokens, bucket=chunk_bucket,
+            last=adm.done + tokens == adm.tail.size)
+
+    def _admit_one(self, free, req, adm=None):
+        """One visit to a request's admission, as the span
+        ``engine.admit``: for most the whole of it -- prefill, first
+        sample, cache write; for a prompt that goes in chunks one
+        chunk, ``adm`` being the backend's record of the chunks before
+        (the last visit samples).  It fails only this request: the slot
+        stays free and the engine moves on.  A block decoder's
+        admission samples nothing and fetches nothing: its
         ``engine.prefill`` times the dispatch alone, and the first
         forward waits for the prefill."""
         from .. import faults as _faults
 
-        req.queue_wait = time.monotonic() - req.arrival
-        _TM_QWAIT.observe(req.queue_wait)
         traced = req.sampled and _tracing.trace_on()
-        if traced:
-            _tracing.record_span(
-                "queue_wait", "replica", req.trace, req.queue_wait,
-                parent=req.parent, request=req.id)
+        if adm is None:
+            req.queue_wait = time.monotonic() - req.arrival
+            _TM_QWAIT.observe(req.queue_wait)
+            if traced:
+                _tracing.record_span(
+                    "queue_wait", "replica", req.trace, req.queue_wait,
+                    parent=req.parent, request=req.id)
+        self._admitting = None
         plen = int(req.prompt.size)
-        bucket = next(b for b in self.prefill_buckets if b >= plen)
-        admitted = False
+        bucket = next((b for b in self.prefill_buckets if b >= plen),
+                      self.prefill_buckets[-1])
+        progressed = admitted = False
         with _tracing.phase(
                 "engine.admit", "engine", request=req.id, slot=free,
                 prompt_len=plen, bucket=bucket,
-                queue_wait_ms=_ms(req.queue_wait)) as adm:
+                queue_wait_ms=_ms(req.queue_wait)) as span:
             try:
-                _faults.maybe_fail("serve_admit")
-                # backend.admit returns a device array without waiting:
-                # the fetch belongs to the prefill, not to sampling
-                with _tracing.phase("engine.prefill", "engine",
-                                    request=req.id, bucket=bucket) as pf:
-                    logits = self.backend.admit(
+                if adm is None:
+                    _faults.maybe_fail("serve_admit")
+                    adm = self.backend.begin_admit(
                         free, req.prompt,
                         trace=(req.trace if traced else None))
+                # a backend returns a device array without waiting:
+                # the fetch belongs to the prefill, not to sampling
+                with self._prefill_phase(req, adm, bucket) as pf:
+                    # nothing is pending of a block decoder's prompt
+                    # that is shorter than one block
+                    logits = self.backend.admit_chunk(adm) \
+                        if adm.pending else None
                     if self._block_n == 1:
                         logits = np.asarray(logits, np.float32)
-                if self._block_n == 1:
+                if adm.pending:
+                    self._admitting = (free, req, adm)
+                elif self._block_n == 1:
                     first = self._sample(req, logits)
             except Exception as exc:  # noqa: BLE001
                 self.backend.release(free)
                 req.error = exc
                 self._terminal(req, "error")
             else:
-                admitted = True
+                progressed = True
+                admitted = self._admitting is None
+            if admitted:
                 if self._slot_used[free]:
                     _TM_REUSE.inc()
                 self._slot_used[free] = True
@@ -622,16 +698,18 @@ class SlotScheduler:
                     self._next_tok[free] = first
                     self._deliver(req, [first], time.monotonic())
                     self._maybe_finish(free, req.token_times[-1])
-        if traced and admitted and adm.t1 is not None:
+        if traced and progressed and span.t1 is not None:
             # the per-request records of a router-sampled request, from
-            # the phases' own stamps (traced implies they were live)
+            # the phases' own stamps (traced implies they were live):
+            # one ``prefill`` a program, ``admit`` when it is whole
             _tracing.record_span(
                 "prefill", "replica", req.trace, pf.t1 - pf.t0,
                 parent=req.parent, bucket=bucket, prompt_len=plen,
                 request=req.id)
-            _tracing.record_span(
-                "admit", "replica", req.trace, adm.t1 - adm.t0,
-                parent=req.parent, slot=free, request=req.id)
+            if admitted:
+                _tracing.record_span(
+                    "admit", "replica", req.trace, span.t1 - span.t0,
+                    parent=req.parent, slot=free, request=req.id)
 
     def _tick(self):
         """ONE jitted decode step over the whole pool + host sampling."""

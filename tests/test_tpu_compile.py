@@ -592,6 +592,76 @@ def test_block_row_scatter_relayouts_the_pool(one_chip):
     assert len(_pool_copies(text, _SDAR_POOL)) >= 2
 
 
+# ------------------------------------------- latent pages and nothing else
+# serve_docqa_kimi: benchmark/configs/kimi-k2-instruct-ep32-l6.json at
+# its published widths and 6 layers, 16 slots of 17,408 positions over a
+# pool of 36,160 pages (4.0 GB of latent rows)
+_KIMI_SLOTS, _KIMI_MAX_LEN, _KIMI_PAGES = 16, 17408, 36160 + 1
+_KIMI_POOL = "bf16[6,%d,%d]" % (_KIMI_PAGES, _BLOCK * 576)
+
+
+def _kimi_programs(sds):
+    """``_CachePrograms`` over a ``KimiDecoder`` that holds shapes for
+    weights, from the benchmark's configuration file."""
+    import json
+    import sys
+
+    from mxnet_tpu.models.kimi import KimiConfig, KimiDecoder
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmark.families import kimi as fam
+
+    with open(os.path.join(root, "benchmark", "configs",
+                           "kimi-k2-instruct-ep32-l6.json")) as f:
+        config = json.load(f)
+    config.pop("rehearse")
+    dec = KimiDecoder.__new__(KimiDecoder)
+    dec.cfg = KimiConfig.from_dict(config)
+    dec.p = {k: sds(tuple(v["shape"]),
+                    "float32" if k.endswith("router_bias") else "bfloat16")
+             for k, v in fam.param_specs(config).items()}
+    dec.max_len, dec.vocab = _KIMI_MAX_LEN, config["vocab_size"]
+    dec._cache_dtype = jnp.dtype("bfloat16")
+    return _CachePrograms(dec, dec.paged_layout(), _BLOCK,
+                          _KIMI_MAX_LEN // _BLOCK, _KIMI_PAGES, _KIMI_SLOTS)
+
+
+@pytest.mark.parametrize("which", ("step", 128, 2048))
+def test_latent_page_program_copies_no_pool(one_chip, which):
+    """The Kimi step (every slot's whole table gathered a layer) and its
+    prefills (the tail's pages written, this layer's rows of the slot
+    gathered, a loop over the history's key blocks) at the benchmark's
+    sizes: no instruction copies the 4.0 GB latent pool, it is written
+    in place in the donated buffer, and the program fits the chip
+    beside its 12.3 GB of arguments -- the largest prefill's blocks of
+    scores among the temporaries."""
+    lowered, _ = _lower(_kimi_programs(one_chip), one_chip, which,
+                        _KIMI_SLOTS, _KIMI_MAX_LEN // _BLOCK)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert _copies_of(text, {_KIMI_POOL}) == []
+    pool_bytes = 2 * 6 * _KIMI_PAGES * _BLOCK * 576
+    mem = compiled.memory_analysis()
+    assert 3.9e9 < pool_bytes <= mem.alias_size_in_bytes < 1.001 * pool_bytes
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.5e9
+    if which != "step":
+        assert "while" in text      # the history's loop is a loop still
+
+
+def test_grouped_matmul_refuses_an_expert_that_does_not_fit_vmem():
+    """A Kimi-K2 expert matrix is 29 MB (7168 x 2048): the kernel holds
+    an expert's WHOLE matrix as one block, two of each stack in flight,
+    so gate and up need 117 MB and down 59 MB of the 48 MB it may use.
+    ``supports`` says so and ``moe_serve`` keeps ``lax.ragged_dot``
+    there, on the TPU too."""
+    assert not gmm.supports(128, 7168, 2048, "bfloat16", 2)
+    assert not gmm.supports(128, 2048, 7168, "bfloat16", 1)
+    assert gmm.default_schedule("tpu", 128, 7168, 2048, "bfloat16",
+                                2) == {"impl": "ragged"}
+
+
 # ------------------------------------------ the experts' grouped matmul
 # (rows, k, n, stacks): the step's two calls in serve_block_sdar and in
 # serve_batch_ling, and the largest prefill buffer (bucket 2048, 8 pairs

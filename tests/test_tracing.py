@@ -654,11 +654,11 @@ def test_engine_spans_reach_the_profilers_host_plane(decoder, tmp_path):
 
 
 def test_prefill_span_holds_the_fetch(decoder, traced, monkeypatch):
-    """``backend.admit`` returns without waiting for the device; the
-    wait is the fetch of the logits, and it belongs to the prefill:
+    """``backend.admit_chunk`` returns without waiting for the device;
+    the wait is the fetch of the logits, and it belongs to the prefill:
     both the engine's span and the sampled request's record hold it."""
     sched = SlotScheduler(decoder, num_slots=1, queue_size=4)
-    real = sched.backend.admit
+    real = sched.backend.admit_chunk
 
     class Slow:
         def __init__(self, row):
@@ -668,7 +668,7 @@ def test_prefill_span_holds_the_fetch(decoder, traced, monkeypatch):
             time.sleep(0.05)
             return np.asarray(self.row, dtype)
 
-    monkeypatch.setattr(sched.backend, "admit",
+    monkeypatch.setattr(sched.backend, "admit_chunk",
                         lambda *a, **kw: Slow(real(*a, **kw)))
     try:
         req = sched.submit([1, 2, 3], max_new_tokens=2, temperature=0,
