@@ -14,9 +14,10 @@ the tests and the benchmark compare this file with.
 One forward (:meth:`KimiDecoder.forward`) over ``N`` tokens and the
 cache view ``serving/paged_kv.py`` hands it:
 
-- the decode step: one token a slot; the view appends a latent row a
-  slot and layer and hands back every slot's table, attended over in
-  the absorbed form;
+- the decode step: one token a slot; the view takes a latent row a
+  slot and layer and attends over the slot's live pages in the absorbed
+  form (``view.attend_pages``: the latent-attention kernel, or its
+  gather lowering);
 - a prefill: the padded *tail* of ONE sequence behind ``hist`` rows
   that are in the slot's pages already -- a cached prefix, or the
   earlier chunks of a prompt longer than the largest bucket.  The view
@@ -41,8 +42,8 @@ import jax
 import jax.numpy as jnp
 
 from .blocks import lin as _lin, moe_block, rms_norm, swiglu as _swiglu
-from .mla import (mla_absorbed, mla_expanded, rope as _rope, yarn_freq,
-                  yarn_mscale)
+from .mla import (mla_absorbed_paged, mla_expanded, rope as _rope,
+                  yarn_freq, yarn_mscale)
 
 __all__ = ["KimiConfig", "KimiDecoder"]
 
@@ -162,9 +163,10 @@ class KimiDecoder:
 
     def mla_block(self, p, i, x, view):
         """MLA over ``x`` (N, D): the view takes each token's ``[latent
-        | rotary key]`` row and hands back what the queries attend over
-        beside their own rows -- every slot's table in the step, this
-        slot's history in a prefill."""
+        | rotary key]`` row; in the step it attends for the absorbed
+        form over each slot's pages, in a prefill it hands back this
+        slot's history, which the queries attend over beside their own
+        rows."""
         c = self.cfg
         N, H = x.shape[0], c.heads
         pre = f"layer{i}_mla_"
@@ -180,12 +182,12 @@ class KimiDecoder:
         k_rope = _rope(kva[:, c.kv_rank:].astype(jnp.float32),
                        view.positions, freq).astype(x.dtype)
         rows = jnp.concatenate([lat, k_rope], -1)              # (N, 576)
-        seen = view.append("latent", i, rows)
         if view.step:
-            o = mla_absorbed(q_nope, q_rope, *seen, p[pre + "kvb_weight"], c)
+            o = mla_absorbed_paged(q_nope, q_rope, rows, view, "latent", i,
+                                   p[pre + "kvb_weight"], c)
         else:
             o = mla_expanded(q_nope, q_rope, rows, p[pre + "kvb_weight"], c,
-                             history=seen)
+                             history=view.append("latent", i, rows))
         return _lin(o, p[pre + "o_weight"])
 
     def forward(self, p, tokens, view):

@@ -19,8 +19,8 @@ blocks, the head.  It runs over ``N`` tokens and a *cache view* that
 the two programs that call it:
 
 - the decode step: ``N`` = slots, one token each at its own position;
-  the view reads and writes each slot's recurrent state and appends one
-  latent row a slot to its pages;
+  the view reads and writes each slot's recurrent state, takes one
+  latent row a slot into its pages and attends over them;
 - an admission's prefill: ``N`` = the padded prompt of ONE sequence; the
   view starts from a zero state (so a reused slot never sees its
   predecessor), takes the state after the last real token into the
@@ -29,7 +29,7 @@ the two programs that call it:
 ``view.step`` tells the two apart where the mathematics has two forms of
 the same thing: the KDA recurrence (one step, or chunks of 16 with the
 state carried between chunks: equal to the token-by-token recurrence,
-tests pin it) and MLA (absorbed over the gathered latent table, or
+tests pin it) and MLA (absorbed over each slot's latent pages, or
 expanded over the prompt's own rows: equal, tests pin it).
 
 What the decoder declares (:meth:`LingDecoder.paged_layout`)
@@ -57,7 +57,8 @@ import jax
 import jax.numpy as jnp
 
 from .blocks import lin as _lin, moe_block, rms_norm, swiglu as _swiglu
-from .mla import mla_absorbed, mla_expanded, rope as _rope, rope_freq
+from .mla import (mla_absorbed, mla_absorbed_paged, mla_expanded,
+                  rope as _rope, rope_freq)
 
 __all__ = ["LingConfig", "LingDecoder", "kda_recurrent_step", "kda_chunked",
            "mla_absorbed", "mla_expanded"]
@@ -308,8 +309,8 @@ class LingDecoder:
 
     def mla_block(self, p, i, x, view):
         """MLA over ``x`` (N, D); the view takes each token's ``[latent |
-        rotary key]`` row and, in the step, hands back every slot's
-        table."""
+        rotary key]`` row and, in the step, attends for the absorbed
+        form over each slot's pages."""
         c = self.cfg
         N, H = x.shape[0], c.heads
         pre = f"layer{i}_mla_"
@@ -324,12 +325,11 @@ class LingDecoder:
             kva[:, c.kv_rank:].astype(jnp.float32), view.positions,
             rope_freq(c.rope_theta, c.rope)).astype(x.dtype)
         rows = jnp.concatenate([lat, k_rope], -1)              # (N, 576)
-        written = view.append("latent", page_layer, rows)
         if view.step:
-            table, valid = written
-            o = mla_absorbed(q_nope, q_rope, table, valid,
-                             p[pre + "kvb_weight"], c)
+            o = mla_absorbed_paged(q_nope, q_rope, rows, view, "latent",
+                                   page_layer, p[pre + "kvb_weight"], c)
         else:
+            view.append("latent", page_layer, rows)
             o = mla_expanded(q_nope, q_rope, rows, p[pre + "kvb_weight"], c)
         return _lin(o, p[pre + "o_weight"])
 

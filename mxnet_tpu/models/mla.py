@@ -5,8 +5,13 @@ YaRN-scaled frequencies, and the two forms of the attention itself over
 the 576-wide cache rows ``[latent | rotary key]``.
 
 - :func:`mla_absorbed` -- the decode form: ``W_kvb`` folded into the
-  query and into the output, attention over the latent rows of every
-  slot's gathered table.
+  query and into the output, so that attention is multi-query attention
+  over the latent rows themselves (``ops/latent_attention.py``).  Over
+  a contiguous table it is the reference form; in the served step
+  (:func:`mla_absorbed_paged`) the cache view attends for it, over each
+  slot's live pages where they lie in the pool -- the Pallas kernel, or
+  a lookup of the slot's pages and the reference form over that -- and
+  no table reaches this module.
 - :func:`mla_expanded` -- the prefill form: keys and values expanded
   from the latent rows of one sequence, causal.  With ``history`` the
   sequence is a *tail* that stands behind rows already in the slot's
@@ -28,11 +33,13 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
+from ..ops import latent_attention as _la
 from .blocks import lin as _lin
 
 __all__ = ["rope_freq", "yarn_freq", "yarn_mscale", "rope",
-           "mla_absorbed", "mla_expanded"]
+           "mla_absorbed", "mla_absorbed_paged", "mla_expanded"]
 
 NEG_INF = -1e30
 MLA_QUERY_BLOCK = 512
@@ -90,34 +97,48 @@ def rope(x, pos, freq):
 
 def _denominator(c):
     """What the scores are divided by: ``sqrt(d_qk)``, over the square
-    of the configuration's ``attn_mscale`` where it has one."""
-    root = jnp.sqrt(jnp.float32(c.nope + c.rope))
+    of the configuration's ``attn_mscale`` where it has one.  A host
+    float32: a kernel takes it as a constant."""
+    root = np.sqrt(np.float32(c.nope + c.rope))
     m = getattr(c, "attn_mscale", 1.0)
-    # no division by one: a configuration without the scale keeps the
-    # program it had before the scale was known here
-    return root if m == 1.0 else root / jnp.float32(m * m)
+    return root if m == 1.0 else root / np.float32(m * m)
 
 
 # ---------------------------------------------------------- the forms
+def _absorbed(q_nope, q_rope, w_kvb, c, attend):
+    """``W_kvb``'s key half into the query, ``attend(q (B, H, rank +
+    rope), rank, denominator) -> (B, H, rank)`` over the latent rows,
+    its value half onto the result.  Returns (B, H * 128)."""
+    H = c.heads
+    wb = w_kvb.reshape(H, c.nope + c.v_dim, c.kv_rank)
+    q_lat = jnp.einsum("bhd,hdr->bhr", q_nope, wb[:, :c.nope])
+    o_lat = attend(jnp.concatenate([q_lat, q_rope], -1), c.kv_rank,
+                   _denominator(c))
+    o = jnp.einsum("bhr,hdr->bhd", o_lat, wb[:, c.nope:])
+    return o.reshape(o.shape[0], H * c.v_dim)
+
+
 def mla_absorbed(q_nope, q_rope, table, valid, w_kvb, c):
     """Decode form: ``q_nope`` (B, H, 128), ``q_rope`` (B, H, 64) turned
     already; ``table`` (B, S, 576) the rows ``[latent | rotary key]`` of
     each slot and ``valid`` (B, S) which of them exist.  ``W_kvb`` is
     absorbed into the query and into the output, so attention runs over
     the latent rows themselves.  Returns (B, H * 128)."""
-    H = c.heads
-    wb = w_kvb.reshape(H, c.nope + c.v_dim, c.kv_rank)
-    lat, k_rope = table[..., :c.kv_rank], table[..., c.kv_rank:]
-    q_lat = jnp.einsum("bhd,hdr->bhr", q_nope, wb[:, :c.nope])
-    s = (jnp.einsum("bhr,bsr->bhs", q_lat, lat,
-                    preferred_element_type=jnp.float32)
-         + jnp.einsum("bhd,bsd->bhs", q_rope, k_rope,
-                      preferred_element_type=jnp.float32)) \
-        / _denominator(c)
-    p = jax.nn.softmax(jnp.where(valid[:, None, :], s, NEG_INF), axis=-1)
-    o_lat = jnp.einsum("bhs,bsr->bhr", p.astype(lat.dtype), lat)
-    o = jnp.einsum("bhr,hdr->bhd", o_lat, wb[:, c.nope:])
-    return o.reshape(o.shape[0], H * c.v_dim)
+    return _absorbed(
+        q_nope, q_rope, w_kvb, c, lambda q, rank, denominator:
+        _la.dense_attention(q, table, valid, rank, denominator))
+
+
+def mla_absorbed_paged(q_nope, q_rope, rows, view, name, layer, w_kvb, c):
+    """The decode form in the served step: ``rows`` (B, 576), each
+    slot's new ``[latent | rotary key]`` row, go into the view's page
+    rows ``name`` of ``layer`` at the slot's cursor, and the view
+    attends over the slot's pages, the new row among them
+    (``view.attend_pages``).  Returns (B, H * 128)."""
+    return _absorbed(
+        q_nope, q_rope, w_kvb, c, lambda q, rank, denominator:
+        view.attend_pages(name, layer, rows, q, rank=rank,
+                          denominator=denominator))
 
 
 def _expand(rows, w_kvb, c):

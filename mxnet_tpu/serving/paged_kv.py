@@ -53,13 +53,21 @@ declare:
   the last bits, not bitwise (tests state the tolerance and the gap
   measured);
 - ``pages``: name -> ``(layers, row width, dtype)`` — page rows of the
-  decoder's own, one ``(layers, P, block * width)`` array each, a page
-  one row, so that gathering a table is a lookup of whole rows
+  decoder's own, one ``(layers, P, block, lanes)`` array each
   (``models/ling.py``, ``models/kimi.py``: one latent row a token and
-  MLA layer).  ``view.append`` writes a layer's new rows and hands back
-  what the queries attend over beside them: in the step every slot's
-  gathered table, in a prefill this layer's rows of the slot and
-  ``hist``, how many of them stand before the tail;
+  MLA layer): a page is ``block`` rows of the width in whole 128-wide
+  lanes, zero behind the width, so that ``pool[layer, page]`` is one
+  contiguous, tile-aligned run on the chip — what a kernel can copy
+  and a lookup by ``(layer, page)`` can read in place
+  (``ops/latent_attention.py``).  In the step ``view.attend_pages``
+  writes each slot's new row at its cursor and attends over the slot's
+  live pages by the schedule's lowering (the Pallas kernel, or a lookup
+  of the slot's table and the dense form over it); in a prefill
+  ``view.append`` writes the tail's rows a page at a time and hands
+  back this layer's rows of the slot and ``hist``, how many of them
+  stand before the tail.  No program slices one layer out of the pool
+  first: that is a copy of the layer (666 MB at the Kimi cell's size,
+  2 ms, before ISSUE 34);
 - ``state``: name -> ``(shape, dtype)`` — a FIXED-SIZE STATE PER SLOT
   beside the pages, one ``(num_slots, *shape)`` array each (a
   linear-attention layer's recurrent state; ``view.state`` /
@@ -191,7 +199,9 @@ def paged_kernel_mode() -> str:
     else.  ``0``/``off``/``gather``: pin the gather path (bit-identical
     to PR 15).  ``pallas`` / ``interpret``: force the kernel of
     ``ops/paged_attention.py`` (``interpret`` is the CPU-parity
-    hook)."""
+    hook).  The step's attention over page rows of a decoder's own
+    (``ops/latent_attention.py``) follows the same mode:
+    :meth:`PagedSlots._resolve_page_schedule`."""
     raw = os.environ.get("MXTPU_PAGED_KERNEL", "auto").strip().lower()
     if raw in ("", "1", "auto"):
         return "auto"
@@ -313,28 +323,34 @@ class _StepView(_CacheView):
                 q, pool_k, pool_v, self._bt, self._limit, layer,
                 block=self._pg.block, schedule=self._pg.schedule)
 
-    def append(self, name, layer, rows):
-        """``rows`` (B, W) land at each slot's cursor; returns the
-        gathered ``(B, S, W)`` table and its ``(B, S)`` validity."""
+    def attend_pages(self, name, layer, rows, q, *, rank, denominator):
+        """The page rows' counterpart of :meth:`attend`: ``rows`` (B, W)
+        land at each slot's cursor in page rows ``name`` of ``layer``;
+        then ``q`` (B, H, W) attends over the slot's pages just
+        written, positions ``[0, cursor]``: scores against a row's
+        ``W`` numbers over ``denominator``, the weighted sum over its
+        first ``rank`` (``ops/latent_attention.py``: the schedule's
+        lowering reads the pool where it lies, the kernel the live
+        pages alone).  Returns ``(B, H, rank)``."""
         import jax
         import jax.numpy as jnp
 
-        pg, bt = self._pg, self._bt
+        from ..ops import latent_attention as _la
+
         pool = self.pages[name]
-        W = rows.shape[-1]
-        rows = rows.astype(pool.dtype)[None, :, None]        # (1, B, 1, W)
+        rows = jnp.pad(rows.astype(pool.dtype),
+                       ((0, 0), (0, pool.shape[-1] - rows.shape[-1])))
+        rows = rows[None, :, None]                       # (1, B, 1, lanes)
         # one dynamic_update_slice a slot, as the K/V pools' row writes
         for b, (page, off) in enumerate(self._at):
             pool = jax.lax.dynamic_update_slice(
-                pool, rows[:, b], (layer, page, off * W),
+                pool, rows[:, b:b + 1], (layer, page, off, 0),
                 allow_negative_indices=False)
         self.pages[name] = pool
-        # a page is one row of ``block * W`` numbers: gathering a slot's
-        # table is a lookup of whole rows, in the layout they lie in
-        S = pg.max_blocks * pg.block
-        table = jnp.take(pool[layer], bt.reshape(-1), axis=0).reshape(
-            bt.shape[0], S, W)
-        return table, jnp.arange(S)[None, :] <= self._cursor[:, None]
+        return _la.latent_attention(
+            q, pool, self._bt, self._cursor, layer, rank=rank,
+            denominator=denominator,
+            schedule=self._pg.page_schedules[name])
 
 
 class _PrefillView(_CacheView):
@@ -442,22 +458,25 @@ class _PrefillView(_CacheView):
         """``rows`` (T, W): the tail's page rows, written whole pages at
         a time like the K/V pools'.  Returns what stands before the
         tail: ``(table (S, W), hist)``, this layer's rows of the slot
-        gathered from the pool (whole pages, as the step gathers them)
-        and how many of them, from position 0 on, are the history; the
-        rest of the table is not the caller's to read.  ``None`` for a
-        layout that can have no history (``prefix_reuse`` False)."""
+        looked up in the pool by ``(layer, page)`` (whole pages; never
+        a slice of the layer first) and how many of them, from position
+        0 on, are the history; the rest of the table is not the
+        caller's to read.  ``None`` for a layout that can have no
+        history (``prefix_reuse`` False)."""
         import jax.numpy as jnp
+
+        from ..ops import latent_attention as _la
 
         pg = self._pg
         pool = self.pages[name]
         T, W = rows.shape
-        rows = jnp.pad(rows.astype(pool.dtype), ((0, -T % pg.block), (0, 0)))
+        rows = jnp.pad(rows.astype(pool.dtype),
+                       ((0, -T % pg.block), (0, pool.shape[-1] - W)))
         self.pages[name] = pool = pool.at[layer, self._page_ids].set(
-            rows.reshape(-1, pg.block * W), mode="drop")
+            rows.reshape(-1, pg.block, pool.shape[-1]), mode="drop")
         if not pg.layout["prefix_reuse"]:
             return None
-        table = jnp.take(pool[layer], self._bt_row, axis=0)
-        return table.reshape(pg.max_blocks * pg.block, W), self._hist
+        return _la.page_table(pool, self._bt_row, layer, W), self._hist
 
 
 class _CachePrograms:
@@ -473,7 +492,7 @@ class _CachePrograms:
     step_view, prefill_view = _StepView, _PrefillView
 
     def __init__(self, decoder, layout, block, max_blocks, num_pages,
-                 num_slots, schedule=None):
+                 num_slots, schedule=None, page_schedules=None):
         from ..models.decode import _WeightProgram, _count_compiles
 
         self.dec, self.layout = decoder, layout
@@ -484,6 +503,11 @@ class _CachePrograms:
         # the K/V step's attention schedule (ops/paged_attention.py);
         # None is gather
         self.schedule = schedule
+        # the step's attention over page rows, a schedule a name
+        # (ops/latent_attention.py); gather where none is given
+        self.page_schedules = {
+            name: (page_schedules or {}).get(name)
+            for name in layout["pages"]}
         self._WeightProgram, self._count = _WeightProgram, _count_compiles
         self._step_jit = _WeightProgram(
             decoder, _count_compiles(self._step_program,
@@ -497,13 +521,16 @@ class _CachePrograms:
         may take the cache's buffers."""
         import jax
 
+        from ..ops import latent_attention as _la
+
         kv = ()
         if "kv_pages" in self.layout:
             layers, heads, dh, dtype = self.layout["kv_pages"]
             kv = (jax.ShapeDtypeStruct(
                 (self.num_pages, layers, heads, self.block, dh), dtype),) * 2
         pages = {n: jax.ShapeDtypeStruct(
-            (layers, self.num_pages, self.block * width), dtype)
+            (layers, self.num_pages, self.block, _la.page_width(width)),
+            dtype)
             for n, (layers, width, dtype) in self.layout["pages"].items()}
         state = {n: jax.ShapeDtypeStruct((self.num_slots,) + tuple(shape),
                                          dtype)
@@ -601,11 +628,19 @@ class PagedSlots:
         self.prefill_buckets = tuple(prefill_buckets or ())
         self.kernel_mode = (paged_kernel_mode() if kernel is None
                             else str(kernel).strip().lower())
+        if self.kernel_mode not in ("auto", "gather", "pallas", "interpret"):
+            raise MXNetError(
+                f"unknown MXTPU_PAGED_KERNEL mode {self.kernel_mode!r} "
+                "(want auto, gather/0, pallas or interpret)")
         layout = decoder.paged_layout()
         self.schedule = self._resolve_schedule(layout.get("kv_pages"))
+        self.page_schedules = {
+            name: self._resolve_page_schedule(width, dtype)
+            for name, (_layers, width, dtype) in layout["pages"].items()}
         self.programs = _CachePrograms(
             decoder, layout, self.block, self.max_blocks,
-            self.num_pages + 1, self.num_slots, schedule=self.schedule)
+            self.num_pages + 1, self.num_slots, schedule=self.schedule,
+            page_schedules=self.page_schedules)
         # tokens a slot a step: a block decoder's block, which a page
         # holds whole (a block never straddles two pages)
         self.block_n = self.programs.block_n
@@ -703,10 +738,6 @@ class PagedSlots:
             if not _pa.supports(blk, dh, dtype):
                 return None         # shape gate even when forced
             return {"impl": "pallas", "interpret": mode == "interpret"}
-        if mode != "auto":
-            raise MXNetError(
-                f"unknown MXTPU_PAGED_KERNEL mode {mode!r} (want auto, "
-                "gather/0, pallas or interpret)")
         platform = jax.default_backend()
         sched = _autotune.ensure(
             "paged_attention",
@@ -716,6 +747,29 @@ class PagedSlots:
             lambda c: _pa.make_bench_fn(c, B=B, H=H, M=M, block=blk,
                                         dh=dh, L=L, dtype=dtype))
         return None if sched.get("impl") == "gather" else dict(sched)
+
+    def _resolve_page_schedule(self, width, dtype):
+        """The schedule of the step's attention over page rows of
+        ``width`` numbers (``ops/latent_attention.py``), decided once,
+        here, by the mode :func:`paged_kernel_mode` reads, the platform
+        and the kernel's gate: ``gather`` pins the lookup, ``pallas``
+        asks for the kernel wherever Mosaic takes the page's shape,
+        ``interpret`` runs it interpreted whatever the shape (the CPU
+        parity hook: no Mosaic in it), ``auto`` takes the kernel on a
+        TPU.  There is nothing to search: the kernel's chunk follows
+        from the shapes."""
+        import jax
+
+        from ..ops import latent_attention as _la
+
+        mode = self.kernel_mode
+        if mode == "interpret":
+            return {"impl": "pallas", "interpret": True}
+        if mode == "gather":
+            return {"impl": "gather"}
+        return _la.default_schedule(
+            "tpu" if mode == "pallas" else jax.default_backend(),
+            self.block, _la.page_width(width), dtype)
 
     # --------------------------------------------------------- bookkeeping
     def _set_gauges(self):
@@ -754,7 +808,12 @@ class PagedSlots:
                "family": self.decoder.family,
                # the step's attention over K/V pages, where there are any
                "kernel": (self.schedule or {"impl": "gather"})["impl"]
-               if "kv_pages" in layout else "none"}
+               if "kv_pages" in layout else "none",
+               # the step's attention over page rows of the decoder's
+               # own: the kernel only if every kind of them takes it
+               "latent_kernel": "none" if not layout["pages"] else
+               "pallas" if all(s["impl"] == "pallas" for s in
+                               self.page_schedules.values()) else "gather"}
         if layout["state"]:
             out["state_slots_in_use"] = self._slots_in_use()
         if layout["pages"]:

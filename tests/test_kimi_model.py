@@ -53,16 +53,24 @@ def tiny_config(**over):
     return config
 
 
-@pytest.fixture(scope="module")
-def served():
+# the step's latent attention under both of its lowerings
+# (ops/latent_attention.py): the lookup by (layer, page), and the Pallas
+# kernel interpreted on the CPU
+LOWERINGS = {"gather": "gather", "interpret": "pallas"}
+
+
+@pytest.fixture(scope="module", params=list(LOWERINGS))
+def served(request):
     """(config, reference sizes, reference leaves, PagedSlots) of the
     rehearsal's three layers (one dense, two MoE) in float32, three
-    slots."""
+    slots, once for each lowering of the step's latent attention."""
     config = tiny_config()
     params = fam.serving_weights(config, SEED, jnp.float32)
     decoder = fam.build_decoder(config, params, 128, jnp.float32)
     slots = PagedSlots(decoder, num_slots=3, block=BLOCK,
-                       prefill_buckets=BUCKETS)
+                       prefill_buckets=BUCKETS, kernel=request.param)
+    assert slots.stats()["latent_kernel"] == LOWERINGS[request.param]
+    assert slots.stats()["kernel"] == "none"
     return config, ref.sizes_of(config), fam.reference_params(config, SEED), \
         slots
 

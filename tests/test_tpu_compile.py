@@ -41,6 +41,7 @@ from jax.sharding import SingleDeviceSharding
 from mxnet_tpu.models.decode import KVDecoder
 from mxnet_tpu.ops import flash_attention as fa
 from mxnet_tpu.ops import grouped_matmul as gmm
+from mxnet_tpu.ops import latent_attention as la
 from mxnet_tpu.ops import paged_attention as pa
 from mxnet_tpu.ops import residual_epilogue as repi
 from mxnet_tpu.serving.paged_kv import (_CachePrograms, _PrefillView,
@@ -164,6 +165,83 @@ def test_paged_gate_rejects_what_mosaic_rejects(one_chip):
         _compile_paged_kernel(one_chip, B, H, dh, block, M, "float32")
 
 
+# ----------------------------------------------------------------- latent
+def _compile_latent_kernel(sds, B, H, M, L, P, dtype, block=16, lanes=640,
+                           width=576, rank=512):
+    return _compile(
+        lambda q, pool, bt, cur: la._pallas_attention(
+            q, pool, bt, cur, L - 1, rank, 13.86, False),
+        sds((B, H, lanes), dtype), sds((L, P, block, lanes), dtype),
+        sds((B, M), "int32"), sds((B,), "int32"))
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("B,H,M,L,P", [
+    pytest.param(16, 64, 1088, 6, 36161, id="serve_docqa_kimi"),
+    pytest.param(128, 32, 144, 1, 18433, id="serve_batch_ling")])
+def test_latent_attention_compiles(one_chip, B, H, M, L, P, dtype):
+    """The two cells' steps: 16 slots x 64 heads over 1,088 pages a slot
+    of a 6-layer pool of 36,161 pages, and 128 slots x 32 heads over 144
+    pages a slot; rows of 576 in 640 lanes.  The block table rides whole
+    as scalar prefetch (70 KB), two chunks of pages sit in VMEM whatever
+    the table's length."""
+    assert la.supports(16, 640, dtype)
+    chunk = la.chunk_pages(16, 640, dtype, M)
+    assert 2 * chunk * 16 * 640 * jnp.dtype(dtype).itemsize \
+        <= la._VMEM_BUDGET
+    text = _compile_latent_kernel(one_chip, B, H, M, L, P, dtype)
+    assert "latent_attn" in text
+
+
+def test_latent_attention_compiles_at_a_rank_off_the_lanes(one_chip):
+    """A latent narrower than whole lanes (the rehearsal's 32 + 8 in
+    128): the value product then covers the row and the caller cuts."""
+    _compile_latent_kernel(one_chip, 3, 8, 8, 2, 40, "float32", block=8,
+                           lanes=128, width=40, rank=32)
+
+
+@pytest.mark.parametrize("block,lanes,why", [
+    pytest.param(16, 576, "aligned to tiling", id="a_row_of_4.5_lane_tiles"),
+    pytest.param(4, 640, "aligned to tiling", id="half_a_sublane_tile")])
+def test_latent_gate_rejects_what_mosaic_rejects(one_chip, block, lanes, why):
+    """What ``supports()`` refuses, the compiler refuses: a page row
+    that is no whole number of 128-wide lanes (PR 33's rows of 576),
+    and pages of 4 rows, half a tile of 8."""
+    assert not la.supports(block, lanes, "bfloat16")
+    with pytest.raises(Exception, match=why):
+        _compile_latent_kernel(one_chip, 16, 64, 64, 2, 1025, "bfloat16",
+                               block=block, lanes=lanes)
+
+
+def test_a_page_that_is_one_row_of_a_matrix_cannot_be_copied(one_chip):
+    """Why the pool changed shape in ISSUE 34: out of PR 33's ``(L, P,
+    block * 576)`` a page is one row, strewn over 72 tiles of 8 pages x
+    128 lanes, and the compiler refuses to copy it."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    def kernel(pg_ref, pool_ref, o_ref, buf, sem):
+        cp = pltpu.make_async_copy(pool_ref.at[1, pl.ds(pg_ref[0], 1)],
+                                   buf, sem.at[0])
+        cp.start()
+        cp.wait()
+        o_ref[...] = buf[...]
+
+    def one_page(pg, pool):
+        return pl.pallas_call(
+            kernel, out_shape=jax.ShapeDtypeStruct((1, 9216), pool.dtype),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1, grid=(1,),
+                in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+                out_specs=pl.BlockSpec((1, 9216), lambda i, *_: (0, 0)),
+                scratch_shapes=[pltpu.VMEM((1, 9216), pool.dtype),
+                                pltpu.SemaphoreType.DMA((1,))]))(pg, pool)
+
+    with pytest.raises(Exception, match="aligned to tiling"):
+        _compile(one_page, one_chip((1,), "int32"),
+                 one_chip((6, 36161, 9216), "bfloat16"))
+
+
 # ------------------------------------------- paged programs and the pool
 # serve_batch's pool: 16 slots x 64 pages of 16 tokens and the scratch
 # page, 16 heads of 128; 3 of the 24 layers, and narrow where the pool
@@ -253,6 +331,20 @@ def _copies_of(text, shapes):
     ``shapes`` (``f32[128,32,128,128]``)."""
     return [name for name, result, op in _instructions(text)
             if _is_copy(name, op) and any(s in result for s in shapes)]
+
+
+def _arrays_of(text, shapes):
+    """Instructions of the optimized HLO, fused ones too, whose result
+    is an array of one of ``shapes``."""
+    return [name for name, result, _op in _instructions(text)
+            if any(s in result for s in shapes)]
+
+
+def _latent_schedules(lowering):
+    """The page rows' schedule as ``PagedSlots`` resolves it on a TPU
+    (``kernel``), or pinned to the lookup (``gather``)."""
+    return {"latent": la.default_schedule(
+        "tpu" if lowering == "kernel" else "cpu", _BLOCK, 640, "bfloat16")}
 
 
 def _pool_copies(text, pool=_POOL):
@@ -411,7 +503,7 @@ def test_gathered_table_is_copied_twice_a_layer(one_chip, bucket):
 _LING_SLOTS, _LING_MAX_LEN = 128, 2304
 
 
-def _ling_programs(sds, donate=True):
+def _ling_programs(sds, donate=True, latent="gather"):
     """``_CachePrograms`` over a ``LingDecoder`` that holds shapes for
     weights, from the benchmark's configuration file."""
     import json
@@ -438,7 +530,8 @@ def _ling_programs(sds, donate=True):
     dec._cache_dtype = jnp.dtype("bfloat16")
     M = _LING_MAX_LEN // _BLOCK
     progs = _CachePrograms(dec, dec.paged_layout(), _BLOCK, M,
-                           _LING_SLOTS * M + 1, _LING_SLOTS)
+                           _LING_SLOTS * M + 1, _LING_SLOTS,
+                           page_schedules=_latent_schedules(latent))
     if not donate:
         from mxnet_tpu.models.decode import _WeightProgram
         progs._step_jit = _WeightProgram(
@@ -457,36 +550,50 @@ def _shape_text(s):
     return "%s[%s]" % (short, ",".join(str(d) for d in s.shape))
 
 
-@pytest.mark.parametrize("which", ("step", 256, 2048))
-def test_declared_program_copies_neither_state_nor_pages(one_chip, which):
+# every slot's whole 2,304-row table of the one MLA layer, as the lookup
+# builds it (and as PR 33's ``jnp.take`` did: the last)
+_LING_TABLES = ["bf16[128,144,16,640]", "bf16[128,2304,640]",
+                "bf16[18432,16,640]", "bf16[18432,9216]"]
+
+
+@pytest.mark.parametrize("which,latent", [
+    ("step", "kernel"), ("step", "gather"), (256, "gather"),
+    (2048, "gather")])
+def test_declared_program_copies_neither_state_nor_pages(one_chip, which,
+                                                         latent):
     """The step and the prefills of the Ling decoder at the benchmark's
     sizes: no instruction copies a recurrent state (268 MB a KDA layer,
-    1.61 GB in all) or the latent pool (340 MB), every leaf of the
+    1.61 GB in all) or the latent pool (377 MB), every leaf of the
     cache is written in place in the donated buffers, and the program
-    fits the chip beside its 12.3 GB of arguments.  The convolution
+    fits the chip beside its 12.4 GB of arguments.  The convolution
     tails (9 MB a layer) are not held to this: the step's compiler
-    stages them through its fast memory, which costs microseconds."""
-    compiled, cache = _compile_ling(_ling_programs(one_chip), one_chip,
-                                    which)
+    stages them through its fast memory, which costs microseconds.
+    The step's latent attention under the kernel builds no table of
+    the slots' rows; under the other lowering it looks one up, which
+    shows that the check can see a table."""
+    compiled, cache = _compile_ling(_ling_programs(one_chip, latent=latent),
+                                    one_chip, which)
     text = compiled.as_text()
     leaves = jax.tree_util.tree_leaves(cache)
     nbytes = lambda s: s.dtype.itemsize * functools.reduce(
         lambda a, b: a * b, s.shape)
     big = {_shape_text(s) for s in leaves if nbytes(s) > 64e6}
-    assert big == {"f32[128,32,128,128]", "bf16[1,18433,9216]"}
+    assert big == {"f32[128,32,128,128]", "bf16[1,18433,16,640]"}
     assert _copies_of(text, big) == []
     cache_bytes = sum(nbytes(s) for s in leaves)
     assert 1.9e9 < cache_bytes < 2.1e9
     mem = compiled.memory_analysis()
-    # the device pads the pool's 18,433 pages to a whole tile: 126 KB
     assert cache_bytes <= mem.alias_size_in_bytes < 1.001 * cache_bytes
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.0e9
     if which == "step":
         # the TPU compiler's own grouped-matmul kernels (lax.ragged_dot),
-        # three a MoE layer and one for its group metadata: no kernel of
-        # this repo is in the step
-        assert text.count('custom_call_target="tpu_custom_call"') == 24
+        # three a MoE layer and one for its group metadata, and under
+        # the kernel this repo's latent attention for the one MLA layer
+        ours = len(re.findall(r'custom-call\(.*latent_attn', text))
+        assert ours == (latent == "kernel")
+        assert text.count('custom_call_target="tpu_custom_call"') == 24 + ours
         assert "paged_attn" not in text
+        assert bool(_arrays_of(text, _LING_TABLES)) == (latent == "gather")
 
 
 def test_a_cache_that_is_not_donated_is_not_written_in_place(one_chip):
@@ -597,10 +704,17 @@ def test_block_row_scatter_relayouts_the_pool(one_chip):
 # its published widths and 6 layers, 16 slots of 17,408 positions over a
 # pool of 36,160 pages (4.0 GB of latent rows)
 _KIMI_SLOTS, _KIMI_MAX_LEN, _KIMI_PAGES = 16, 17408, 36160 + 1
-_KIMI_POOL = "bf16[6,%d,%d]" % (_KIMI_PAGES, _BLOCK * 576)
+_KIMI_POOL = "bf16[6,%d,%d,640]" % (_KIMI_PAGES, _BLOCK)
+# one layer of the pool (666 MB in PR 33's rows of 9,216, which every
+# gather copied out first), and every slot's whole 17,408-row table
+_KIMI_SLABS = ["bf16[%d,%d,640]" % (_KIMI_PAGES, _BLOCK),
+               "bf16[1,%d,%d,640]" % (_KIMI_PAGES, _BLOCK),
+               "bf16[%d,9216]" % _KIMI_PAGES]
+_KIMI_TABLES = ["bf16[16,1088,16,640]", "bf16[16,17408,640]",
+                "bf16[17408,16,640]", "bf16[17408,9216]"]
 
 
-def _kimi_programs(sds):
+def _kimi_programs(sds, latent="gather"):
     """``_CachePrograms`` over a ``KimiDecoder`` that holds shapes for
     weights, from the benchmark's configuration file."""
     import json
@@ -625,29 +739,50 @@ def _kimi_programs(sds):
     dec.max_len, dec.vocab = _KIMI_MAX_LEN, config["vocab_size"]
     dec._cache_dtype = jnp.dtype("bfloat16")
     return _CachePrograms(dec, dec.paged_layout(), _BLOCK,
-                          _KIMI_MAX_LEN // _BLOCK, _KIMI_PAGES, _KIMI_SLOTS)
+                          _KIMI_MAX_LEN // _BLOCK, _KIMI_PAGES, _KIMI_SLOTS,
+                          page_schedules=_latent_schedules(latent))
 
 
-@pytest.mark.parametrize("which", ("step", 128, 2048))
-def test_latent_page_program_copies_no_pool(one_chip, which):
-    """The Kimi step (every slot's whole table gathered a layer) and its
-    prefills (the tail's pages written, this layer's rows of the slot
-    gathered, a loop over the history's key blocks) at the benchmark's
-    sizes: no instruction copies the 4.0 GB latent pool, it is written
-    in place in the donated buffer, and the program fits the chip
-    beside its 12.3 GB of arguments -- the largest prefill's blocks of
-    scores among the temporaries."""
-    lowered, _ = _lower(_kimi_programs(one_chip), one_chip, which,
+@pytest.mark.parametrize("which,latent", [
+    ("step", "kernel"), ("step", "gather"), (128, "gather"),
+    (2048, "gather")])
+def test_latent_page_program_copies_no_pool(one_chip, which, latent):
+    """The Kimi step (a latent-attention kernel a layer over each slot's
+    live pages; under the other lowering the slots' tables looked up by
+    layer and page) and its prefills (the tail's pages written, this
+    layer's rows of the slot looked up, a loop over the history's key
+    blocks) at the benchmark's sizes: no instruction copies the 4.4 GB
+    latent pool, it is written in place in the donated buffer, NO ARRAY
+    OF ONE LAYER OF IT exists anywhere (PR 33's ``pool[layer]`` was a
+    666 MB copy before each gather), and the program fits the chip
+    beside its 12.8 GB of arguments -- the largest prefill's blocks of
+    scores among the temporaries.  Under the kernel the step holds no
+    table of the slots' rows either and next to no temporaries; under
+    the other lowering it holds one a layer, which shows that the
+    check can see a table."""
+    lowered, _ = _lower(_kimi_programs(one_chip, latent), one_chip, which,
                         _KIMI_SLOTS, _KIMI_MAX_LEN // _BLOCK)
     compiled = lowered.compile()
     text = compiled.as_text()
     assert _copies_of(text, {_KIMI_POOL}) == []
-    pool_bytes = 2 * 6 * _KIMI_PAGES * _BLOCK * 576
+    assert _arrays_of(text, _KIMI_SLABS) == []
+    pool_bytes = 2 * 6 * _KIMI_PAGES * _BLOCK * 640
     mem = compiled.memory_analysis()
-    assert 3.9e9 < pool_bytes <= mem.alias_size_in_bytes < 1.001 * pool_bytes
+    assert 4.4e9 < pool_bytes <= mem.alias_size_in_bytes < 1.001 * pool_bytes
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.5e9
     if which != "step":
         assert "while" in text      # the history's loop is a loop still
+        return
+    ours = len(re.findall(r'custom-call\(.*latent_attn', text))
+    assert ours == (6 if latent == "kernel" else 0)
+    assert bool(_arrays_of(text, _KIMI_TABLES)) == (latent == "gather")
+    if latent == "kernel":
+        assert mem.temp_size_in_bytes < 64e6
+    else:
+        # the lookup stages no fill: no select over a table's shape
+        assert not [name for name, result, op in _instructions(text)
+                    if op == "select" and "17408" in result
+                    and "bf16" in result]
 
 
 def test_grouped_matmul_refuses_an_expert_that_does_not_fit_vmem():
