@@ -71,6 +71,17 @@ BOUNDARIES = {
         "opt-in debugging Monitor: interval-gated stat rendering syncs "
         "by contract (PR-5 keeps the per-batch tic() sync-free; "
         "production loops install no monitor)",
+    # the serving tick's ONE host sync (PR 35): sampling needs the step's
+    # logits on the host, and that fetch has always been the tick's wait
+    # for the device (``np.asarray`` of a device array, which the
+    # analyzer cannot type).  While a trace is being taken the same wait
+    # is written in two parts, ``block_until_ready`` then the copy, so
+    # that each gets a span of its own; with nobody looking it is the
+    # one ``np.asarray`` it was
+    "mxnet_tpu.serving.scheduler.SlotScheduler._to_host":
+        "the tick's (and an admission's) one fetch of what sampling "
+        "reads, split into engine.wait + engine.fetch only while "
+        "tracing.recording(): the same sync in two spans, not a second",
     # autotuner (ISSUE 18): schedule search is a bind/admit-time
     # activity ONLY — PagedSlots construction and explicit tune() call
     # sites.  measure() blocks on each candidate by design; the

@@ -671,10 +671,6 @@ class PagedSlots:
         # trace id of the admission currently allocating, so _alloc can
         # attribute its prefix evictions; None for step-time evictions
         self._trace_ctx = None
-        # perf plane (telemetry/perf.py): one analytical cost row per
-        # compiled paged program, captured at first dispatch
-        self._cost_step_done = False
-        self._cost_prefill_done = set()
         self._set_gauges()
 
     # ----------------------------------------------------------------- pool
@@ -983,12 +979,6 @@ class PagedSlots:
         args = (_snap(adm.bt_row), jnp.asarray(padded), np.int32(adm.slot),
                 jnp.int32(adm.hist + adm.done), jnp.int32(t))
         logits = self._run(self.programs.prefill(bucket), *args)
-        if bucket not in self._cost_prefill_done and _tm.perf.enabled():
-            self._cost_prefill_done.add(bucket)
-            _tm.perf.attach_cost_analysis(
-                f"decode_prefill_paged[b{bucket}]",
-                self.programs.prefill(bucket),
-                *self._lowering_args(), *args)
         adm.done += t
         self._host_counts["prefill_chunks"] += 1
         if not adm.pending:
@@ -1076,11 +1066,6 @@ class PagedSlots:
         args = (_snap(self.bt), _snap(tokens), _snap(self.cursor),
                 np.array(occupied, bool))
         logits = self._run(self.programs._step_jit, *args)
-        if not self._cost_step_done and _tm.perf.enabled():
-            self._cost_step_done = True
-            _tm.perf.attach_cost_analysis(
-                "decode_step_paged", self.programs._step_jit,
-                *self._lowering_args(), *args)
         adv = (occupied if commit is None else commit).copy()
         adv[starved] = False
         self.cursor[adv] += self.block_n

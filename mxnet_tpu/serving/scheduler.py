@@ -613,19 +613,58 @@ class SlotScheduler:
                 _TM_QUEUE.set(len(self._queue))
             self._admit_one(free, req)
 
-    def _prefill_phase(self, req, adm, bucket):
-        """The span around one prefill program and the fetch of its
-        logits: ``engine.prefill`` for an admission of one program,
-        ``engine.prefill_chunk``, with the chunk's place in the prompt,
-        for each of several."""
-        if not adm.chunked:
-            return _tracing.phase("engine.prefill", "engine",
-                                  request=req.id, bucket=bucket)
-        tokens, chunk_bucket = self.backend.next_chunk(adm)
-        return _tracing.phase(
-            "engine.prefill_chunk", "engine", request=req.id,
-            hist=adm.hist + adm.done, tokens=tokens, bucket=chunk_bucket,
-            last=adm.done + tokens == adm.tail.size)
+    def _to_host(self, out, program):
+        """What a backend returned, as the host array(s) the sampler
+        reads -- the float32 logits, or a block decoder's ``(token,
+        probability)`` pair: the ONE host sync of a tick and of an
+        admission.  While someone is looking it is two leaf spans:
+        ``engine.wait``, the device finishing, and ``engine.fetch``,
+        the conversion and the copy."""
+        block = self._block_n > 1
+
+        def arrays():
+            if block:
+                return tuple(np.asarray(a) for a in out)
+            return np.asarray(out, np.float32)
+
+        if not _tracing.recording():
+            return arrays()
+        import jax
+
+        with _tracing.phase("engine.wait", "engine", program=program):
+            jax.block_until_ready(out)
+        nbytes = sum(a.nbytes for a in out) if block else 4 * out.size
+        with _tracing.phase("engine.fetch", "engine", program=program,
+                            bytes=nbytes):
+            return arrays()
+
+    def _prefill(self, req, adm, bucket):
+        """One prefill program of ``adm`` and, for the sampler, its
+        logits on the host, under one span: ``engine.prefill`` for an
+        admission of one program, ``engine.prefill_chunk``, with the
+        chunk's place in the prompt, for each of several.  Returns the
+        span and the logits (None from a block decoder, whose admission
+        fetches nothing)."""
+        name, program = "engine.prefill", "prefill"
+        attrs = {"request": req.id, "bucket": bucket}
+        if adm.chunked:
+            tokens, bucket = self.backend.next_chunk(adm)
+            name, program = "engine.prefill_chunk", "chunk"
+            attrs = {"request": req.id, "hist": adm.hist + adm.done,
+                     "tokens": tokens, "bucket": bucket,
+                     "last": adm.done + tokens == adm.tail.size}
+        with _tracing.phase(name, "engine", **attrs) as pf:
+            logits = None
+            # nothing is pending of a block decoder's prompt that is
+            # shorter than one block
+            if adm.pending:
+                # a backend returns a device array without waiting
+                with _tracing.phase("engine.launch", "engine",
+                                    program=program, bucket=bucket):
+                    logits = self.backend.admit_chunk(adm)
+            if self._block_n == 1:
+                logits = self._to_host(logits, program)
+        return pf, logits
 
     def _admit_one(self, free, req, adm=None):
         """One visit to a request's admission, as the span
@@ -662,15 +701,8 @@ class SlotScheduler:
                     adm = self.backend.begin_admit(
                         free, req.prompt,
                         trace=(req.trace if traced else None))
-                # a backend returns a device array without waiting:
                 # the fetch belongs to the prefill, not to sampling
-                with self._prefill_phase(req, adm, bucket) as pf:
-                    # nothing is pending of a block decoder's prompt
-                    # that is shorter than one block
-                    logits = self.backend.admit_chunk(adm) \
-                        if adm.pending else None
-                    if self._block_n == 1:
-                        logits = np.asarray(logits, np.float32)
+                pf, logits = self._prefill(req, adm, bucket)
                 if adm.pending:
                     self._admitting = (free, req, adm)
                 elif self._block_n == 1:
@@ -748,18 +780,18 @@ class SlotScheduler:
             if _tracing.trace_on() and n % _tracing.TICK_EVERY == 0:
                 tick_reqs = [(i, self.slots[i]) for i in occupied
                              if self.slots[i].sampled]
-            with _tracing.phase("engine.step", "engine", tick=n) as step:
+            with _tracing.phase("engine.step", "engine", tick=n):
+                with _tracing.phase("engine.launch", "engine",
+                                    program="step"):
+                    out, starved = self.backend.step(
+                        *((self._blk_ids, occ_mask, commit) if block
+                          else (self._next_tok, occ_mask)))
                 # the ONE host sync/tick: the logits, or a block
                 # decoder's (token, probability) a row
                 if block:
-                    out, starved = self.backend.step(
-                        self._blk_ids, occ_mask, commit)
-                    toks, probs = (np.asarray(a) for a in out)
+                    toks, probs = self._to_host(out, "step")
                 else:
-                    logits, starved = self.backend.step(self._next_tok,
-                                                        occ_mask)
-                    logits = np.asarray(logits, np.float32)
-            t_fetch = step.t1 or time.perf_counter()
+                    logits = self._to_host(out, "step")
             with _tracing.phase("engine.sample", "engine",
                                 tick=n) as sample:
                 now = time.monotonic()
@@ -784,18 +816,6 @@ class SlotScheduler:
                 self.stats["slot_ticks"] += len(occupied)
             tick_dur = (sample.t1 or time.perf_counter()) - t0
             _TM_TICK.observe(tick_dur)
-            if _tm.perf.enabled() and occupied:
-                # perf-attribution plane (docs/perf_attr.md): the tick
-                # wall splits into the decode dispatch (step + the one
-                # logits fetch above) and the host sampling loop — the
-                # stamps the tick already takes, no extra device sync
-                _tm.perf.record_dispatch(
-                    "decode_step_paged"
-                    if getattr(self.backend, "paged", False)
-                    else "decode_step_slots", t_fetch - t0)
-                _tm.perf.record_step_buckets(
-                    wall_s=tick_dur, dispatch=t_fetch - t0,
-                    sample=tick_dur - (t_fetch - t0))
             for i, req in tick_reqs:
                 _tracing.record_span(
                     "decode_tick", "replica", req.trace, tick_dur,

@@ -14,7 +14,7 @@ Three ledgers, all host-side, all pure arithmetic:
   row — the capture never raises and never runs when the plane is
   disarmed.
 - **runtime attribution** — the already-timed dispatch sites
-  (executor fwd/fwdbwd, FusedTrainer.step, the serving tick) feed a
+  (executor fwd/fwdbwd, FusedTrainer.step) feed a
   per-program cumulative host-wall ledger, and the fit loops split
   each step's wall into ``data_wait`` / ``dispatch`` /
   ``window_stall`` buckets (plus the epoch-boundary ``boundary_sync``
@@ -177,8 +177,7 @@ _TM_ROOFLINE = _reg.gauge(
 _TM_STEP_TIME = _reg.counter(
     "step_time_seconds",
     "cumulative step wall split into buckets (data_wait/dispatch/"
-    "window_stall per step; boundary_sync at epoch boundaries; "
-    "sample at serving ticks)",
+    "window_stall per step; boundary_sync at epoch boundaries)",
     labels=("bucket",))
 
 # ---------------------------------------------------------------------------

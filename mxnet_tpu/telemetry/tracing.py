@@ -134,9 +134,11 @@ def sample_rate() -> float:
         return 1.0
 
 
-# a busy engine writes ~270 spans a second (three a tick, three an
-# admission, at 26 ms a tick): the default holds a minute of them, so
-# that a reader of a 10 s window finds its first spans still there
+# a busy engine writes ~470 spans a second (six a tick, five an
+# admission and its request's record, at 25 ms a tick and an admission
+# a tick: 4,600-4,800 in a traced 10 s window of the benchmark): the
+# default holds half a minute of them, so that a reader of a 10 s
+# window finds its first spans still there
 _SPAN_RING = 16384
 
 
@@ -278,10 +280,14 @@ class _Phase:
     """One open :func:`phase`.  ``t0`` / ``t1`` are its
     ``time.perf_counter()`` stamps (``t1`` once closed), so that a
     caller which needs the same duration for another sink takes it from
-    here and not from a second pair of clock reads."""
+    here and not from a second pair of clock reads.  ``start_ns`` is
+    its start on the clock the profiler stamps the annotation with
+    (``time.time_ns()``: the host plane's events count nanoseconds of
+    the same wall clock), read once, right after the annotation
+    opened."""
 
     __slots__ = ("name", "svc", "trace", "attrs", "parent", "sid", "t0",
-                 "t1", "_annotation")
+                 "t1", "start_ns", "_annotation")
 
     def __init__(self, name, svc, trace, parent, step, attrs):
         self.name, self.svc, self.trace = name, svc, trace
@@ -303,6 +309,7 @@ class _Phase:
             self.parent = stack[-1].sid
         stack.append(self)
         self._annotation.__enter__()
+        self.start_ns = time.time_ns()
         self.t0 = time.perf_counter()
         return self
 
@@ -310,8 +317,12 @@ class _Phase:
         self.t1 = time.perf_counter()
         self._annotation.__exit__(*exc)
         _open_phases.stack.pop()
-        record_span(self.name, self.svc, self.trace, self.t1 - self.t0,
-                    parent=self.parent, span=self.sid, **self.attrs)
+        dur_s = self.t1 - self.t0
+        # the end stamp on the start's clock: [t - dur_s, t] is then
+        # the annotation's interval, beside the device's operations
+        record_span(self.name, self.svc, self.trace, dur_s,
+                    t=self.start_ns * 1e-9 + dur_s, parent=self.parent,
+                    span=self.sid, start_ns=self.start_ns, **self.attrs)
         return False
 
 
@@ -321,10 +332,13 @@ def phase(name, svc, trace=None, parent=None, step=None, **attrs):
     on the profiler's clock, on its thread's line of the host plane,
     beside the device's operations, with ``attrs`` as its stats -- and,
     on exit, one record of the span ring (``GET /spans.json``) holding
-    ``dur_s``, the end stamp ``t``, ``attrs``, ``parent`` (the ``sid``
-    of the enclosing open phase on this thread, else the ``parent``
-    given) and ``prof: true`` when a profiler session was running at
-    exit (:func:`record_span`).  ``step=<n>`` makes the annotation a
+    ``dur_s``, ``start_ns`` (its start in nanoseconds of the wall
+    clock, which is the clock the profiler stamps the annotation and
+    the device's operations with), the end stamp ``t`` = ``start_ns *
+    1e-9 + dur_s``, ``attrs``, ``parent`` (the ``sid`` of the enclosing
+    open phase on this thread, else the ``parent`` given) and ``prof:
+    true`` when a profiler session was running at exit
+    (:func:`record_span`).  ``step=<n>`` makes the annotation a
     ``StepTraceAnnotation`` (the profiler's step view; the record holds
     ``step`` too).  While :func:`recording` is false this returns one
     shared do-nothing object: no allocation, no clock read, the ring

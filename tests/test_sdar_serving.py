@@ -442,6 +442,48 @@ def test_the_scheduler_serves_what_generate_gives(model, server, p_len,
     assert moved["tokens_unmasked"] == sum(len(f[3]) for f in forwards)
 
 
+def test_a_block_decoders_admission_is_a_launch_alone(server):
+    """Under a block decoder an admission fetches nothing: its
+    ``engine.prefill`` holds ``engine.launch`` and no more (a prompt
+    shorter than one block runs no program: not even that); a forward's
+    ``engine.step`` holds launch, wait and the fetch of both ``(B, n)``
+    arrays, int32 tokens and float32 probabilities."""
+    from mxnet_tpu.telemetry import tracing
+
+    _srv, sched = server
+    was = tracing.trace_on()
+    tracing.enable_tracing(True)
+    tracing.clear_spans()
+    try:
+        for p_len in (9, 3):
+            assert sched.generate(prompt_of(p_len, 5),
+                                  max_new_tokens=5).outcome == "ok"
+        spans = tracing.spans()
+    finally:
+        tracing.enable_tracing(was)
+        tracing.clear_spans()
+
+    def kids(parent):
+        mine = [s for s in spans if s["parent"] == parent["sid"]]
+        return [(s["name"], s.get("program"))
+                for s in sorted(mine, key=lambda s: s["start_ns"])]
+
+    long, short = [s for s in spans if s["name"] == "engine.prefill"]
+    assert kids(long) == [("engine.launch", "prefill")]
+    assert kids(short) == []
+    steps = [s for s in spans if s["name"] == "engine.step"]
+    assert steps
+    for step in steps:
+        assert kids(step) == [("engine.launch", "step"),
+                              ("engine.wait", "step"),
+                              ("engine.fetch", "step")]
+    fetches = [s for s in spans if s["name"] == "engine.fetch"]
+    # 3 slots of 4 positions, a token and a probability each
+    assert {f["bytes"] for f in fetches} == {3 * 4 * (4 + 4)}
+    assert len([s for s in spans if s["name"] == "engine.wait"]) \
+        == len(steps)
+
+
 def test_a_request_ends_at_the_block_that_holds_its_eos(model, server):
     _config, c, rp, _p = model
     _srv, sched = server

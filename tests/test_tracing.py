@@ -662,7 +662,7 @@ def test_prefill_span_holds_the_fetch(decoder, traced, monkeypatch):
 
     class Slow:
         def __init__(self, row):
-            self.row = row
+            self.row, self.size = row, row.size
 
         def __array__(self, dtype=None, copy=None):
             time.sleep(0.05)
@@ -684,3 +684,159 @@ def test_prefill_span_holds_the_fetch(decoder, traced, monkeypatch):
     admit = next(s for s in spans if s["name"] == "admit")
     assert admit["dur_s"] == next(
         s for s in spans if s["name"] == "engine.admit")["dur_s"]
+
+
+# ---------------------------------------------------------------------------
+# the leaves under every program of the engine thread (ISSUE 35)
+# ---------------------------------------------------------------------------
+LEAVES = ["engine.launch", "engine.wait", "engine.fetch"]
+
+
+def _end_ns(span):
+    return span["start_ns"] + span["dur_s"] * 1e9
+
+
+@pytest.mark.parametrize("parent,program,paged", [
+    ("engine.step", "step", False), ("engine.prefill", "prefill", False),
+    ("engine.prefill", "prefill", True),
+    ("engine.prefill_chunk", "chunk", True)])
+def test_a_program_span_holds_launch_wait_and_fetch(decoder, traced, parent,
+                                                    program, paged):
+    """Each program the engine thread runs is three leaves, one after
+    the other and nothing else: the backend's call, the device
+    finishing, the copy to the host.  On either backend; a prompt over
+    the largest bucket of a paged one goes in chunks."""
+    kw = dict(kv_block=4, prefill_buckets=(8,), paged_kernel="gather") \
+        if paged else {}
+    sched = SlotScheduler(decoder, num_slots=2, queue_size=8, **kw)
+    try:
+        for prompt in ([1, 2, 3], list(range(1, 15)) if paged else [4, 5]):
+            assert sched.generate(prompt, max_new_tokens=3,
+                                  temperature=0).outcome == "ok"
+    finally:
+        sched.close()
+    spans = tracing.spans()
+    whole = [s for s in spans if s["name"] == parent]
+    assert whole
+    by_sid = {s["sid"]: s for s in spans}
+    # nothing nests under a leaf
+    assert not any(by_sid.get(s["parent"], {}).get("name") in LEAVES
+                   for s in spans)
+    for p in whole:
+        kids = sorted((s for s in spans if s["parent"] == p["sid"]),
+                      key=lambda s: s["start_ns"])
+        assert [k["name"] for k in kids] == LEAVES
+        assert all(k["program"] == program for k in kids)
+        launch, wait, fetch = kids
+        # float32 logits: a row of the vocabulary a slot, or one row
+        assert fetch["bytes"] == 4 * V * (2 if program == "step" else 1)
+        if program != "step":
+            assert launch["bucket"] == p["bucket"]
+        # one open at a time, inside the parent, and all of it but the
+        # parent's own clock reads
+        assert p["start_ns"] <= launch["start_ns"]
+        assert _end_ns(launch) <= wait["start_ns"] + 1e3
+        assert _end_ns(wait) <= fetch["start_ns"] + 1e3
+        assert _end_ns(fetch) <= _end_ns(p) + 1e3
+        left = p["dur_s"] - sum(k["dur_s"] for k in kids)
+        assert 0 <= left < 5e-3, (p, kids)
+
+
+@pytest.mark.parametrize("looking", [False, True])
+def test_the_wait_is_taken_only_while_someone_looks(decoder, monkeypatch,
+                                                    looking):
+    """With ``recording()`` false a tick and an admission make the calls
+    they always made: one conversion of what the backend returned, no
+    ``block_until_ready``, the ring untouched.  While recording the same
+    sync is a wait and then the conversion."""
+    calls = {"block": 0, "array": 0, "programs": 0}
+
+    class Counted:
+        def __init__(self, value):
+            self.value, self.size = value, value.size
+            calls["programs"] += 1
+
+        def block_until_ready(self):
+            calls["block"] += 1
+            return self
+
+        def __array__(self, dtype=None, copy=None):
+            calls["array"] += 1
+            return np.asarray(self.value, dtype)
+
+    was = tracing.trace_on()
+    tracing.enable_tracing(looking)
+    tracing.clear_spans()
+    sched = SlotScheduler(decoder, num_slots=1, queue_size=4)
+    step, admit_chunk = sched.backend.step, sched.backend.admit_chunk
+
+    def counted_step(*a, **kw):
+        out, starved = step(*a, **kw)
+        return Counted(out), starved
+
+    monkeypatch.setattr(sched.backend, "step", counted_step)
+    monkeypatch.setattr(sched.backend, "admit_chunk",
+                        lambda *a, **kw: Counted(admit_chunk(*a, **kw)))
+    try:
+        assert tracing.recording() is looking
+        req = sched.generate([1, 2, 3], max_new_tokens=4, temperature=0)
+        assert req.outcome == "ok" and len(req.tokens) == 4
+        assert calls["programs"] == 1 + sched.stats["ticks"] == 4
+        assert calls["array"] == calls["programs"]
+        assert calls["block"] == (calls["programs"] if looking else 0)
+        names = [s["name"] for s in tracing.spans()]
+        assert names.count("engine.wait") == calls["block"]
+        if not looking:
+            assert names == []
+    finally:
+        sched.close()
+        tracing.enable_tracing(was)
+        tracing.clear_spans()
+
+
+def test_a_phase_starts_on_the_profilers_clock(tmp_path):
+    """``start_ns`` is the wall clock in nanoseconds, which is what the
+    profiler stamps a ``TraceAnnotation`` with: an event of the host
+    plane stands at ``start_ns`` less the session's
+    ``profile_start_time`` (a stat of the plane ``Task Environment``).
+    The end stamp is on that clock too."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    tracing.enable_tracing(False)
+    tracing.clear_spans()
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        for i in range(4):
+            with tracing.phase("clock.outer", "engine", i=i):
+                with tracing.phase("clock.inner", "engine", i=i):
+                    time.sleep(0.002)
+    finally:
+        jax.profiler.stop_trace()
+    try:
+        spans = [s for s in tracing.spans() if s["name"] == "clock.inner"]
+        path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                          recursive=True)
+        events, origin = [], None
+        for plane in ProfileData.from_file(path).planes:
+            if plane.name == "Task Environment":
+                origin = dict(plane.stats)["profile_start_time"]
+            for line in plane.lines:
+                events += [ev for ev in line.events
+                           if ev.name == "clock.inner"]
+        assert len(events) == len(spans) == 4 and origin
+        events.sort(key=lambda ev: ev.start_ns)
+        for ev, s in zip(events, spans):
+            assert s["prof"] is True
+            assert abs(s["start_ns"] - (origin + ev.start_ns)) < 1e6
+            assert abs(s["dur_s"] * 1e9 - ev.duration_ns) < 1e6
+            assert abs((s["t"] - s["dur_s"]) - s["start_ns"] * 1e-9) < 1e-6
+        # a record written directly keeps its form
+        direct = tracing.record_span("direct", "engine", None, 0.5)
+        assert "start_ns" not in direct
+    finally:
+        tracing.clear_spans()
