@@ -2427,40 +2427,23 @@ def _bench(dev, kind, init_notes=(), init_attempts=1):
                 cfg = MFU_HEADLINE_CONFIG
                 Tm, Vm = cfg["seq_len"], cfg["vocab_size"]
                 Bm = int(os.environ.get("BENCH_LM_MFU_BATCH", "8"))
-                # flash-attention tile size from the same sweep (read at
-                # trace time); restored after the trainer is built
-                blk = os.environ.get("BENCH_LM_MFU_BLOCK", "256x256")
-                old_blk = (os.environ.get("MXTPU_FLASH_BLOCK_Q"),
-                           os.environ.get("MXTPU_FLASH_BLOCK_K"))
-                bq, bk = blk.split("x")
-                os.environ["MXTPU_FLASH_BLOCK_Q"] = bq
-                os.environ["MXTPU_FLASH_BLOCK_K"] = bk
-                try:
-                    big_lm = models.transformer.transformer_lm(**cfg)
-                    mtr = FusedTrainer(big_lm, optimizer="adam",
-                                       optimizer_params={"lr": 1e-4},
-                                       dtype=dtype)
-                    mtr.init(data=(Bm, Tm), softmax_label=(Bm, Tm))
-                    mtoks = jax.device_put(rs.randint(
-                        0, Vm, (Bm, Tm)).astype(np.float32))
-                    mlabs = jax.device_put(rs.randint(
-                        0, Vm, (Bm, Tm)).astype(np.float32))
-                    mtr.step(data=mtoks, softmax_label=mlabs)  # compile
-                    mname = sorted(mtr.params)[0]
-                    mbarrier = lambda: float(
-                        np.asarray(mtr.params[mname]).ravel()[0])
-                    mbarrier()
-                    mdt = _time_steps(
-                        lambda: mtr.step(data=mtoks, softmax_label=mlabs),
-                        mbarrier, 10)
-                finally:
-                    for env_k, env_v in zip(("MXTPU_FLASH_BLOCK_Q",
-                                             "MXTPU_FLASH_BLOCK_K"),
-                                            old_blk):
-                        if env_v is None:
-                            os.environ.pop(env_k, None)
-                        else:
-                            os.environ[env_k] = env_v
+                big_lm = models.transformer.transformer_lm(**cfg)
+                mtr = FusedTrainer(big_lm, optimizer="adam",
+                                   optimizer_params={"lr": 1e-4},
+                                   dtype=dtype)
+                mtr.init(data=(Bm, Tm), softmax_label=(Bm, Tm))
+                mtoks = jax.device_put(rs.randint(
+                    0, Vm, (Bm, Tm)).astype(np.float32))
+                mlabs = jax.device_put(rs.randint(
+                    0, Vm, (Bm, Tm)).astype(np.float32))
+                mtr.step(data=mtoks, softmax_label=mlabs)  # compile
+                mname = sorted(mtr.params)[0]
+                mbarrier = lambda: float(
+                    np.asarray(mtr.params[mname]).ravel()[0])
+                mbarrier()
+                mdt = _time_steps(
+                    lambda: mtr.step(data=mtoks, softmax_label=mlabs),
+                    mbarrier, 10)
                 mtok_s = Bm * Tm * 10 / mdt
                 fpt = lm_train_flops_per_token(
                     cfg["num_layers"], cfg["d_model"], cfg["d_ff"], Tm, Vm)
@@ -2469,9 +2452,9 @@ def _bench(dev, kind, init_notes=(), init_attempts=1):
                 extras["transformer_lm_mfu_tokens_per_sec"] = round(
                     mtok_s, 0)
                 extras["transformer_lm_mfu_config"] = (
-                    "L%d D%d ff%d T%d V%d b%d blk%s %s" % (
+                    "L%d D%d ff%d T%d V%d b%d %s" % (
                         cfg["num_layers"], cfg["d_model"], cfg["d_ff"],
-                        Tm, Vm, Bm, blk, jnp.dtype(dtype).name))
+                        Tm, Vm, Bm, jnp.dtype(dtype).name))
         except Exception as exc:  # noqa: BLE001
             extras["lm_mfu_error"] = repr(exc)  # the headline must not
             #                                     vanish behind an earlier

@@ -113,7 +113,7 @@ def lm_train_flops_per_token(num_layers, d_model, d_ff, seq_len,
     """Model-FLOP cost of ONE training token, conservative accounting:
     6 * matmul-params (qkv/proj, ffn, head; embedding gathers are free)
     plus causal-halved flash attention (6*L*T*D — the Pallas kernel
-    skips fully-masked key blocks, ops/flash_attention.py:48-63)."""
+    skips fully-masked key blocks, ops/flash_attention.py)."""
     n_mat = (num_layers * (4 * d_model * d_model + 2 * d_model * d_ff)
              + d_model * vocab_size)
     return 6 * n_mat + 6 * num_layers * seq_len * d_model

@@ -18,7 +18,6 @@ ring direction automatically).
 """
 from __future__ import annotations
 
-import os
 
 import jax
 import jax.numpy as jnp
@@ -191,22 +190,14 @@ def attention(q, k, v, causal=False, scale=None, impl="auto", platform=None,
     heads, no collective."""
     from ..ops import flash_attention as fa
 
-    # kernel tile sizes are a measured quantity, not a constant:
-    # MXTPU_FLASH_BLOCK_Q/K let the on-silicon sweeps
-    # (tools/probe_lm_mfu.py) tune them without code edits.  Clamped to
-    # T (matching flash_attention's own clamp) BEFORE the supports()
-    # check so an oversized tile cannot silently demote a
-    # flash-compatible shape to the O(T^2) lax path.
-    bq = min(_env_block("MXTPU_FLASH_BLOCK_Q"), q.shape[2])
-    bk = min(_env_block("MXTPU_FLASH_BLOCK_K"), q.shape[2])
     if impl == "auto":
         on_tpu = (platform or jax.default_backend()) == "tpu"
-        impl = "flash" if on_tpu and fa.supports(q.shape, bq, bk) else "lax"
+        impl = "flash" if on_tpu and fa.supports(q.shape, q.dtype) else "lax"
     if impl in ("flash", "flash_interpret"):
         # flash_interpret: the CPU test path for the kernels
         def kernel(q, k, v):
-            return fa.flash_attention(q, k, v, causal, scale, bq, bk,
-                                      impl == "flash_interpret")
+            return fa.flash_attention(q, k, v, causal, scale,
+                                      interpret=impl == "flash_interpret")
 
         spec = _kernel_spec(mesh, q.shape)
         if spec is not None:
@@ -214,22 +205,3 @@ def attention(q, k, v, causal=False, scale=None, impl="auto", platform=None,
                                    out_specs=spec, check_vma=False)
         return kernel(q, k, v)
     return full_attention(q, k, v, causal=causal, scale=scale)
-
-
-def _env_block(name, default=128):
-    """Tile-size env knob: malformed or non-positive values fall back to
-    the default with a warning instead of crashing unrelated paths."""
-    raw = os.environ.get(name)
-    if raw is None:
-        return default
-    try:
-        val = int(raw)
-    except ValueError:
-        val = 0
-    if val <= 0:
-        import warnings
-
-        warnings.warn(f"{name}={raw!r} is not a positive integer; "
-                      f"using {default}", stacklevel=3)
-        return default
-    return val

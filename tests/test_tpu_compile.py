@@ -89,24 +89,62 @@ def _compile(fn, *shapes):
 
 
 # ------------------------------------------------------------------ flash
-_QKV = (8, 16, 1024, 128)     # lm-560m: B8 H16 T1024 dh128
+_QKV = (8, 16, 1024, 128)     # lm_train: B8 H16 T1024 dh128
 
 
-@pytest.mark.parametrize("tile", [128, 256])
+def _flash_tiles(kernel, tile):
+    """``tile`` a side, or what ``fa.tiles`` chooses for ``kernel`` at
+    the benchmark's shape (the step's own kernels)."""
+    if tile == "chosen":
+        return fa.tiles(_QKV[2], _QKV[3], jnp.bfloat16)[kernel]
+    return tile, tile
+
+
+@pytest.mark.parametrize("tile", [128, 256, "chosen"])
 def test_flash_fwd_compiles(one_chip, tile):
-    assert fa.supports(_QKV, tile, tile)
+    bq, bk = _flash_tiles("fwd", tile)
+    assert fa.supports(_QKV, jnp.bfloat16)
     q = one_chip(_QKV, "bfloat16")
     _compile(lambda q, k, v: fa._fwd_impl(
-        q, k, v, 1.0 / 128 ** 0.5, True, tile, tile, False), q, q, q)
+        q, k, v, 1.0 / 128 ** 0.5, True, bq, bk, False), q, q, q)
 
 
-@pytest.mark.parametrize("tile", [128, 256])
+@pytest.mark.parametrize("tile", [128, 256, "chosen"])
 def test_flash_bwd_compiles(one_chip, tile):
     q = one_chip(_QKV, "bfloat16")
     lse = one_chip(_QKV[:3], "float32")
-    _compile(lambda q, k, v, o, lse, do: fa._bwd_impl(
-        q, k, v, o, lse, do, 1.0 / 128 ** 0.5, True, tile, tile, False),
+    text = _compile(lambda q, k, v, o, lse, do: fa._bwd_impl(
+        q, k, v, o, lse, do, 1.0 / 128 ** 0.5, True,
+        _flash_tiles("dq", tile), _flash_tiles("dkv", tile), False),
         q, q, q, q, lse, q)
+    # the row statistics travel lane-dense: a (T, 1) column is one value
+    # a 128-lane tile, 67 MB an array at this shape
+    assert "f32[128,1024,1]" not in text
+
+
+@pytest.mark.parametrize("causal", [True, False])
+def test_flash_fwd_compiles_in_float32(one_chip, causal):
+    """float32 inputs keep float32 products (``contract_precision<fp32>``
+    and tiles of 4 B): the chosen tiles must still fit."""
+    bq, bk = fa.tiles(_QKV[2], _QKV[3], jnp.float32)["fwd"]
+    q = one_chip(_QKV, "float32")
+    _compile(lambda q, k, v: fa._fwd_impl(
+        q, k, v, 1.0 / 128 ** 0.5, causal, bq, bk, False), q, q, q)
+
+
+@pytest.mark.parametrize("t,d,dtype", [
+    (2048, 128, "bfloat16"), (8192, 128, "bfloat16"), (16384, 64, "bfloat16"),
+    (192, 64, "bfloat16"), (512, 64, "float32"), (2048, 128, "float32"),
+    (4096, 128, "float32")])
+def test_flash_chosen_tiles_fit_vmem(one_chip, t, d, dtype):
+    """Who else runs the kernels: other lengths, narrow heads, float32.
+    ``tiles`` steps down its ladder by a model of what the kernels ask
+    of VMEM; Mosaic has the last word, here, for the whole custom_vjp."""
+    assert fa.supports((1, 2, t, d), dtype)
+    q = one_chip((1, 2, t, d), dtype)
+    _compile(jax.grad(lambda q, k, v: jnp.sum(
+        fa.flash_attention(q, k, v, True).astype(jnp.float32) ** 2),
+        argnums=(0, 1, 2)), q, q, q)
 
 
 # ------------------------------------------------------------------ paged

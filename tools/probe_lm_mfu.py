@@ -11,7 +11,7 @@ FLOP accounting (conservative, causal-halved):
   train FLOPs/token = 6*N_mat + 6*L*T*D
 where N_mat counts matmul params only (embedding gathers are free) —
 the standard 6N rule with flash attention's causal block skipping
-(ops/flash_attention.py:48-63) counted at half the full T^2 cost.
+(ops/flash_attention.py) counted at half the full T^2 cost.
 
 Run on the bench chip:  python tools/probe_lm_mfu.py
 CPU smoke:  MXTPU_PLATFORM=cpu python tools/probe_lm_mfu.py --smoke
@@ -77,18 +77,16 @@ def run_config(name, L, H, D, d_ff, T, V, B, iters=12, peak=PEAK_BF16):
     return mfu
 
 
-def run_one_subprocess(name, cfg, iters, extra_env=None, timeout=420):
+def run_one_subprocess(name, cfg, iters, timeout=420):
     """One config in its own process: a failed/OOMed config must not
     poison the rest of the sweep (the first on-silicon capture lost 3
     configs to a RESOURCE_EXHAUSTED cascade after one real OOM).  The
     parent never touches JAX, so each child in turn is the one process
     that holds the chip."""
-    env = dict(os.environ)
-    env.update(extra_env or {})
     spec = json.dumps({"name": name, "cfg": cfg, "iters": iters})
     try:
         r = subprocess.run([sys.executable, os.path.abspath(__file__),
-                            "--one", spec], env=env, capture_output=True,
+                            "--one", spec], capture_output=True,
                            text=True, timeout=timeout)
     except subprocess.TimeoutExpired:
         print(f"{name}: FAILED timeout", flush=True)
@@ -148,20 +146,6 @@ def main():
         if mfu > best[1]:
             best = (name, mfu, cfg)
     print(f"best: {best[0]} mfu={best[1]:.3f}", flush=True)
-
-    # flash-attention tile sweep on the winner (MXTPU_FLASH_BLOCK_Q/K
-    # are read at trace time, so each setting builds a fresh trainer)
-    if best[2] is not None:
-        tile_best = ("128x128", best[1])
-        for bq, bk in ((256, 256), (128, 512), (512, 128)):
-            mfu = run_one_subprocess(
-                f"{best[0]}-blk{bq}x{bk}", best[2], args.iters,
-                extra_env={"MXTPU_FLASH_BLOCK_Q": str(bq),
-                           "MXTPU_FLASH_BLOCK_K": str(bk)})
-            if mfu > tile_best[1]:
-                tile_best = (f"{bq}x{bk}", mfu)
-        print(f"best-tiles: {best[0]} blk{tile_best[0]} "
-              f"mfu={tile_best[1]:.3f}", flush=True)
 
 
 if __name__ == "__main__":
