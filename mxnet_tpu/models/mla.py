@@ -18,7 +18,10 @@ the 576-wide cache rows ``[latent | rotary key]``.
   pages (a cached prefix, or the chunks of the same prompt that went
   before): the tail attends over ``[history | own rows]``, the history
   a block of keys at a time for as many blocks as it has, joined by a
-  running maximum and sum.
+  running maximum and sum -- the Pallas kernel
+  ``ops/latent_attention.py:latent_prefill`` over the pages where they
+  lie when the page rows' schedule puts it there, this module's XLA
+  form over the slot's rows looked up otherwise.
 
 A configuration object ``c`` gives ``heads``, ``kv_rank``, ``nope``,
 ``rope``, ``v_dim`` and, where its rotary part is YaRN-scaled,
@@ -203,12 +206,22 @@ def mla_expanded(q_nope, q_rope, rows, w_kvb, c, history=None,
     ``q_rope`` (T, H, 64), ``rows`` (T, 576).  Keys and values are
     expanded from the latent rows; causal, a block of queries at a time
     over the keys up to its end.  Without ``history`` the sequence
-    starts at position 0; with it (see :func:`_over_history`) the
-    sequence stands behind ``hist`` rows of the slot's pages and every
-    query attends over them too, ``key_block`` of them at a time, in
-    one softmax with its own rows.
+    starts at position 0; with it, an
+    ``ops/latent_attention.py:PrefillHistory`` or a plain ``(table,
+    hist)``, the sequence stands behind ``hist`` rows of the slot's
+    pages and every query attends over them too, in one softmax with
+    its own rows: on the kernel ``latent_prefill`` where the page rows'
+    schedule says so (it reads history and tail from the pages), else
+    over the slot's rows looked up, ``key_block`` of them at a time
+    (:func:`_over_history`).
     Returns (T, H * 128)."""
     T, H = q_nope.shape[0], c.heads
+    if history is not None:
+        if _la.prefill_on_kernel(history, c.kv_rank, c.nope, c.v_dim):
+            return _la.latent_prefill(q_nope, q_rope, w_kvb, history,
+                                      nope=c.nope, v_dim=c.v_dim,
+                                      denominator=_denominator(c))
+        history = tuple(history)        # the slot's rows looked up
     k_nope, v, k_rope = _expand(rows, w_kvb, c)
     if history is not None:
         m_h, l_h, acc_h = _over_history(q_nope, q_rope, history, w_kvb, c,

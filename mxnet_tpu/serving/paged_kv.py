@@ -64,8 +64,10 @@ declare:
   live pages by the schedule's lowering (the Pallas kernel, or a lookup
   of the slot's table and the dense form over it); in a prefill
   ``view.append`` writes the tail's rows a page at a time and hands
-  back this layer's rows of the slot and ``hist``, how many of them
-  stand before the tail.  No program slices one layer out of the pool
+  back the pool, the slot's table row, ``hist`` (how many positions
+  stand before the tail) and the page rows' schedule, by which the
+  decoder reads the slot's pages where they lie (a kernel) or looks
+  this layer's rows up.  No program slices one layer out of the pool
   first: that is a copy of the layer (666 MB at the Kimi cell's size,
   2 ms, before ISSUE 34);
 - ``kv_pages`` AND ``pages`` side by side, the K/V pools and page rows
@@ -510,21 +512,23 @@ class _PrefillView(_CacheView):
     def append(self, name, layer, rows):
         """``rows`` (T, W): the tail's page rows, written whole pages at
         a time like the K/V pools'.  Returns what stands before the
-        tail: ``(table (S, W), hist)``, this layer's rows of the slot
-        looked up in the pool by ``(layer, page)`` (whole pages; never
-        a slice of the layer first) and how many of them, from position
-        0 on, are the history; the rest of the table is not the
-        caller's to read.  ``None`` for a layout that can have no
+        tail, an ``ops/latent_attention.py:PrefillHistory``: the pool
+        just written, the slot's table row, ``layer``, ``W``, ``hist``
+        and ``length``, and the page rows' schedule, by which the
+        decoder reads the slot's pages where they lie (the kernel); it
+        unpacks as ``(table (S, W), hist)``, this layer's rows of the
+        slot looked up by ``(layer, page)`` (whole pages; never a slice
+        of the layer first) and how many of them, from position 0 on,
+        are the history.  ``None`` for a layout that can have no
         history (``prefix_reuse`` False)."""
-        import jax.numpy as jnp
-
         from ..ops import latent_attention as _la
 
         pool = self._put_page_rows(name, layer, rows)
         if not self._pg.layout["prefix_reuse"]:
             return None
-        return _la.page_table(pool, self._bt_row, layer, rows.shape[1]), \
-            self._hist
+        return _la.PrefillHistory(pool, self._bt_row, layer, rows.shape[1],
+                                  self._hist, self.length,
+                                  self._pg.page_schedules[name])
 
     def _put_page_rows(self, name, layer, rows):
         """The tail's page rows ``rows`` (T, W), whole pages at a time

@@ -73,17 +73,23 @@ def _last_row(params, c, prompt):
 
 
 # ------------------------------------------- chunks = one program = reference
+# under gather (the XLA form over the slot's rows looked up) and under the
+# prefill kernel interpreted, which reads history and tail from the pages
+LOWERINGS = pytest.mark.parametrize("kernel", ["gather", "interpret"])
+
+
+@LOWERINGS
 @pytest.mark.parametrize("plen", [
     pytest.param(65, id="one_token_over_a_chunk"),
     pytest.param(100, id="a_chunk_and_a_part"),
     pytest.param(128, id="two_whole_chunks"),
     pytest.param(203, id="three_chunks_and_a_part")])
-def test_a_prompt_in_chunks_is_the_prompt_in_one_program(kimi, plen):
+def test_a_prompt_in_chunks_is_the_prompt_in_one_program(kimi, plen, kernel):
     config, c, params, decoder = kimi
     prompt = np.random.default_rng(plen).integers(0, config["vocab_size"],
                                                   plen)
-    chunked = _slots(decoder, (32, 64))
-    whole = _slots(decoder, (32, 64, MAX_LEN))
+    chunked = _slots(decoder, (32, 64), kernel=kernel)
+    whole = _slots(decoder, (32, 64, MAX_LEN), kernel=kernel)
     before = chunked.stats()["prefill_chunks"]
     got = np.asarray(chunked.admit(0, prompt), np.float32)
     assert chunked.stats()["prefill_chunks"] - before == -(-plen // 64)
@@ -104,7 +110,8 @@ def test_a_prompt_in_chunks_is_the_prompt_in_one_program(kimi, plen):
 
 
 # ----------------------------------------------------------- the prefix index
-def test_a_prefix_hit_gives_the_logits_of_a_cold_admission(kimi):
+@LOWERINGS
+def test_a_prefix_hit_gives_the_logits_of_a_cold_admission(kimi, kernel):
     """The second ask of a document finds the document's whole pages in
     the index and prefills the question alone, over the document's
     latent rows: same logits as a backend that never saw the document,
@@ -114,7 +121,7 @@ def test_a_prefix_hit_gives_the_logits_of_a_cold_admission(kimi):
     doc = rng.integers(0, config["vocab_size"], 150)
     asks = [np.concatenate([doc, rng.integers(0, config["vocab_size"], n)])
             for n in (9, 21, 5)]
-    slots = _slots(decoder, (32, 64))
+    slots = _slots(decoder, (32, 64), kernel=kernel)
     for k, prompt in enumerate(asks):
         before = slots.stats()
         got = np.asarray(slots.admit(0, prompt), np.float32)
@@ -124,8 +131,8 @@ def test_a_prefix_hit_gives_the_logits_of_a_cold_admission(kimi):
         assert after["prompt_tokens"] - before["prompt_tokens"] == len(prompt)
         # the document's 9 whole pages, once the first ask has left them
         assert hit == (150 // BLOCK * BLOCK if k else 0)
-        cold = np.asarray(_slots(decoder, (32, 64)).admit(0, prompt),
-                          np.float32)
+        cold = np.asarray(_slots(decoder, (32, 64), kernel=kernel).admit(
+            0, prompt), np.float32)
         want = _last_row(params, c, prompt)
         assert np.max(np.abs(got - want)) < LOGIT_TOL
         assert np.max(np.abs(cold - want)) < LOGIT_TOL

@@ -251,6 +251,36 @@ def test_latent_gate_rejects_what_mosaic_rejects(one_chip, block, lanes, why):
                                block=block, lanes=lanes)
 
 
+@pytest.mark.parametrize("T", [2048, 128], ids=["a_chunk", "a_short_tail"])
+def test_latent_prefill_chosen_tiles_fit_vmem(one_chip, T):
+    """The prefill kernel alone at the Kimi cell's shapes, with the
+    tiles :func:`prefill_tiles` chooses: 64 heads, a pool of 6 layers of
+    36,161 pages and a table of 1,088 (17,408 positions: the grid walks
+    34 key blocks, enough for a chunk of 2,048 behind ``hist`` 15,360).
+    The queries whole in one tile, the group's running sums and
+    accumulator, two buffers of 32 pages: Mosaic takes what the model
+    of VMEM counts."""
+    H, M = 64, 1088
+    tl = la.prefill_tiles(T, H, 16, 640, 512, 128, 128, "bfloat16", M)
+    assert tl["query"] == T and tl["keys"] == 512
+    assert la._prefill_vmem(T, tl["sub"], 512, tl["heads"], 256, 256, 512,
+                            128, 640, 2) <= la._PREFILL_VMEM
+
+    def run(q_nope, q_rope, w, pool, bt_row, hist, length):
+        return la.latent_prefill(
+            q_nope, q_rope, w, la.PrefillHistory(
+                pool, bt_row, 5, 576, hist, length, {"impl": "pallas"}),
+            nope=128, v_dim=128, denominator=7.64)
+
+    text = _compile(run, one_chip((T, H, 128), "bfloat16"),
+                    one_chip((T, H, 64), "bfloat16"),
+                    one_chip((H * 256, 512), "bfloat16"),
+                    one_chip((6, _KIMI_PAGES, 16, 640), "bfloat16"),
+                    one_chip((M,), "int32"), one_chip((), "int32"),
+                    one_chip((), "int32"))
+    assert "latent_prefill" in text
+
+
 def test_a_page_that_is_one_row_of_a_matrix_cannot_be_copied(one_chip):
     """Why the pool changed shape in ISSUE 34: out of PR 33's ``(L, P,
     block * 576)`` a page is one row, strewn over 72 tiles of 8 pages x
@@ -749,7 +779,11 @@ _KIMI_SLABS = ["bf16[%d,%d,640]" % (_KIMI_PAGES, _BLOCK),
                "bf16[1,%d,%d,640]" % (_KIMI_PAGES, _BLOCK),
                "bf16[%d,9216]" % _KIMI_PAGES]
 _KIMI_TABLES = ["bf16[16,1088,16,640]", "bf16[16,17408,640]",
-                "bf16[17408,16,640]", "bf16[17408,9216]"]
+                "bf16[17408,16,640]", "bf16[17408,9216]",
+                "bf16[1088,16,640]", "bf16[17408,576]"]
+# the XLA form's history loop over 512 keys of a chunk: scores, running
+# sums, accumulator, in float32 through HBM
+_KIMI_HISTORY_LOOP = ["f32[64,2048,512]", "f32[2048,64,128]"]
 
 
 def _kimi_programs(sds, latent="gather"):
@@ -783,21 +817,23 @@ def _kimi_programs(sds, latent="gather"):
 
 @pytest.mark.parametrize("which,latent", [
     ("step", "kernel"), ("step", "gather"), (128, "gather"),
-    (2048, "gather")])
+    (2048, "gather"), (128, "kernel"), (2048, "kernel")])
 def test_latent_page_program_copies_no_pool(one_chip, which, latent):
     """The Kimi step (a latent-attention kernel a layer over each slot's
     live pages; under the other lowering the slots' tables looked up by
-    layer and page) and its prefills (the tail's pages written, this
-    layer's rows of the slot looked up, a loop over the history's key
-    blocks) at the benchmark's sizes: no instruction copies the 4.4 GB
-    latent pool, it is written in place in the donated buffer, NO ARRAY
-    OF ONE LAYER OF IT exists anywhere (PR 33's ``pool[layer]`` was a
-    666 MB copy before each gather), and the program fits the chip
-    beside its 12.8 GB of arguments -- the largest prefill's blocks of
-    scores among the temporaries.  Under the kernel the step holds no
-    table of the slots' rows either and next to no temporaries; under
-    the other lowering it holds one a layer, which shows that the
-    check can see a table."""
+    layer and page) and its prefills (the tail's pages written, then a
+    ``latent_prefill`` kernel a layer over the slot's pages where they
+    lie; under the other lowering this layer's rows of the slot looked
+    up and a loop over the history's key blocks) at the benchmark's
+    sizes: no instruction copies the 4.4 GB latent pool, it is written
+    in place in the donated buffer, NO ARRAY OF ONE LAYER OF IT exists
+    anywhere (PR 33's ``pool[layer]`` was a 666 MB copy before each
+    gather), and the program fits the chip beside its 12.8 GB of
+    arguments.  Under the kernel neither program holds a table of the
+    slots' rows, the prefill no loop and no block of float32 scores;
+    under the other lowering both hold the tables, and the prefill the
+    loop and, at 2,048 rows, its scores, which shows that the check can
+    see them."""
     lowered, _ = _lower(_kimi_programs(one_chip, latent), one_chip, which,
                         _KIMI_SLOTS, _KIMI_MAX_LEN // _BLOCK)
     compiled = lowered.compile()
@@ -808,12 +844,25 @@ def test_latent_page_program_copies_no_pool(one_chip, which, latent):
     mem = compiled.memory_analysis()
     assert 4.4e9 < pool_bytes <= mem.alias_size_in_bytes < 1.001 * pool_bytes
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.5e9
+    assert bool(_arrays_of(text, _KIMI_TABLES)) == (latent == "gather")
     if which != "step":
-        assert "while" in text      # the history's loop is a loop still
+        loops = [op for _n, _r, op in _instructions(text) if op == "while"]
+        ours = len(re.findall(r'custom-call\(.*latent_prefill', text))
+        scores = _arrays_of(text, _KIMI_HISTORY_LOOP)
+        if latent == "gather":
+            # the history's loop is a loop still, a layer
+            assert len(loops) == 6 and ours == 0
+            assert bool(scores) == (which == 2048)
+            return
+        assert (ours, loops, scores) == (6, [], [])
+        # a short tail's program holds little beside the layer's
+        # projections (97.6 MB under the other lowering); a chunk's
+        # temporaries are the head's and the experts' float32 copies
+        # (S16), the history's ~0.3 GB of scores and sums are gone
+        assert mem.temp_size_in_bytes < (32e6 if which == 128 else 1.035e9)
         return
     ours = len(re.findall(r'custom-call\(.*latent_attn', text))
     assert ours == (6 if latent == "kernel" else 0)
-    assert bool(_arrays_of(text, _KIMI_TABLES)) == (latent == "gather")
     if latent == "kernel":
         assert mem.temp_size_in_bytes < 64e6
     else:
