@@ -57,14 +57,12 @@ import jax
 import jax.numpy as jnp
 
 from .blocks import lin as _lin, moe_block, rms_norm, swiglu as _swiglu
+from .delta_rule import kda_chunked, kda_recurrent_step, l2norm, short_conv
 from .mla import (mla_absorbed, mla_absorbed_paged, mla_expanded,
                   rope as _rope, rope_freq)
 
 __all__ = ["LingConfig", "LingDecoder", "kda_recurrent_step", "kda_chunked",
            "mla_absorbed", "mla_expanded"]
-
-HIGHEST = jax.lax.Precision.HIGHEST
-KDA_CHUNK = 16      # |g| <= 5 a token: exp(+-40) stays well inside float32
 
 
 @dataclasses.dataclass(frozen=True)
@@ -126,83 +124,6 @@ class LingConfig:
             mixers=tuple("mla" if (i + off + 1) % period == 0 else "kda"
                          for i in range(n)),
             mlps=tuple("dense" if i < dense else "moe" for i in range(n)))
-
-
-# ------------------------------------------------------------- pieces
-def _l2(x):
-    return x * jax.lax.rsqrt(jnp.sum(jnp.square(x), -1, keepdims=True)
-                             + 1e-6)
-
-
-# ---------------------------------------------------------------- KDA
-def kda_recurrent_step(q, k, v, g, beta, S):
-    """One position for every row: ``q, k, g`` (B, H, d_k), ``v`` (B, H,
-    d_v), ``beta`` (B, H), ``S`` (B, H, d_k, d_v), all float32.  The
-    state is read twice and written once: both contractions with the
-    decayed state (``k`` for the delta rule's prediction, ``q`` for the
-    output) come from one pass, and ``o = S'^T q = (a S)^T q + (q.k) u``
-    spares the third."""
-    a = jnp.exp(g)
-    pred = jnp.sum(S * (k * a)[..., None], axis=-2)
-    out0 = jnp.sum(S * (q * a)[..., None], axis=-2)
-    u = beta[..., None] * (v - pred)
-    S = a[..., None] * S + k[..., None] * u[..., None, :]
-    return out0 + jnp.sum(q * k, -1, keepdims=True) * u, S
-
-
-def kda_chunked(q, k, v, g, beta, S0, chunk=KDA_CHUNK):
-    """The same recurrence over one sequence in chunks: ``q, k, g`` (T,
-    H, d_k), ``v`` (T, H, d_v), ``beta`` (T, H), ``S0`` (H, d_k, d_v);
-    ``T`` a multiple of ``chunk``.  Returns ``(o (T, H, d_v), S_T)``.
-
-    Inside a chunk, with ``G_t`` the running sum of ``g`` (a channel's
-    log decay since the chunk began) and ``u_t = beta_t (v_t - S_{t-1}^T
-    Diag(alpha_t) k_t)``:  ``(I + A) U = beta V - beta (K e^G) S_0`` with
-    ``A_ti = beta_t (k_t e^{G_t}) . (k_i e^{-G_i})`` for ``i < t``;  ``O =
-    (Q e^G) S_0 + tril(B) U`` with ``B_ti = (q_t e^{G_t}) . (k_i
-    e^{-G_i})``;  ``S_C = Diag(e^{G_C}) S_0 + (K e^{G_C - G})^T U``.
-    Everything that does not hold the state is computed for all chunks
-    at once; the scan carries the state alone.  The two factors of a
-    pairwise decay are taken from the chunk's middle, so their exponents
-    reach ``+-5 chunk / 2``, which float32 holds with room for chunks of
-    16 (the "safe gate"'s lower bound of -5 a token); a position with
-    ``beta = 0`` and ``g = 0`` leaves the state as it is (right
-    padding)."""
-    T, H, dk = q.shape
-    dv = v.shape[-1]
-    n = T // chunk
-
-    def blocks(a):
-        return a.reshape(n, chunk, H, -1).transpose(0, 2, 1, 3)
-
-    q, k, v, g = blocks(q), blocks(k), blocks(v), blocks(g)  # (n, H, C, d)
-    beta = beta.reshape(n, chunk, H).transpose(0, 2, 1)[..., None]
-    G = jnp.cumsum(g, axis=2)
-    k_in, q_in = k * jnp.exp(G), q * jnp.exp(G)
-    mm = lambda spec, a, b: jnp.einsum(spec, a, b, precision=HIGHEST)
-    # the pairwise decays e^{G_t - G_i} as a product of two factors taken
-    # from the chunk's middle: exponents of at most +-5 chunk / 2
-    Gm = G - G[:, :, chunk // 2 - 1:chunk // 2]
-    k_out = k * jnp.exp(-Gm)
-    A = jnp.tril(mm("nhtd,nhid->nhti", k * jnp.exp(Gm), k_out) * beta, -1)
-    B = jnp.tril(mm("nhtd,nhid->nhti", q * jnp.exp(Gm), k_out))
-    rhs = jnp.concatenate([beta * v, beta * k_in], -1)
-    sol = jax.scipy.linalg.solve_triangular(
-        A + jnp.eye(chunk, dtype=A.dtype), rhs, lower=True,
-        unit_diagonal=True)
-    w_v, w_k = sol[..., :dv], sol[..., dv:]
-    g_end = G[:, :, -1]                                       # (n, H, d_k)
-    k_end = k * jnp.exp(g_end[:, :, None] - G)
-
-    def body(S, c):
-        w_v, w_k, q_in, B, k_end, g_end = c
-        U = w_v - mm("hck,hkv->hcv", w_k, S)
-        O = mm("hck,hkv->hcv", q_in, S) + mm("hti,hiv->htv", B, U)
-        S = jnp.exp(g_end)[..., None] * S + mm("hck,hcv->hkv", k_end, U)
-        return S, O
-
-    S, O = jax.lax.scan(body, S0, (w_v, w_k, q_in, B, k_end, g_end))
-    return O.transpose(0, 2, 1, 3).reshape(T, H, dv), S
 
 
 # ------------------------------------------------------------ decoder
@@ -271,22 +192,9 @@ class LingDecoder:
                              for n in ("q", "k", "v")], -1)      # (N, 3Hd)
         kernel = jnp.concatenate([p[pre + n + "_conv"]
                                   for n in ("q", "k", "v")])     # (3Hd, K)
-        tail_name, K = f"layer{i}.kda_conv", c.conv
-        if view.step:
-            win = jnp.concatenate([view.state(tail_name), u[:, None]], 1)
-            view.set_state(tail_name, win[:, 1:])
-            y = jnp.einsum("nkc,ck->nc", win.astype(jnp.float32),
-                           kernel.astype(jnp.float32))
-        else:
-            pad = jnp.concatenate(
-                [jnp.zeros((K - 1, u.shape[1]), u.dtype), u])
-            # inputs at positions length-3 .. length-1
-            view.set_state(tail_name, jax.lax.dynamic_slice_in_dim(
-                pad, view.length, K - 1))
-            y = sum(pad[j:j + N].astype(jnp.float32)
-                    * kernel[:, j].astype(jnp.float32) for j in range(K))
+        y = short_conv(u, kernel, view, f"layer{i}.kda_conv")
         y = jax.nn.silu(y).reshape(N, 3, H, d)
-        q, k, v = _l2(y[:, 0]) * d ** -0.5, _l2(y[:, 1]), y[:, 2]
+        q, k, v = l2norm(y[:, 0]) * d ** -0.5, l2norm(y[:, 1]), y[:, 2]
         a = (_lin(x, p[pre + "a_weight"]).astype(jnp.float32)
              + p[pre + "dt_bias"]).reshape(N, H, d)
         g = c.kda_lower * jax.nn.sigmoid(

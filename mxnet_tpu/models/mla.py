@@ -39,6 +39,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops import latent_attention as _la
+from ..ops import paged_attention as _pa
 from .blocks import lin as _lin
 
 __all__ = ["rope_freq", "yarn_freq", "yarn_mscale", "rope",
@@ -176,25 +177,15 @@ def _over_history(q_nope, q_rope, history, w_kvb, c, block):
     T, H = q_nope.shape[0], c.heads
     table = jnp.pad(table, ((0, -table.shape[0] % block), (0, 0)))
 
-    def body(j, carry):
-        m, l, acc = carry
+    def one(j):
         k_nope, v, k_rope = _expand(
             jax.lax.dynamic_slice_in_dim(table, j * block, block), w_kvb, c)
         seen = j * block + jnp.arange(block) < hist
-        s = jnp.where(seen, _scores(q_nope, q_rope, k_nope, k_rope, c),
-                      NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-        # a block the loop visits holds a seen key, so m_new is finite
-        # and an unseen key's weight is exactly 0
-        p = jnp.exp(s - m_new[..., None])
-        keep = jnp.exp(m - m_new)
-        acc = acc * keep.T[..., None] + jnp.einsum(
-            "hts,shd->thd", p.astype(v.dtype), v,
-            preferred_element_type=jnp.float32)
-        return m_new, l * keep + jnp.sum(p, axis=-1), acc
+        return jnp.where(seen, _scores(q_nope, q_rope, k_nope, k_rope, c),
+                         NEG_INF), v
 
-    return jax.lax.fori_loop(
-        0, (hist + block - 1) // block, body,
+    return _pa.online_softmax(
+        (hist + block - 1) // block, one,
         (jnp.full((H, T), NEG_INF, jnp.float32),
          jnp.zeros((H, T), jnp.float32),
          jnp.zeros((T, H, c.v_dim), jnp.float32)))

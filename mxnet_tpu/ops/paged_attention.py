@@ -50,12 +50,19 @@ from ..base import mxu_precision
 __all__ = [
     "supports", "keysig", "default_schedule", "candidate_schedules",
     "chunk_pages", "paged_attention", "layer_table", "dense_attention",
-    "make_bench_fn",
+    "online_softmax", "history_attention", "make_bench_fn",
 ]
 
 # the masking constant of the decode stack (== models.decode.NEG_INF;
 # kept literal so this op module never imports the models package)
 NEG_INF = -1e30
+# a prefill over a slot window of up to this many positions takes one
+# softmax over ``[history | tail]`` (``dense_attention``); over a longer
+# window it takes the history a key block at a time
+# (``history_attention``): at 30 heads, 2048 rows and a window of 17,408
+# the one softmax's float32 scores would be 4.8 GB
+DENSE_WINDOW = 4096
+HISTORY_KEY_BLOCK = 1024
 
 
 def supports(block: int, dh: int, dtype) -> bool:
@@ -121,6 +128,72 @@ def dense_attention(q, k, v, mask):
     scores = jnp.where(mask, scores, NEG_INF)
     att = jax.nn.softmax(scores, axis=-1)
     return jnp.einsum("bhns,bhsd->bhnd", att, v)
+
+
+def online_softmax(trips, block, carry):
+    """A softmax over keys taken ``trips`` key blocks at a time, online
+    in float32: ``block(j)`` gives block ``j``'s scores ``(H, T, K)``,
+    masked with ``NEG_INF``, and its values ``(K, H, d)``; ``carry`` is
+    the running maximum and sum of exponentials ``(H, T)`` and the
+    weighted values ``(T, H, d)``.  Returns them after the last block,
+    in a loop whose trip count may ride on a traced scalar.  A block the
+    loop visits must hold a seen key of each row: the maximum is then
+    finite and an unseen key weighs exactly 0."""
+    def body(j, carry):
+        m, l, acc = carry
+        s, v = block(j)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+        p = jnp.exp(s - m_new[..., None])
+        keep = jnp.exp(m - m_new)
+        acc = acc * keep.T[..., None] + jnp.einsum(
+            "hts,shd->thd", p.astype(v.dtype), v,
+            preferred_element_type=jnp.float32)
+        return m_new, l * keep + jnp.sum(p, axis=-1), acc
+
+    return jax.lax.fori_loop(0, trips, body, carry)
+
+
+def history_attention(q, k, v, pool_k, pool_v, bt_row, layer, hist, seen,
+                      *, block):
+    """A prefill's attention with the softmax taken online, in float32:
+    ``q`` ``(H, R, dh)`` (``R`` = query heads a K/V head x the tail's
+    ``T`` rows), the tail's own ``k, v`` ``(H, T, dh)`` under ``seen``
+    ``(R, T)``, then the slot's history -- positions ``[0, hist)`` of
+    the table row ``bt_row`` in ``layer`` of the pools --
+    ``HISTORY_KEY_BLOCK`` positions at a time (:func:`online_softmax`),
+    each block's pages read from the pool as it comes (``pool[pages,
+    layer]``: whole pages, never the layer), in a loop of ``ceil(hist /
+    HISTORY_KEY_BLOCK)`` trips.  The largest score matrix is ``(H, R,
+    max(T, HISTORY_KEY_BLOCK))``.  Returns ``(H, R, dh)`` in ``q``'s
+    dtype."""
+    H, R, dh = q.shape
+    kb = HISTORY_KEY_BLOCK
+    scale = 1.0 / np.sqrt(dh)
+    f32 = jnp.float32
+    s = jnp.einsum("hrd,htd->hrt", q, k, preferred_element_type=f32)
+    s = jnp.where(seen, s * scale, NEG_INF)
+    m = jnp.max(s, axis=-1)
+    p = jnp.exp(s - m[..., None])
+    acc = jnp.einsum("hrt,htd->rhd", p.astype(v.dtype), v,
+                     preferred_element_type=f32)
+    pages = kb // block
+    # the row in whole blocks: a block past the table reads the scratch
+    # page, masked like every position past ``hist``
+    bt_row = jnp.pad(bt_row, (0, -bt_row.shape[0] % pages))
+
+    def keys(pool, j):                                   # (kb, H, dh)
+        ids = jax.lax.dynamic_slice_in_dim(bt_row, j * pages, pages)
+        return pool[ids, layer].transpose(0, 2, 1, 3).reshape(kb, H, dh)
+
+    def one(j):
+        s = jnp.einsum("hrd,khd->hrk", q, keys(pool_k, j),
+                       preferred_element_type=f32)
+        at = j * kb + jnp.arange(kb)
+        return jnp.where(at < hist, s * scale, NEG_INF), keys(pool_v, j)
+
+    m, l, acc = online_softmax((hist + kb - 1) // kb, one,
+                               (m, jnp.sum(p, axis=-1), acc))
+    return (acc / l.T[..., None]).transpose(1, 0, 2).astype(q.dtype)
 
 
 def _gather_attention(q, pool_k, pool_v, bt, limit, layer):

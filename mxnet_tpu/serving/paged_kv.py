@@ -85,20 +85,31 @@ declare:
   among ``[history | tail]`` pages alike;
 - ``state``: name -> ``(shape, dtype)`` — a FIXED-SIZE STATE PER SLOT
   beside the pages, one ``(num_slots, *shape)`` array each (a
-  linear-attention layer's recurrent state; ``view.state`` /
-  ``view.set_state``).  A prefill starts from a zero state and
-  overwrites the slot's row, so a reused slot never sees its
-  predecessor;
+  linear-attention layer's recurrent state and its convolution's last
+  inputs: ``models/ling.py``'s ``kda_S`` / ``kda_conv``,
+  ``models/olmo_hybrid.py``'s ``gdn_S`` / ``gdn_conv``; ``view.state`` /
+  ``view.set_state``).  A prompt's first prefill program starts from a
+  zero state, so a reused slot never sees its predecessor; a later
+  chunk of the same prompt starts from the slot's row, where the chunk
+  before it left the state (``hist`` > 0); each program overwrites the
+  row with the state after its last real token.  The step leaves the
+  rows of the slots it is not given as occupied as they were: a slot
+  between two chunks of its prompt rides the step as a free row;
 - ``counters``: names of int32 device counters the forward adds to
   (``view.count``), reported by ``stats()`` under those names;
-- ``prefix_reuse``: whether a prefill's tail may stand behind rows the
-  slot's pages already hold -- a cached prefix picked up at a block
-  boundary, or the earlier chunks of the same prompt.  It would also
-  need the state there, which nothing snapshots: a decoder with state
-  says ``False``, no page of it is ever shared
-  (``stats()["prefix_reuse"]``) and its prompts are one program each.
-  This flag is all that is asked: no family and no kind of page is
-  named here.
+- ``chunks``: whether a prefill's tail may stand behind rows the slot
+  already holds from the earlier chunks of the SAME prompt (and, for a
+  decoder with state, behind the state those chunks left): a prompt
+  longer than the largest bucket then goes in chunks.  Where a layout
+  does not say, it is ``prefix_reuse``;
+- ``prefix_reuse``: whether a tail may also stand behind ANOTHER
+  request's cached prefix, picked up at a block boundary.  That would
+  need the state at the boundary, which nothing snapshots: a decoder
+  with state says ``False`` and no page of it is ever shared
+  (``stats()["prefix_reuse"]``), though its own long prompts may go in
+  chunks (``models/olmo_hybrid.py``; ``models/ling.py``, whose latent
+  prefill takes no history, says neither).  These flags are all that
+  is asked: no family and no kind of page is named here.
 
 An admission is :meth:`PagedSlots.begin_admit` (prefix lookup, page
 allocation) and one or more :meth:`PagedSlots.admit_chunk` (a prefill
@@ -295,7 +306,7 @@ class _StepView(_CacheView):
                 (cursor[:, None] + jnp.arange(n)).reshape(-1),
                 jnp.repeat(occupied, n))
             self._limit = cursor + (n - 1)
-        self._bt, self._cursor = bt, cursor
+        self._bt, self._cursor, self._occupied = bt, cursor, occupied
         # where each slot's row goes, as scalar pairs made once a
         # program: a step's trace holds ``2 L B`` row writes
         pages = jnp.take_along_axis(
@@ -307,7 +318,13 @@ class _StepView(_CacheView):
         return self._state[name]
 
     def set_state(self, name, value):
-        self._state[name] = value.astype(self._state[name].dtype)
+        import jax.numpy as jnp
+
+        old = self._state[name]
+        # a slot between two chunks of its prompt is a free row here:
+        # its state is the chunks', and stays
+        keep = self._occupied.reshape((-1,) + (1,) * (old.ndim - 1))
+        self._state[name] = jnp.where(keep, value.astype(old.dtype), old)
 
     def _write_rows(self, pool, new, layer):
         """The K or V rows of a slot: ``new[b]`` ``(H, n, dh)`` lands at
@@ -426,7 +443,9 @@ class _PrefillView(_CacheView):
     layer's pages of the history (:meth:`attend`); the all-layer table
     a prefill scattered its rows into until ISSUE 30 was copied whole
     twice a layer by the TPU compiler, as the pool had been.
-    The state starts at zero and the state after the last real token
+    The state starts at zero (a prompt's first program) or from the
+    slot's row (a later chunk of it, ``hist`` > 0, where the decoder's
+    prompts go in chunks), and the state after the last real token
     overwrites the slot's row.  ``slot``, ``hist`` and ``length`` ride
     as traced scalars, so the program count is one per padded bucket
     length."""
@@ -449,10 +468,12 @@ class _PrefillView(_CacheView):
         self._hist = hist
 
     def state(self, name):
+        import jax
         import jax.numpy as jnp
 
         s = self._state[name]
-        return jnp.zeros(s.shape[1:], s.dtype)
+        row = jax.lax.dynamic_index_in_dim(s, self._slot, keepdims=False)
+        return jnp.where(self._hist > 0, row, jnp.zeros_like(row))
 
     def set_state(self, name, value):
         import jax
@@ -486,6 +507,12 @@ class _PrefillView(_CacheView):
         stand behind it; block-causal for a decoder of ``n`` tokens a
         step, key ``j`` seen by query ``i`` iff ``j // n <= i // n``):
         the two blocks side by side on the key axis, one softmax.
+        Where the slot's window is longer than
+        ``ops/paged_attention.py:DENSE_WINDOW`` the same softmax is
+        taken online in float32 (``history_attention``): the tail's own
+        keys, then the history a key block at a time, read from the
+        slot's pages as it comes and only as far as ``hist`` reaches,
+        so no score matrix over the whole window is ever built.
         Returns ``q``'s shape."""
         import jax.numpy as jnp
 
@@ -494,20 +521,32 @@ class _PrefillView(_CacheView):
         self.kv = tuple(
             self._write_pages(pool, a[0].transpose(1, 0, 2), layer)
             for pool, a in zip(self.kv, (k, v)))
+        T = k.shape[2]
+        if self._pg.max_blocks * self._pg.block > _pa.DENSE_WINDOW:
+            return _pa.history_attention(
+                q[0], k[0], v[0], *self.kv, self._bt_row, layer, self._hist,
+                jnp.tile(self._tail_mask(T), (q.shape[2] // T, 1)),
+                block=self._pg.block)[None]
         hk, hv = (_pa.layer_table(pool, self._bt_row[None], layer)
                   for pool in self.kv)               # (1, H, S, dh)
-        S, T = hk.shape[2], k.shape[2]
+        S = hk.shape[2]
         history = jnp.broadcast_to(jnp.arange(S) < self._hist, (T, S))
-        if self._pg.block_n > 1:        # block-causal
-            at = jnp.arange(T) // self._pg.block_n
-            tail = at[None, :] <= at[:, None]
-        else:
-            tail = jnp.arange(T)[None, :] <= jnp.arange(T)[:, None]
-        seen = jnp.concatenate([history, tail], axis=1)
+        seen = jnp.concatenate([history, self._tail_mask(T)], axis=1)
         if q.shape[2] != T:
             seen = jnp.tile(seen, (q.shape[2] // T, 1))
         return _pa.dense_attention(q, jnp.concatenate([hk, k], axis=2),
                                    jnp.concatenate([hv, v], axis=2), seen)
+
+    def _tail_mask(self, T):
+        """``(T, T)``: which of the tail's keys each of its queries
+        sees -- causal, block-causal for a decoder of several tokens a
+        step."""
+        import jax.numpy as jnp
+
+        if self._pg.block_n > 1:
+            at = jnp.arange(T) // self._pg.block_n
+            return at[None, :] <= at[:, None]
+        return jnp.arange(T)[None, :] <= jnp.arange(T)[:, None]
 
     def append(self, name, layer, rows):
         """``rows`` (T, W): the tail's page rows, written whole pages at
@@ -524,7 +563,7 @@ class _PrefillView(_CacheView):
         from ..ops import latent_attention as _la
 
         pool = self._put_page_rows(name, layer, rows)
-        if not self._pg.layout["prefix_reuse"]:
+        if not self._pg.chunks:
             return None
         return _la.PrefillHistory(pool, self._bt_row, layer, rows.shape[1],
                                   self._hist, self.length,
@@ -591,6 +630,9 @@ class _CachePrograms:
         from ..models.decode import _WeightProgram, _count_compiles
 
         self.dec, self.layout = decoder, layout
+        # a tail may stand behind the same prompt's earlier chunks; a
+        # state then goes from one chunk to the next in the slot's row
+        self.chunks = bool(layout.get("chunks", layout["prefix_reuse"]))
         # tokens a slot a step (a block decoder's block; else 1)
         self.block_n = int(getattr(decoder, "block_length", 1))
         self.block, self.max_blocks = int(block), int(max_blocks)
@@ -749,18 +791,21 @@ class PagedSlots:
             raise MXNetError(
                 f"KV block {self.block} must be a multiple of the "
                 f"decoder's block_length {self.block_n}")
-        # a decoder says whether a prefix of its cache can be picked up
-        # at a block boundary (per-slot state cannot)
+        # a decoder says whether another request's prefix of its cache
+        # can be picked up at a block boundary (per-slot state cannot)
         self.prefix_on = self.prefix_on and layout["prefix_reuse"]
-        # the same flag says a prefill's tail may stand behind rows the
-        # slot's pages already hold: a tail over the largest bucket then
+        # and whether a prefill's tail may stand behind the earlier
+        # chunks of its own prompt: a tail over the largest bucket then
         # goes in chunks of that bucket's whole pages (0: it cannot)
         self._bucket_max = max(self.prefill_buckets, default=0)
         self._chunk = self._bucket_max // self.block * self.block \
-            if layout["prefix_reuse"] else 0
-        # host-side counts since start, beside the decoder's own
+            if self.programs.chunks else 0
+        # host-side counts since start, beside the decoder's own; with
+        # a state a slot, the chunks that began from the one carried
         self._host_counts = {"prefill_chunks": 0, "prompt_tokens": 0,
                              "prefix_tokens_hit": 0}
+        if layout["state"]:
+            self._host_counts["state_chunks_carried"] = 0
         # the device counters the decoder's programs add to, by the
         # names it declared, and what stats() has read of them so far
         # (host side, never wraps)
@@ -1032,8 +1077,8 @@ class PagedSlots:
             raise MXNetError(
                 f"a tail of {adm.tail.size} tokens exceeds the largest "
                 f"prefill bucket {self._bucket_max}, and this decoder's "
-                "prefill takes no history (per-slot state): it cannot go "
-                "in chunks")
+                "prefill takes no history (its layout says no chunks): it "
+                "cannot go in chunks")
         # pin the matched chain BEFORE allocating: _alloc evicts ref==1
         # prefix pages, which would otherwise include this request's own
         # shared chain under pool pressure — the evicted page would come
@@ -1097,6 +1142,8 @@ class PagedSlots:
         args = (_snap(adm.bt_row), jnp.asarray(padded), np.int32(adm.slot),
                 jnp.int32(adm.hist + adm.done), jnp.int32(t))
         logits = self._run(self.programs.prefill(bucket), *args)
+        if self.programs.layout["state"] and adm.hist + adm.done:
+            self._host_counts["state_chunks_carried"] += 1
         adm.done += t
         self._host_counts["prefill_chunks"] += 1
         if not adm.pending:

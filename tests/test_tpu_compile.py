@@ -1072,3 +1072,84 @@ def test_residual_epilogue_vjp_compiles(one_chip):
     x = one_chip(shape, "bfloat16")
     c = one_chip((shape[-1],), "float32")
     _compile(jax.grad(loss, argnums=(0, 1, 2, 3)), x, x, c, c)
+
+
+# ---------------------------------------- gated DeltaNet beside K/V pages
+# serve_docqa_olmo_hybrid: benchmark/configs/olmo-hybrid-7b-l8.json at its
+# published widths and 8 layers, 8 slots of 17,408 positions
+_OLMO_SLOTS, _OLMO_MAX_LEN = 8, 17408
+_OLMO_PAGES = _OLMO_SLOTS * _OLMO_MAX_LEN // _BLOCK + 1
+_OLMO_POOL = "bf16[%d,2,30,%d,128]" % (_OLMO_PAGES, _BLOCK)
+
+
+def _olmo_programs(sds):
+    """``_CachePrograms`` over an ``OlmoHybridDecoder`` that holds shapes
+    for weights, from the benchmark's configuration file, the step's
+    attention on the paged kernel."""
+    import json
+    import sys
+
+    from mxnet_tpu.models.olmo_hybrid import (KEPT_FLOAT32,
+                                              OlmoHybridConfig,
+                                              OlmoHybridDecoder)
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "olmo-hybrid-7b-l8.json")) as f:
+        config = json.load(f)
+    config.pop("rehearse")
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmark.families import olmo_hybrid as fam
+
+    dec = OlmoHybridDecoder.__new__(OlmoHybridDecoder)
+    dec.cfg = OlmoHybridConfig.from_dict(config)
+    dec.p = {k: sds(tuple(v["shape"]),
+                    "float32" if k.endswith(KEPT_FLOAT32) else "bfloat16")
+             for k, v in fam.param_specs(config).items()}
+    dec.max_len, dec.vocab = _OLMO_MAX_LEN, config["vocab_size"]
+    dec._cache_dtype = jnp.dtype("bfloat16")
+    dec._kv_layer = {3: 0, 7: 1}
+    M = _OLMO_MAX_LEN // _BLOCK
+    return _CachePrograms(
+        dec, dec.paged_layout(), _BLOCK, M, _OLMO_PAGES, _OLMO_SLOTS,
+        schedule=pa.default_schedule("tpu", _BLOCK, 128, "bfloat16"))
+
+
+@pytest.mark.parametrize("which", ["step", 2048])
+def test_gated_deltanet_programs_compile_in_place(one_chip, which):
+    """The Olmo-Hybrid step and its 2,048-token prefill (a chunk behind
+    up to 15k positions of history) at the benchmark's sizes: every leaf
+    of the cache written in place, the K/V pool (4.28 GB) never copied
+    and a state at most staged through fast memory, the step's two attention layers on the paged kernel
+    and no other Mosaic call, and each program beside its 9.2 GB of
+    arguments within the chip: the prefill's attention takes the history
+    a key block at a time, no score matrix over the window."""
+    progs = _olmo_programs(one_chip)
+    lowered, cache = _lower(progs, one_chip, which, _OLMO_SLOTS,
+                            _OLMO_MAX_LEN // _BLOCK)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    leaves = jax.tree_util.tree_leaves(cache)
+    nbytes = lambda s: s.dtype.itemsize * functools.reduce(
+        lambda a, b: a * b, s.shape)
+    assert {_shape_text(s) for s in leaves if nbytes(s) > 1e9} == \
+        {_OLMO_POOL}
+    assert _copies_of(text, {_OLMO_POOL}) == []
+    # a state (17.7 MB) may be staged through the chip's fast memory and
+    # back, as Ling's convolution tails are: a read and a write it does
+    # anyway; never more than that a layer
+    assert len(_copies_of(text, {"f32[8,30,96,192]"})) <= 2 * 6
+    cache_bytes = sum(nbytes(s) for s in leaves)
+    mem = compiled.memory_analysis()
+    # a state's rows of 192 lie in two 128-wide lanes on the chip: the
+    # six states (106 MB) take a third more in place
+    assert cache_bytes <= mem.alias_size_in_bytes < 1.01 * cache_bytes
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14.0e9
+    print("memory: arguments %.3f GB, temporaries %.3f GB"
+          % (mem.argument_size_in_bytes / 1e9, mem.temp_size_in_bytes / 1e9))
+    kernels = text.count('custom_call_target="tpu_custom_call"')
+    assert kernels == (2 if which == "step" else 0)
+    if which != "step":
+        # no (heads, rows, keys) score matrix wider than a key block
+        assert not re.search(r"f32\[30,2048,(1[0-9]{4})\]", text)
