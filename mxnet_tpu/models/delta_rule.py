@@ -15,7 +15,9 @@ the transition):
 - :func:`kda_chunked`: a sequence in chunks of 16, a gate a channel
   bounded below (KDA's "safe gate", -5 a token);
 - :func:`gdn_chunked`: a sequence in chunks of 64, a gate a head and
-  no bound below.
+  no bound below (on a TPU the same algebra runs as one Pallas kernel,
+  ``ops/gdn_scan.py``; this XLA form runs everywhere else and is the
+  kernel's reference).
 
 And what both families put before it: the L2 norm of ``q`` and ``k``
 (:func:`l2norm`) and the causal depthwise convolution whose last inputs

@@ -14,7 +14,9 @@ n_b(SwiGLU(h))``).
   dt_bias)``, unbounded below; ``beta = 2 sigmoid(x W_b)`` in (0, 2);
   the delta rule over a ``(d_k, d_v)`` = ``(96, 192)`` float32 state a
   head (``models/delta_rule.py``: the one-token step, and the chunked
-  form for a gate a head, which no gate overflows); the output
+  form for a gate a head, which no gate overflows -- in a prefill on a
+  TPU the kernel ``ops/gdn_scan.py``, where the view's scan schedule
+  and the kernel's gate allow); the output
   RMS-normed a head and gated by ``SiLU(x W_g)``.
 - **Attention**: 30 heads, each with K/V of its own, QK-norm over the
   whole projection, no rotary, over the K/V pages (``view.attend``: the
@@ -46,6 +48,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
+from ..ops import gdn_scan as _gs
 from .blocks import lin as _lin, rms_norm, swiglu as _swiglu
 from .delta_rule import gdn_chunked, kda_recurrent_step, l2norm, short_conv
 
@@ -183,9 +186,15 @@ class OlmoHybridDecoder:
                     view.state(S_name))
             else:
                 real = view.valid[:, None]
-                o, S = gdn_chunked(q, k, v, jnp.where(real, g, 0.0),
-                                   jnp.where(real, beta, 0.0),
-                                   view.state(S_name))
+                args = (q, k, v, jnp.where(real, g, 0.0),
+                        jnp.where(real, beta, 0.0), view.state(S_name))
+                sched = view.scan_schedule
+                if _gs.on_kernel(sched, N, H, dk, dv):
+                    view.scanned_on_kernel()
+                    o, S = _gs.gdn_scan(
+                        *args, interpret=bool(sched.get("interpret")))
+                else:
+                    o, S = gdn_chunked(*args)
             view.set_state(S_name, S)
         gate = jax.nn.silu(_lin(x, p[pre + "g_weight"]).astype(jnp.float32))
         o = rms_norm(o, p[pre + "onorm_weight"], c.eps) \

@@ -94,7 +94,10 @@ declare:
   before it left the state (``hist`` > 0); each program overwrites the
   row with the state after its last real token.  The step leaves the
   rows of the slots it is not given as occupied as they were: a slot
-  between two chunks of its prompt rides the step as a free row;
+  between two chunks of its prompt rides the step as a free row.  A
+  prefill's chunked scan over the state has a schedule of its own
+  (``view.scan_schedule``, ``ops/gdn_scan.py``), by the same mode as
+  the pages';
 - ``counters``: names of int32 device counters the forward adds to
   (``view.count``), reported by ``stats()`` under those names;
 - ``chunks``: whether a prefill's tail may stand behind rows the slot
@@ -466,6 +469,7 @@ class _PrefillView(_CacheView):
                             programs.max_blocks - 1)],
             programs.num_pages)
         self._hist = hist
+        self.bucket = tokens.shape[0]
 
     def state(self, name):
         import jax
@@ -474,6 +478,18 @@ class _PrefillView(_CacheView):
         s = self._state[name]
         row = jax.lax.dynamic_index_in_dim(s, self._slot, keepdims=False)
         return jnp.where(self._hist > 0, row, jnp.zeros_like(row))
+
+    @property
+    def scan_schedule(self):
+        """The schedule of a chunked scan over the state
+        (``ops/gdn_scan.py``: the kernel, or the decoder's XLA form)."""
+        return self._pg.state_schedule
+
+    def scanned_on_kernel(self):
+        """The decoder put this program's scan on the kernel: noted as
+        the program is traced, counted each time it runs
+        (``stats()["gdn_scan_on_kernel"]``)."""
+        self._pg.scan_on_kernel.add(self.bucket)
 
     def set_state(self, name, value):
         import jax
@@ -626,7 +642,7 @@ class _CachePrograms:
 
     def __init__(self, decoder, layout, block, max_blocks, num_pages,
                  num_slots, schedule=None, page_schedules=None,
-                 sparse_schedule=None):
+                 sparse_schedule=None, state_schedule=None):
         from ..models.decode import _WeightProgram, _count_compiles
 
         self.dec, self.layout = decoder, layout
@@ -648,6 +664,11 @@ class _CachePrograms:
         # the chosen-page attention over the K/V pools
         # (ops/sparse_attention.py); gather where none is given
         self.sparse_schedule = sparse_schedule
+        # a prefill's chunked scan over the state (ops/gdn_scan.py), and
+        # the buckets whose program put it on the kernel, noted as each
+        # is traced; the XLA form where none is given
+        self.state_schedule = state_schedule
+        self.scan_on_kernel = set()
         self._WeightProgram, self._count = _WeightProgram, _count_compiles
         self._step_jit = _WeightProgram(
             decoder, _count_compiles(self._step_program,
@@ -779,11 +800,13 @@ class PagedSlots:
             for name, (_layers, width, dtype) in layout["pages"].items()}
         self.sparse_schedule = self._resolve_sparse_schedule(
             layout.get("kv_pages"))
+        self.state_schedule = self._resolve_state_schedule(layout["state"])
         self.programs = _CachePrograms(
             decoder, layout, self.block, self.max_blocks,
             self.num_pages + 1, self.num_slots, schedule=self.schedule,
             page_schedules=self.page_schedules,
-            sparse_schedule=self.sparse_schedule)
+            sparse_schedule=self.sparse_schedule,
+            state_schedule=self.state_schedule)
         # tokens a slot a step: a block decoder's block, which a page
         # holds whole (a block never straddles two pages)
         self.block_n = self.programs.block_n
@@ -806,6 +829,7 @@ class PagedSlots:
                              "prefix_tokens_hit": 0}
         if layout["state"]:
             self._host_counts["state_chunks_carried"] = 0
+            self._host_counts["gdn_scan_on_kernel"] = 0
         # the device counters the decoder's programs add to, by the
         # names it declared, and what stats() has read of them so far
         # (host side, never wraps)
@@ -916,6 +940,15 @@ class PagedSlots:
         _L, _H, dh, dtype = kv_pages
         return self._by_mode(lambda platform: _sa.default_schedule(
             platform, self.block, dh, dtype))
+
+    def _resolve_state_schedule(self, state):
+        """The schedule of a prefill's chunked scan over the state
+        (``ops/gdn_scan.py``), by the same mode; ``None`` without
+        state.  Whether a scan's shapes take the kernel is the kernel's
+        gate, asked as the program is traced."""
+        from ..ops import gdn_scan as _gs
+
+        return self._by_mode(_gs.default_schedule) if state else None
 
     def _by_mode(self, default):
         """``interpret`` and ``gather`` as they say; ``pallas`` the
@@ -1144,6 +1177,8 @@ class PagedSlots:
         logits = self._run(self.programs.prefill(bucket), *args)
         if self.programs.layout["state"] and adm.hist + adm.done:
             self._host_counts["state_chunks_carried"] += 1
+        if bucket in self.programs.scan_on_kernel:
+            self._host_counts["gdn_scan_on_kernel"] += 1
         adm.done += t
         self._host_counts["prefill_chunks"] += 1
         if not adm.pending:

@@ -26,9 +26,11 @@ if ROOT not in sys.path:
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
+from benchmark.families import ling as ling_fam  # noqa: E402
 from benchmark.families import olmo_hybrid as fam  # noqa: E402
 from benchmark.reference import olmo_hybrid as ref  # noqa: E402
 from mxnet_tpu.models import delta_rule  # noqa: E402
+from mxnet_tpu.ops import gdn_scan  # noqa: E402
 from mxnet_tpu.ops import paged_attention  # noqa: E402
 from mxnet_tpu.serving import serve_decoder  # noqa: E402
 from mxnet_tpu.serving.paged_kv import PagedSlots  # noqa: E402
@@ -102,17 +104,12 @@ def _recurrence(q, k, v, g, beta, S):
     return jnp.stack(outs), S
 
 
-@pytest.mark.parametrize("T,lowest", [
-    pytest.param(128, -3.0, id="two_chunks"),
-    pytest.param(100, -50.0, id="gates_to_-50_padded_tail"),
-    pytest.param(70, -12.0, id="one_chunk_and_a_bit")])
-def test_gdn_chunked_is_the_recurrence(T, lowest):
-    """``d_k`` 6 != ``d_v`` 10, ``beta`` over (0, 2), gates from 0 down
-    to ``lowest`` a token (no inf or NaN anywhere), the last 9 positions
-    right padding (``beta = g = 0``: the state stays), a starting state
-    of its own; relative error <= 1e-5 in the outputs and the state."""
+def _scan_inputs(T, lowest, H=3, dk=6, dv=10):
+    """``(q, k, v, g, beta, S0)`` in float32: L2-normed keys, ``beta``
+    over (0, 2), gates from 0 down to ``lowest`` a token, the last 9
+    positions right padding (``beta = g = 0``), a starting state of its
+    own."""
     rng = np.random.default_rng(T)
-    H, dk, dv = 3, 6, 10
     l2 = lambda a: a / np.linalg.norm(a, axis=-1, keepdims=True)
     q = l2(rng.normal(size=(T, H, dk))) * dk ** -0.5
     k = l2(rng.normal(size=(T, H, dk)))
@@ -123,16 +120,103 @@ def test_gdn_chunked_is_the_recurrence(T, lowest):
     g[-9:] = 0.0
     beta[-9:] = 0.0
     S0 = rng.normal(size=(H, dk, dv))
-    args = [jnp.asarray(a, jnp.float32) for a in (q, k, v, g, beta, S0)]
+    return [jnp.asarray(a, jnp.float32) for a in (q, k, v, g, beta, S0)]
+
+
+def _rel(got, want):
+    return float(np.linalg.norm(got - want) / np.linalg.norm(want))
+
+
+def _holds_the_recurrence(got_o, got_S, args):
+    """Relative error <= 1e-5 in the outputs and the state against the
+    token-by-token recurrence, nothing infinite, and the padding left
+    the state as it was after the last real token."""
     want_o, want_S = _recurrence(*args)
-    got_o, got_S = delta_rule.gdn_chunked(*args, chunk=32)
     for got, want in ((got_o, want_o), (got_S, want_S)):
         assert np.isfinite(np.asarray(got)).all()
-        assert float(jnp.linalg.norm(got - want)
-                     / jnp.linalg.norm(want)) <= 1e-5
-    # the padding left the state as it was after the last real token
+        assert _rel(got, want) <= 1e-5
+    T = args[0].shape[0]
     _, S_real = _recurrence(*(a[:T - 9] for a in args[:5]), args[5])
     assert float(jnp.max(jnp.abs(got_S - S_real))) < 1e-5
+
+
+SCAN_CASES = [
+    pytest.param(128, -3.0, id="two_chunks"),
+    pytest.param(100, -50.0, id="gates_to_-50_padded_tail"),
+    pytest.param(70, -12.0, id="one_chunk_and_a_bit")]
+
+
+@pytest.mark.parametrize("T,lowest", SCAN_CASES)
+def test_gdn_chunked_is_the_recurrence(T, lowest):
+    """``d_k`` 6 != ``d_v`` 10, ``beta`` over (0, 2), gates from 0 down
+    to ``lowest`` a token (no inf or NaN anywhere), the last 9 positions
+    right padding (``beta = g = 0``: the state stays), a starting state
+    of its own; relative error <= 1e-5 in the outputs and the state."""
+    args = _scan_inputs(T, lowest)
+    _holds_the_recurrence(*delta_rule.gdn_chunked(*args, chunk=32), args)
+
+
+@pytest.mark.parametrize("T,lowest,widths", [
+    pytest.param(*case.values, (6, 10), id=case.id) for case in SCAN_CASES
+] + [pytest.param(256, -3.0, (96, 192), id="the_cell_widths")])
+def test_gdn_scan_kernel_is_the_recurrence(T, lowest, widths):
+    """``ops/gdn_scan.py``'s kernel, interpreted, in its own chunks of
+    64: the cases above, and the cell's widths (``d_k`` 96, ``d_v``
+    192) over 256 positions, right-padded; relative error <= 1e-5
+    against the recurrence and against ``gdn_chunked``."""
+    args = _scan_inputs(T, lowest, 3, *widths)
+    o, S = gdn_scan.gdn_scan(*args, interpret=True)
+    _holds_the_recurrence(o, S, args)
+    want_o, want_S = delta_rule.gdn_chunked(*args)
+    assert _rel(o, want_o) <= 1e-5 and _rel(S, want_S) <= 1e-5
+
+
+def _recurrence64(q, k, v, g, beta, S):
+    """The recurrence token by token in float64 (numpy): the truth the
+    float32 forms are measured from where they part."""
+    q, k, v, g, beta, S = (np.asarray(a, np.float64)
+                           for a in (q, k, v, g, beta, S))
+    outs = []
+    for t in range(q.shape[0]):
+        S = np.exp(g[t])[:, None, None] * S
+        u = beta[t][:, None] * (v[t] - np.einsum("hk,hkv->hv", k[t], S))
+        S = S + k[t][:, :, None] * u[:, None, :]
+        outs.append(np.einsum("hk,hkv->hv", q[t], S))
+    return np.stack(outs), S
+
+
+@pytest.mark.parametrize("chunk", [64, 128])
+def test_the_inverse_by_products_is_the_solve(chunk):
+    """The adversarial system: ``beta`` = 2 everywhere, keys nearly
+    parallel, no decay (``g`` = 0), so that ``A``'s entries are all
+    near 2 and ``(I + A)`` is far from the identity (condition ~7e3 at
+    64, ~3e4 at 128).  The kernel's inverse from products against
+    ``solve_triangular`` on the same right-hand side: relative error
+    <= 1e-5.  Over two chunks of it both float32 forms part from the
+    float64 recurrence by 1.3-5.3e-5 (the XLA form by 1.3-3.4e-5, the
+    kernel by 2.3-5.3e-5, measured on the CPU): each is held to 1e-4
+    of it."""
+    rng = np.random.default_rng(chunk)
+    H, dk, dv, T = 2, 96, 192, 2 * chunk
+    k = np.ones((T, H, dk)) + 0.01 * rng.normal(size=(T, H, dk))
+    k /= np.linalg.norm(k, axis=-1, keepdims=True)
+    k = jnp.asarray(k, jnp.float32)
+    kc = k[:chunk, 0]
+    A = 2.0 * jnp.tril(jnp.dot(kc, kc.T, precision="highest"), -1)
+    rhs = jnp.asarray(rng.normal(size=(chunk, dk + dv)), jnp.float32)
+    want = jax.scipy.linalg.solve_triangular(
+        A + jnp.eye(chunk), rhs, lower=True, unit_diagonal=True)
+    got = jnp.dot(gdn_scan.unit_lower_inverse(A), rhs, precision="highest")
+    assert _rel(got, want) <= 1e-5
+    q = k * dk ** -0.5
+    v = jnp.asarray(rng.normal(size=(T, H, dv)), jnp.float32)
+    args = (q, k, v, jnp.zeros((T, H)), jnp.full((T, H), 2.0),
+            jnp.asarray(rng.normal(size=(H, dk, dv)), jnp.float32))
+    want_o, want_S = _recurrence64(*args)
+    for o, S in (gdn_scan.gdn_scan(*args, interpret=True, chunk=chunk),
+                 delta_rule.gdn_chunked(*args, chunk=chunk)):
+        assert _rel(np.asarray(o, np.float64), want_o) <= 1e-4
+        assert _rel(np.asarray(S, np.float64), want_S) <= 1e-4
 
 
 def test_the_recurrence_has_negative_eigenvalues():
@@ -194,6 +278,9 @@ def test_prefill_then_decode_agrees_with_the_reference(
     chunks = -(-prompt_len // 64)
     assert slots.stats()["prefill_chunks"] == chunks
     assert slots.stats()["state_chunks_carried"] == chunks - 1
+    # the interpreted kernels' mode puts the chunked scan on its kernel
+    assert slots.stats()["gdn_scan_on_kernel"] == (
+        chunks if kernel == "interpret" else 0)
 
 
 def test_the_scheduler_serves_it_greedy_as_the_reference_would(weights):
@@ -300,6 +387,40 @@ def test_a_state_decoder_shares_no_page_across_requests(weights):
     want = reference_rows(leaves, c, list(prompt), 80)[0]
     for got in (a, b):
         assert np.max(np.abs(np.asarray(got) - want)) < LOGIT_TOL
+
+
+def test_the_scan_kernel_counter_and_its_gate(weights):
+    """``gdn_scan_on_kernel`` counts the prefill programs whose scan ran
+    on the kernel: none for an Olmo prompt of two chunks off the chip
+    (the XLA form), none for Ling even where the mode asks for kernels;
+    and the kernel's gate takes the cell's shapes (30 heads of 96 and
+    192) at every prefill bucket."""
+    config, _c, params, _ = weights
+    decoder = fam.build_decoder(config, params, MAX_LEN, jnp.float32)
+    slots = _slots(decoder, kernel="auto")
+    assert slots.state_schedule == {"impl": "xla"}
+    slots.admit(0, np.arange(100) % config["vocab_size"])
+    stats = slots.stats()
+    assert stats["prefill_chunks"] == 2 and stats["gdn_scan_on_kernel"] == 0
+
+    ling_config = json.load(open(os.path.join(
+        ROOT, "benchmark", "configs", "ling-3.0-flash-ep4-l7.json")))
+    ling_config.update(ling_config.pop("rehearse"))
+    ling = ling_fam.build_decoder(
+        ling_config, ling_fam.serving_weights(ling_config, SEED, jnp.float32),
+        128, jnp.float32)
+    slots = PagedSlots(ling, num_slots=2, block=BLOCK, prefill_buckets=(32,),
+                       kernel="interpret")
+    assert slots.state_schedule == {"impl": "pallas", "interpret": True}
+    slots.admit(0, np.arange(20) % ling_config["vocab_size"])
+    assert slots.stats()["gdn_scan_on_kernel"] == 0
+
+    for bucket in (128, 256, 512, 1024, 2048):
+        assert gdn_scan.supports(bucket, 30, 96, 192)
+        assert gdn_scan.on_kernel(gdn_scan.default_schedule("tpu"),
+                                  bucket, 30, 96, 192)
+        assert not gdn_scan.on_kernel(gdn_scan.default_schedule("cpu"),
+                                      bucket, 30, 96, 192)
 
 
 def test_the_layout_declares_both_kinds_of_cache(weights):

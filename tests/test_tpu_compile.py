@@ -40,6 +40,7 @@ from jax.sharding import SingleDeviceSharding
 
 from mxnet_tpu.models.decode import KVDecoder
 from mxnet_tpu.ops import flash_attention as fa
+from mxnet_tpu.ops import gdn_scan as gs
 from mxnet_tpu.ops import grouped_matmul as gmm
 from mxnet_tpu.ops import latent_attention as la
 from mxnet_tpu.ops import paged_attention as pa
@@ -1085,7 +1086,8 @@ _OLMO_POOL = "bf16[%d,2,30,%d,128]" % (_OLMO_PAGES, _BLOCK)
 def _olmo_programs(sds):
     """``_CachePrograms`` over an ``OlmoHybridDecoder`` that holds shapes
     for weights, from the benchmark's configuration file, the step's
-    attention on the paged kernel."""
+    attention on the paged kernel and a prefill's chunked scan on
+    ``ops/gdn_scan.py``'s, as ``PagedSlots`` schedules them on a TPU."""
     import json
     import sys
 
@@ -1113,7 +1115,8 @@ def _olmo_programs(sds):
     M = _OLMO_MAX_LEN // _BLOCK
     return _CachePrograms(
         dec, dec.paged_layout(), _BLOCK, M, _OLMO_PAGES, _OLMO_SLOTS,
-        schedule=pa.default_schedule("tpu", _BLOCK, 128, "bfloat16"))
+        schedule=pa.default_schedule("tpu", _BLOCK, 128, "bfloat16"),
+        state_schedule=gs.default_schedule("tpu"))
 
 
 @pytest.mark.parametrize("which", ["step", 2048])
@@ -1121,10 +1124,13 @@ def test_gated_deltanet_programs_compile_in_place(one_chip, which):
     """The Olmo-Hybrid step and its 2,048-token prefill (a chunk behind
     up to 15k positions of history) at the benchmark's sizes: every leaf
     of the cache written in place, the K/V pool (4.28 GB) never copied
-    and a state at most staged through fast memory, the step's two attention layers on the paged kernel
-    and no other Mosaic call, and each program beside its 9.2 GB of
-    arguments within the chip: the prefill's attention takes the history
-    a key block at a time, no score matrix over the window."""
+    and a state at most staged through fast memory, the step's two
+    attention layers on the paged kernel and the prefill's six
+    gated-DeltaNet scans on theirs and no other Mosaic call, no
+    triangular solve left in the prefill, and each program beside its
+    9.2 GB of arguments within the chip: the prefill's attention takes
+    the history a key block at a time, no score matrix over the
+    window."""
     progs = _olmo_programs(one_chip)
     lowered, cache = _lower(progs, one_chip, which, _OLMO_SLOTS,
                             _OLMO_MAX_LEN // _BLOCK)
@@ -1149,7 +1155,25 @@ def test_gated_deltanet_programs_compile_in_place(one_chip, which):
     print("memory: arguments %.3f GB, temporaries %.3f GB"
           % (mem.argument_size_in_bytes / 1e9, mem.temp_size_in_bytes / 1e9))
     kernels = text.count('custom_call_target="tpu_custom_call"')
-    assert kernels == (2 if which == "step" else 0)
+    assert kernels == (2 if which == "step" else 6)
     if which != "step":
         # no (heads, rows, keys) score matrix wider than a key block
         assert not re.search(r"f32\[30,2048,(1[0-9]{4})\]", text)
+        # the six kernels are the scan's, and nothing of a solve is left
+        assert len(re.findall(r"custom-call\(.*gdn_scan", text)) == 6
+        assert "triangular_solve" not in text
+        assert "InvertDiagBlocksLowerTriangular" not in text
+
+
+@pytest.mark.parametrize("bucket", [128, 2048])
+def test_gdn_scan_compiles(one_chip, bucket):
+    """The chunked gated-DeltaNet scan alone at the cell's widths (30
+    heads, ``d_k`` 96, ``d_v`` 192, float32) for the smallest and the
+    largest prefill bucket: one Mosaic call, no triangular solve."""
+    f32 = lambda *shape: one_chip(shape, "float32")
+    H, dk, dv = 30, 96, 192
+    text = _compile(gs.gdn_scan, f32(bucket, H, dk), f32(bucket, H, dk),
+                    f32(bucket, H, dv), f32(bucket, H), f32(bucket, H),
+                    f32(H, dk, dv))
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "gdn_scan" in text and "triangular_solve" not in text
